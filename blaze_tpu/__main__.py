@@ -51,13 +51,13 @@ dispatch-floor vs device-compute split, plan-annotated metrics tree,
 recovery timeline).
 
 ``--warmup`` populates the kernel and persistent XLA compile caches
-(``spark.blaze.xla.cacheDir`` / BLAZE_XLA_CACHEDIR, default
-``~/.cache/blaze_tpu/xla``) by running the listed queries (default q1
-q6) twice, fused + pruned exactly as run_task would, and GATES on the
-warm run: a second pass that triggers any fresh XLA compile exits
-nonzero.  Run once per image so the multi-minute first q01 compile is
-never paid inside a query; CI pairs it with the dispatch-budget
-regression test:
+(``JAX_COMPILATION_CACHE_DIR``, else ``spark.blaze.xla.cacheDir`` /
+BLAZE_XLA_CACHEDIR, else ``<checkout>/.jax_cache``) by running the
+listed queries (default q1 q6) twice, fused + pruned exactly as
+run_task would, and GATES on the warm run: a second pass that triggers
+any fresh XLA compile exits nonzero.  Run once per image so the first
+compiles are never paid inside a query; CI pairs it with the
+dispatch-budget regression test:
 
     python -m blaze_tpu --warmup && \
         pytest tests/test_dispatch_budget.py && python -m blaze_tpu --chaos
@@ -195,8 +195,7 @@ def _rows_via_scheduler(plan, manager=None, pool=None):
     return sorted(zip(*[flat[n] for n in names])) if names else []
 
 
-def _warmup(suite: str, names, scale: float, n_parts: int,
-            cache_dir: str = "") -> int:
+def _warmup(suite: str, names, scale: float, n_parts: int) -> int:
     """Pre-warm the persistent XLA compile cache and gate on warm-run
     recompiles (see module docstring).  Two passes per query, each run
     twice (cold + gated warm):
@@ -208,22 +207,15 @@ def _warmup(suite: str, names, scale: float, n_parts: int,
        per-task ShuffleWriterExec wrap, the tier-5 fused shuffle-write
        kernels, the IPC reader decode — are warmed too and a
        scheduler-path warm run sees zero recompiles."""
-    import os
-
     from . import conf
     from .runtime import dispatch
-    from .runtime.kernel_cache import default_cache_dir, enable_persistent_cache
+    from .runtime.kernel_cache import enable_persistent_cache
 
-    cache_dir = cache_dir or str(conf.XLA_CACHE_DIR.get() or "") or default_cache_dir()
-    os.makedirs(cache_dir, exist_ok=True)
-    enabled = enable_persistent_cache(cache_dir)
-    if enabled:
-        # publish the RESOLVED dir (arg/conf/image default) in conf so
-        # the pooled pass below inherits it: hostpool._spawn forwards
-        # conf.XLA_CACHE_DIR into worker env as BLAZE_XLA_CACHEDIR
-        conf.XLA_CACHE_DIR.set(cache_dir)
-    print(f"# warmup: persistent XLA cache "
-          f"{'at ' + cache_dir if enabled else 'DISABLED'}")
+    # one function places the cache for every launcher; pool workers of
+    # the pooled pass below resolve the same directory (inherited
+    # JAX_COMPILATION_CACHE_DIR, the forwarded conf key, or the same
+    # in-checkout default)
+    print(f"# warmup: persistent XLA cache at {enable_persistent_cache()}")
 
     build_query, names, scans = _load_suite(suite, names, scale, n_parts)
     if build_query is None:
@@ -2587,10 +2579,6 @@ def main(argv=None) -> int:
                          "caches (spark.blaze.xla.cacheDir) by running the "
                          "queries twice; exit nonzero if the warm run "
                          "recompiles anything")
-    ap.add_argument("--xla-cache-dir", default="",
-                    help="persistent XLA compile cache directory for "
-                         "--warmup (default: conf spark.blaze.xla.cacheDir, "
-                         "else ~/.cache/blaze_tpu/xla)")
     ap.add_argument("--explain", action="store_true",
                     help="EXPLAIN ANALYZE: warm each query, re-run it "
                          "traced through the stage scheduler, and render "
@@ -2927,7 +2915,7 @@ def main(argv=None) -> int:
             return _serve_forever()
         ap.error("query names required (or pass --chaos / --warmup / "
                  "--serve for the defaults)")
-    # persistent compile cache for plain runs too, when configured
+    # persistent compile cache for plain runs too
     if not args.warmup:
         from .runtime.kernel_cache import enable_persistent_cache
 
@@ -2935,8 +2923,7 @@ def main(argv=None) -> int:
     rc = 0
     try:
         if args.warmup:
-            rc = _warmup(args.suite, queries, args.scale, args.parts,
-                         args.xla_cache_dir)
+            rc = _warmup(args.suite, queries, args.scale, args.parts)
         elif args.chaos_seeds:
             # seed sweep: N independent schedules; the first also arms
             # speculation against an injected straggler, the second

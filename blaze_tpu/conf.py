@@ -392,10 +392,12 @@ FUSION_ENABLE = ConfEntry("spark.blaze.fusion.enabled", True, _bool)
 # the q01 dispatch collapse.  OFF = reduce + concat + merge as
 # separate programs (the pending-list doubling path).
 FUSED_AGG_UPDATE = ConfEntry("spark.blaze.tpu.fusedAggUpdate", True, _bool)
-# Persistent XLA compilation cache directory (jax_compilation_cache_dir)
-# — empty disables.  Pre-warm once per image with
-# `python -m blaze_tpu --warmup` so the 15-22 min first q01 compile
-# (round 5) is never paid inside a query.  Env: BLAZE_XLA_CACHEDIR.
+# Persistent XLA compilation cache directory (jax_compilation_cache_dir).
+# JAX_COMPILATION_CACHE_DIR, when set, wins and this key is not read;
+# empty falls to <checkout>/.jax_cache (runtime/kernel_cache.py
+# enable_persistent_cache is the one place that decides).  Pre-warm
+# once per image with `python -m blaze_tpu --warmup` so first compiles
+# are never paid inside a query.  Env: BLAZE_XLA_CACHEDIR.
 XLA_CACHE_DIR = ConfEntry("spark.blaze.xla.cacheDir", "", str)
 
 # TPU-specific knobs (no reference equivalent).
@@ -409,8 +411,8 @@ SEG_SCAN_REDUCE = ConfEntry("spark.blaze.tpu.segScanReduce", True, _bool)
 # duplicate groups are re-merged downstream)
 AGG_HASH_SORT_PARTIAL = ConfEntry("spark.blaze.tpu.aggHashSortPartial", True, _bool)
 # In-process exchanges keep partition buffers device-resident (HBM)
-# instead of round-tripping IPC files through the host — over a
-# remote/tunneled chip every host sync costs a full RTT.  The file
+# instead of round-tripping IPC files through the host — every host
+# sync drains the device queue and pays a D2H + H2D copy.  The file
 # shuffle remains the cross-process / spill path (turn this off to
 # force it, e.g. when a stage's output exceeds HBM).
 EXCHANGE_IN_PROCESS = ConfEntry("spark.blaze.exchange.inProcess", True, _bool)
@@ -484,11 +486,6 @@ PERF_BASELINES = ConfEntry("spark.blaze.perf.baselines", "", str)
 # Override path for the per-device-kind peak table (empty = the
 # packaged runtime/device_peaks.json).
 PERF_PEAKS = ConfEntry("spark.blaze.perf.peaks", "", str)
-# bench.py stale-cache guard: a carried cached q01/q06 half whose
-# ``measured_at`` stamp is older than this many days is DROPPED from
-# the merge (re-measured) instead of silently re-emitted — BENCH_r05
-# shipped a q01 number stamped six days stale.  0 = never expire.
-BENCH_MAX_CACHE_AGE_DAYS = ConfEntry("spark.blaze.bench.maxCacheAgeDays", 3, int)
 
 # Runtime statistics observatory (runtime/stats.py): cardinality
 # estimates stamped at optimize_plan, per-partition exchange

@@ -243,6 +243,32 @@ def null_nested_column(dtype: DataType, shape: Tuple[int, ...]) -> Column:
     return Column(dtype, jnp.zeros(shape, dtype.np_dtype), zeros_b)
 
 
+class RawUnscaled(int):
+    """Marker: the literal int is ALREADY the unscaled decimal value
+    (a literal decoded from plan bytes, a scalar-subquery result) —
+    ``Lit`` values are otherwise logical.  The repr differs from the
+    plain int's so :func:`expr_key` never shares a baked-constant
+    kernel between ``Lit(100)`` and ``Lit(RawUnscaled(100))``."""
+
+    def __repr__(self) -> str:
+        return f"RawUnscaled({int(self)})"
+
+
+def decimal_unscaled(value, scale: int) -> int:
+    """The int64 device value of a decimal literal — the ONE definition
+    the baked-constant lowering, the literal slots and the plan
+    serializer share."""
+    if isinstance(value, RawUnscaled):
+        return int(value)
+    if isinstance(value, str):
+        from decimal import Decimal
+
+        return int(Decimal(value).scaleb(scale).to_integral_value())
+    if isinstance(value, float):
+        return int(round(value * 10**scale))
+    return int(value) * 10**scale
+
+
 def _lit_column(value, dtype: DataType, n: int) -> Column:
     if value is None:
         if dtype.is_nested:
@@ -257,14 +283,7 @@ def _lit_column(value, dtype: DataType, n: int) -> Column:
         data = jnp.broadcast_to(jnp.asarray(row), (n, w))
         return Column(dtype, data, valid, jnp.full(n, len(b), jnp.int32))
     if dtype.is_decimal:
-        if isinstance(value, str):
-            from decimal import Decimal
-
-            unscaled = int(Decimal(value).scaleb(dtype.scale).to_integral_value())
-        elif isinstance(value, float):
-            unscaled = int(round(value * 10**dtype.scale))
-        else:
-            unscaled = int(value) * 10**dtype.scale
+        unscaled = decimal_unscaled(value, dtype.scale)
         return Column(dtype, jnp.full(n, unscaled, jnp.int64), valid)
     if dtype.kind == TypeKind.DATE32:
         if isinstance(value, str):
@@ -587,15 +606,7 @@ def _slot_physical(value, dtype: DataType):
     scalar so the jit argument dtype is pinned host-side (a python int
     would retrace on the int32/int64 weak-type boundary)."""
     if dtype.is_decimal:
-        if isinstance(value, str):
-            from decimal import Decimal
-
-            unscaled = int(Decimal(value).scaleb(dtype.scale).to_integral_value())
-        elif isinstance(value, float):
-            unscaled = int(round(value * 10**dtype.scale))
-        else:
-            unscaled = int(value) * 10**dtype.scale
-        return np.int64(unscaled)
+        return np.int64(decimal_unscaled(value, dtype.scale))
     if dtype.kind == TypeKind.DATE32:
         if isinstance(value, str):
             value = datetime.date.fromisoformat(value)

@@ -14,15 +14,12 @@ tests (tests/test_pallas.py).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ..runtime.errors import reraise_control
 
 
 def _x32():
@@ -32,17 +29,10 @@ def _x32():
     int64/float64), but under x64 Mosaic's grid path emits 64-bit index
     arithmetic it cannot legalize ("failed to legalize func.return").
     Every kernel here is 32-bit end to end, so tracing them in an
-    x64-off scope is value-preserving.  (jax 0.9 removed the public
-    disable_x64 context manager; fall back to a no-op if the internal
-    one moves.)
+    x64-off scope is value-preserving.
     """
-    try:
-        from jax._src.config import enable_x64
+    return jax.enable_x64(False)
 
-        return enable_x64(False)
-    except Exception as e:  # noqa: BLE001 — version probe
-        reraise_control(e)
-        return contextlib.nullcontext()
 
 LANES = 128
 TILE_ROWS = 8
@@ -65,21 +55,14 @@ def force_interpret(flag: bool) -> None:
 
 def available() -> bool:
     """True when the kernels can run (real TPU, or forced interpret)."""
-    if _FORCE_INTERPRET:
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception as e:  # noqa: BLE001 — backend probe
-        reraise_control(e)
-        return False
+    return _FORCE_INTERPRET or jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception as e:  # noqa: BLE001 — backend probe
-        reraise_control(e)
-        return True
+    """Interpret mode is something only ``force_interpret(True)``
+    (tests) turns on: everywhere else a kernel compiles for the
+    backend in force or raises."""
+    return _FORCE_INTERPRET
 
 
 # ---------------------------------------------------------------- helpers
@@ -102,6 +85,12 @@ def _pad_plane(a: jnp.ndarray, fill) -> jnp.ndarray:
 # kernel's contribution is fusion: hashing K key columns is one HBM
 # read of each plane and one HBM write of the pids.
 from ..exprs.hash import _fmix, _mix_h1, _mix_k1, _normalize_float  # noqa: E402
+from ..schema import TypeKind  # noqa: E402
+
+#: key kinds by uint32 word planes (floats enter as their bit view)
+_ONE_WORD = frozenset({TypeKind.BOOL, TypeKind.INT8, TypeKind.INT16,
+                       TypeKind.INT32, TypeKind.DATE32})
+_TWO_WORDS = frozenset({TypeKind.INT64, TypeKind.TIMESTAMP, TypeKind.DECIMAL})
 
 
 def _murmur3_pids_kernel(n_parts: int, widths: Tuple[int, ...], *refs):
@@ -173,28 +162,33 @@ def _build_murmur3_pids(n_parts: int, widths: Tuple[int, ...], m: int, interpret
     )
 
 
+def key_type_supported(dtype) -> bool:
+    """Does a key column of ``dtype`` have a word-plane form
+    (:func:`column_word_planes`)?  Fixed-width scalars do; strings and
+    nested types do not — their callers hash with XLA.  Decided from
+    the TYPE before any kernel is built, so a kernel that then fails to
+    lower or compile is a failure, never a fallback."""
+    return dtype.is_float or dtype.kind in _ONE_WORD or dtype.kind in _TWO_WORDS
+
+
 def column_word_planes(col) -> Tuple[List[jnp.ndarray], int]:
     """Split a Column's data into uint32 word planes for murmur3_pids.
 
-    Returns (planes, width).  Only fixed-width non-string types; the
-    caller falls back to the XLA hash path otherwise.
+    Returns (planes, width).  Only the types ``key_type_supported``
+    admits.
     """
-    from ..schema import TypeKind
-
     k = col.dtype.kind
     d = col.data
-    if col.dtype.is_string:
-        raise NotImplementedError("string keys use the XLA hash path")
+    if not key_type_supported(col.dtype):
+        raise NotImplementedError(f"murmur3 pallas path over {col.dtype!r}")
     if col.dtype.is_float:
         d, k = _normalize_float(col)  # -0.0 normalize + bit view (hash.py)
-    if k in (TypeKind.BOOL, TypeKind.INT8, TypeKind.INT16, TypeKind.INT32, TypeKind.DATE32):
+    if k in _ONE_WORD:
         return [d.astype(jnp.int32).view(jnp.uint32)], 1
-    if k in (TypeKind.INT64, TypeKind.TIMESTAMP, TypeKind.DECIMAL):
-        v = d.astype(jnp.int64)
-        low = (v & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32)
-        high = ((v >> jnp.int64(32)) & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32)
-        return [low, high], 2
-    raise NotImplementedError(f"murmur3 pallas path over {col.dtype!r}")
+    v = d.astype(jnp.int64)
+    low = (v & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32)
+    high = ((v >> jnp.int64(32)) & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32)
+    return [low, high], 2
 
 
 # ---------------------------------------------------------------- histogram
@@ -315,8 +309,11 @@ def _build_group_sums(g_pad: int, k: int, m: int, interpret: bool):
 #: largest build-side key table the pallas probe path accepts: the
 #: kernel counts ALL (probe, table) pairs per tile, so work is N*T —
 #: a win only for the small sorted tables of broadcast-style builds
-#: where XLA's per-probe searchsorted dispatch dominates
-SORTED_LOOKUP_MAX_TABLE = 8192
+#: where XLA's per-probe searchsorted dispatch dominates.  It is also
+#: what fits: the (8, 128, T) compare planes of one probe tile must
+#: stay inside the chip's 16 MiB scoped VMEM, and Mosaic refuses
+#: T = 8192 for a v5e (tests/test_chip_compile.py compiles this limit)
+SORTED_LOOKUP_MAX_TABLE = 4096
 
 
 def _sorted_lookup_kernel(q_hi_ref, q_lo_ref, t_hi_ref, t_lo_ref,

@@ -247,12 +247,11 @@ def _segscan(op, vals, flags):
     shifts and elementwise ops only.
 
     Deliberately NOT ``lax.associative_scan``: its recursive even/odd
-    decomposition emits strided slices + interleaves whose Mosaic/TPU
-    compile is pathological — measured on the real chip, ONE
-    associative_scan at the 4M bucket pushed the q1 agg kernel's
-    remote compile past 35 minutes and its execution to ~50 s/call
-    (.bench_q1diag.log, round 5); the doubling form compiles in
-    seconds and runs at HBM speed."""
+    decomposition emits strided slices + interleaves whose TPU compile
+    was pathological when last read on a chip (pre-PR-1, at the 4M
+    bucket: compile in tens of minutes, execution in tens of seconds
+    per call; not re-measured since); the doubling form is contiguous
+    shifts only."""
     n = vals.shape[0]
     v, f = vals, flags
     d = 1
@@ -671,9 +670,14 @@ class AggExec(ExecNode):
         pre_filter: Optional[Expr] = None,
         post_sort: Optional[Sequence] = None,
         post_fetch: Optional[int] = None,
+        dup_groups_ok: bool = False,
     ):
         super().__init__([child])
         self.mode = mode
+        # the state-merging twin of a PARTIAL agg (_StateMerger): its
+        # output is re-merged by every later stage, so it may emit
+        # hash-split duplicate groups exactly like PARTIAL itself
+        self._dup_groups_ok = dup_groups_ok
         # stage fusion may fold a downstream Sort(+Limit) into the
         # finalize program (FINAL mode emits one blocking batch per
         # partition, so an in-program key sort over it is exact):
@@ -786,7 +790,7 @@ class AggExec(ExecNode):
             tuple((a.fn, None if a.expr is None else expr_key(a.expr), a.name)
                   for a in self.aggs),
             bool(conf.SEG_SCAN_REDUCE.get()),
-            bool(conf.AGG_HASH_SORT_PARTIAL.get()),
+            bool(conf.AGG_HASH_SORT_PARTIAL.get()), self._dup_groups_ok,
             None if self.post_sort is None else sort_fields_key(self.post_sort),
         )
         self._kernel_key = kernel_key
@@ -837,12 +841,14 @@ class AggExec(ExecNode):
         # kernels are cached process-wide and must not pin this exec's
         # child subtree (scanned data) alive
         use_segscan = bool(conf.SEG_SCAN_REDUCE.get())  # in kernel_key
-        # exactness: only PARTIAL may emit hash-split duplicate groups
+        # exactness: only PARTIAL — and the twin that merges a PARTIAL
+        # agg's own accumulators — may emit hash-split duplicate groups
         # (every later stage re-merges); FINAL/PARTIAL_MERGE sort the
-        # full key words
-        use_hash_sort = (
-            bool(conf.AGG_HASH_SORT_PARTIAL.get()) and self.mode == AggMode.PARTIAL
-        )
+        # full key words.  The one-u32-key sort is also what the chip's
+        # compiler can afford: its sort compile time grows with rows x
+        # operands, minutes for four u64 key words at 16k rows
+        use_hash_sort = bool(conf.AGG_HASH_SORT_PARTIAL.get()) and (
+            self.mode == AggMode.PARTIAL or self._dup_groups_ok)
 
         def eval_inputs(cols: Tuple[Column, ...], schema: Schema):
             env = {f.name: c for f, c in zip(schema.fields, cols)}
@@ -1278,10 +1284,11 @@ class AggExec(ExecNode):
         reduce plus ~#state-buffers programs per concat+merge cascade.
 
         grouped_update(acc_cols, acc_n, in_cols, in_n, out_cap) ->
-        (state cols sliced to the STATIC ``out_cap``, true merged group
-        count); when the count exceeds out_cap the caller redoes the
-        batch through the eager reduce+merge, which re-buckets the
-        grown accumulator to a power-of-two capacity.
+        (state cols sliced to the STATIC ``out_cap``, merged group
+        count — exact up to out_cap, and over it whenever the batch
+        overflowed the bucket); when the count exceeds out_cap the
+        caller redoes the batch through the eager reduce+merge, which
+        re-buckets the grown accumulator to a power-of-two capacity.
         scalar_update(acc_cols, in_cols, in_n) -> 1-row state cols."""
         if self._update_k is None:
             from functools import partial
@@ -1303,16 +1310,32 @@ class AggExec(ExecNode):
                 @partial(jax.jit, static_argnums=(4,))
                 def grouped_update(acc_cols, acc_n, in_cols, in_n, out_cap):
                     part_cols, part_n = reduce_g(in_cols, in_n)
+                    # merge the accumulator with the partial's first
+                    # out_cap rows, not its whole batch-sized buffer:
+                    # any more groups than that overflow the bucket
+                    # anyway.  Merging at cap_a + batch capacity
+                    # (66,560 rows for q01) sorted a batch of padding
+                    # per update, and the v5e compiler does not survive
+                    # that program (SIGSEGV in its HLO passes; the
+                    # merge alone compiles for minutes)
+                    part_cols = tuple(head_rows(p, out_cap) for p in part_cols)
+                    kept = jnp.minimum(part_n, out_cap)
                     cap_a = acc_cols[0].validity.shape[0]
-                    cap_i = part_cols[0].validity.shape[0]
                     comb = tuple(
                         _concat_device_cols(
-                            f.dtype, [a, p], [acc_n, part_n], cap_a + cap_i
+                            f.dtype, [a, p], [acc_n, kept], cap_a + out_cap
                         )
                         for f, a, p in zip(state_schema.fields, acc_cols, part_cols)
                     )
-                    merged, m_n = merge_g(comb, acc_n + part_n)
-                    return tuple(head_rows(c, out_cap) for c in merged), m_n
+                    merged, m_n = merge_g(comb, acc_n + kept)
+                    # a truncated partial (part_n > out_cap) reports a
+                    # count over out_cap: the caller's overflow check
+                    # then redoes the batch through the eager path
+                    # int32 like the seed count: the next update takes
+                    # this scalar as acc_n, and a second dtype would be
+                    # a second compile of the same program
+                    return (tuple(head_rows(c, out_cap) for c in merged),
+                            jnp.maximum(m_n, part_n).astype(jnp.int32))
 
                 @jax.jit
                 def scalar_update(acc_cols, in_cols, in_n):
@@ -1522,6 +1545,7 @@ class _StateMerger:
             AggMode.PARTIAL_MERGE,
             [GroupingExpr(_col(g.name), g.name) for g in agg.groupings],
             agg.aggs,
+            dup_groups_ok=agg.mode == AggMode.PARTIAL,
         )
 
     @classmethod
@@ -1617,8 +1641,8 @@ class _FusedGroupedUpdate:
         st = consumer.take_state_any()
         if st is None:
             # seed (or post-spill restart): reduce, shrink to its own
-            # bucket so steady-state updates sort acc_cap + batch_cap
-            # rows, not 2x batch_cap (q01: 4 groups -> min capacity)
+            # bucket so steady-state updates sort 2x acc_cap rows, not
+            # 2x batch_cap (q01: 4 groups -> min capacity)
             self._pending = None
             part = agg._reduce_batch(batch, self._in_schema)
             cap = bucket_capacity(max(part.num_rows, 1))

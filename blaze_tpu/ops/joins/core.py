@@ -146,19 +146,16 @@ def probe_counts(jmap_keys, probe_keys, use_pallas: bool = False):
     ``use_pallas`` routes the two searchsorted dispatches through the
     fused pallas counting-lookup kernel (kernels/pallas_ops.py) — a
     trace-time constant (the Joiner cache key carries it), applied only
-    when the build table fits the kernel's all-pairs work bound.  Any
-    lowering failure falls back to the XLA path at trace time."""
+    when the build table fits the kernel's all-pairs work bound.  A
+    lowering or compile failure of the kernel raises: a table over the
+    bound is dispatch on size, a broken kernel is not."""
     if use_pallas:
         from ...kernels import pallas_ops
-        from ...runtime.errors import reraise_control
 
         if jmap_keys.shape[0] <= pallas_ops.SORTED_LOOKUP_MAX_TABLE:
-            try:
-                lo, hi = pallas_ops.sorted_lookup(jmap_keys, probe_keys)
-                is_sent = probe_keys == _SENTINEL
-                return lo, jnp.where(is_sent, 0, hi - lo)
-            except Exception as e:  # noqa: BLE001 — XLA path is exact
-                reraise_control(e)
+            lo, hi = pallas_ops.sorted_lookup(jmap_keys, probe_keys)
+            is_sent = probe_keys == _SENTINEL
+            return lo, jnp.where(is_sent, 0, hi - lo)
     lo = jnp.searchsorted(jmap_keys, probe_keys, side="left")
     hi = jnp.searchsorted(jmap_keys, probe_keys, side="right")
     is_sent = probe_keys == _SENTINEL

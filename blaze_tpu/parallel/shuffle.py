@@ -800,9 +800,15 @@ class ShuffleWriterExec(ExecNode):
                  tuple(expr_key(e) for e in exprs), n_out),
                 lambda: _build_pid_kernels(schema, exprs, n_out),
             )
-            # pallas fast path decided on the first batch (key dtypes
-            # are static); falls back to XLA for string/unsupported keys
-            self._pallas_pids = conf.PALLAS_ENABLE.get()
+            # pallas fast path: decided HERE from the key types (string
+            # and nested keys have no word-plane form and hash with
+            # XLA) — dispatch on type, so nothing downstream needs to
+            # catch a kernel failure and mistake it for one
+            from ..exprs.compile import infer_dtype
+            from ..kernels.pallas_ops import key_type_supported
+
+            self._pallas_pids = bool(conf.PALLAS_ENABLE.get()) and all(
+                key_type_supported(infer_dtype(e, schema)) for e in exprs)
         elif isinstance(partitioning, RangePartitioning):
             from ..exprs.compile import expr_key
             from ..runtime.kernel_cache import cached_kernel, schema_key
@@ -827,25 +833,15 @@ class ShuffleWriterExec(ExecNode):
         return pids_fn(words, boundaries)
 
     def _hash_pids(self, cols, num_rows):
+        """Partition ids of one batch.  No ``except``: a Pallas
+        lowering or compile failure raises — the XLA path is for key
+        types and backends the kernel does not serve, not for a kernel
+        that broke."""
         if self._pallas_pids:
-            try:
-                from ..kernels import pallas_ops
+            from ..kernels import pallas_ops
 
-                if pallas_ops.available():
-                    return self._hash_pids_pallas(cols, num_rows)
-                self._pallas_pids = False
-            except NotImplementedError:
-                self._pallas_pids = False  # e.g. string keys: expected, quiet
-            except Exception as e:  # import/lowering failures: warn once
-                from ..runtime.errors import reraise_control
-
-                reraise_control(e)
-                self._pallas_pids = False
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "pallas pid path failed (%s); using XLA path", e
-                )
+            if pallas_ops.available():
+                return self._hash_pids_pallas(cols, num_rows)
         return self._hash_pids_xla(cols, num_rows)
 
     @property

@@ -17,12 +17,12 @@ only schemas, expression IR, and static parameters.
 
 from __future__ import annotations
 
+import os
 from typing import Any, Callable, Dict, Tuple
 
 from ..analysis.locks import make_lock
 from ..schema import Schema
 from . import lockset
-from .errors import reraise_control
 
 _CACHE: Dict[tuple, Any] = {}
 _LOCK = make_lock("kernel_cache.registry")
@@ -83,46 +83,44 @@ def cached_kernel(key: tuple, builder: Callable[[], Any]) -> Any:
         return _CACHE.setdefault(key, built)
 
 
-_PERSISTENT_DIR = [""]  # active cache dir; "" = disabled
+#: the variable JAX itself reads for ``jax_compilation_cache_dir``
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def default_cache_dir() -> str:
-    """The image-wide default persistent-cache location — the ONE
-    definition `--warmup` pre-warms and bench measurement children
-    read (two literals would silently diverge and re-pay the
-    multi-minute first compile on the leased chip)."""
-    import os
-
-    return os.path.join(os.path.expanduser("~"), ".cache", "blaze_tpu", "xla")
+def checkout_cache_dir() -> str:
+    """``<checkout>/.jax_cache`` — the fixed fallback.  The directory
+    is part of the cache key, so it is never built from a temp name,
+    pid or time, and it stays inside the checkout (gitignored)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
 
 
-def enable_persistent_cache(path: str = "") -> bool:
-    """Point JAX's persistent compilation cache at
-    ``spark.blaze.xla.cacheDir`` (or ``path``) so the multi-minute
-    first compile of the big agg/sort programs is paid once per image
-    — warm processes deserialize the XLA executable instead of
-    recompiling (≙ the reference shipping precompiled native code in
-    its .so).  Thresholds drop to zero: EVERY program is worth caching
-    when per-program compile turnaround is the bottleneck.  Returns
-    True when the cache is active.  Shape bucketing (batch.py
-    power-of-two capacities) keeps the entry count bounded."""
-    from .. import conf
+def enable_persistent_cache() -> str:
+    """Decide where JAX's persistent compilation cache lives, for every
+    launcher (the CLI, pool workers, ``chip_smoke.py``), and return the
+    directory in force:
 
-    path = path or str(conf.XLA_CACHE_DIR.get() or "")
-    if not path:
-        return False
-    if _PERSISTENT_DIR[0] == path:
-        return True  # idempotent: already pointed here
+    1. ``JAX_COMPILATION_CACHE_DIR`` set: whoever launched the process
+       placed the cache.  JAX reads the variable itself, so the
+       directory is not touched here at all;
+    2. else ``spark.blaze.xla.cacheDir`` when set;
+    3. else ``<checkout>/.jax_cache``.
+
+    Thresholds drop to zero either way: every program is worth keeping
+    when a new process starts with no compiled code (≙ the reference
+    shipping precompiled native code in its .so).  Shape bucketing
+    (batch.py power-of-two capacities) keeps the entry count bounded."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
+    from .. import conf
+
+    path = os.environ.get(CACHE_DIR_ENV, "")
+    if not path:
+        path = str(conf.XLA_CACHE_DIR.get() or "") or checkout_cache_dir()
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # noqa: BLE001 — knob renamed across jax versions
-        reraise_control(e)
-    _PERSISTENT_DIR[0] = path
-    return True
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def cache_stats() -> Dict[str, int]:

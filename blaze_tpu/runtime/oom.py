@@ -69,7 +69,12 @@ def is_resource_exhausted(exc: BaseException) -> bool:
     ``XlaRuntimeError`` — matched by message, the only stable contract
     across jaxlib versions) and for the fault injector's
     :class:`runtime.faults.InjectedOom` stand-in.  Host-side
-    ``MemoryError`` stays out: retry.classify treats it as FATAL."""
+    ``MemoryError`` stays out: retry.classify treats it as FATAL.  So
+    does a COMPILER refusal: Mosaic/XLA report a kernel whose scoped
+    on-chip memory (``vmem``/``smem``) does not fit under the same
+    status, and no spill or smaller batch repairs a kernel that cannot
+    be built — absorbing it would hide a broken kernel behind the
+    eager rung."""
     if isinstance(exc, MemoryError):
         return False
     if isinstance(exc, DeviceOomError):
@@ -78,7 +83,10 @@ def is_resource_exhausted(exc: BaseException) -> bool:
         # re-run a batch whose donated inputs may already be deleted
         return False
     s = str(exc)
-    return "RESOURCE_EXHAUSTED" in s or "Resource exhausted" in s
+    if "RESOURCE_EXHAUSTED" not in s and "Resource exhausted" not in s:
+        return False
+    low = s.lower()
+    return "vmem" not in low and "smem" not in low
 
 
 def max_downshifts() -> int:

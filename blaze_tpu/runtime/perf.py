@@ -196,43 +196,34 @@ def load_peaks(path: Optional[str] = None) -> Dict[str, Any]:
 
 def peaks_for(device_kind: str,
               table: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Peak numbers for a device-kind string (``str(jax.devices()[0])``
-    or the bench line's ``device_kind`` stamp): case-insensitive
-    substring match over the table's device keys, LONGEST match first
-    (so ``v5e`` beats ``v5`` if both ever exist), falling back to the
-    table's ``default``.  The returned dict carries the matched key as
-    ``device`` so consumers can stamp which roof they judged against."""
+    """Peak numbers for a device kind (``jax.devices()[0].device_kind``
+    or an event log's ``device_kind`` stamp): exact, case-insensitive
+    match over the table's keys.  A kind that is not in the table
+    raises ``KeyError`` — there is no default row, so a chip the table
+    does not know is never judged against an invented roof.  The
+    returned dict carries the matched key as ``device`` so consumers
+    can stamp which roof they judged against."""
     table = table or load_peaks()
-    kind = (device_kind or "").lower()
-    best_key = None
-    for key in table.get("devices", {}):
-        if key.lower() in kind and (
-                best_key is None or len(key) > len(best_key)):
-            best_key = key
-    if best_key is not None:
-        entry = dict(table["devices"][best_key])
-        entry["device"] = best_key
-        return entry
-    entry = dict(table.get("default", {"hbm_gbps": 50.0, "tflops": 0.5}))
-    entry["device"] = "default"
-    return entry
+    kind = (device_kind or "").strip().lower()
+    for key, row in table.get("devices", {}).items():
+        if key.lower() == kind:
+            return dict(row, device=key)
+    raise KeyError(
+        f"device kind {device_kind!r} is not in the peak table "
+        f"({peaks_path()}): add its row with the source of its peaks")
 
 
 _device_kind_cache: List[str] = []
 
 
 def current_device_kind() -> str:
-    """``str(jax.devices()[0])`` cached — what this process's programs
-    actually ran on (the bench line's ``device_kind`` stamp uses the
-    same derivation)."""
+    """``jax.devices()[0].device_kind`` cached — what this process's
+    programs actually ran on (the query span stamps it into the event
+    log, and the peak table is keyed on it)."""
     if not _device_kind_cache:
-        try:
-            import jax
+        import jax
 
-            _device_kind_cache.append(str(jax.devices()[0])[:80])
-        except Exception as e:  # noqa: BLE001 — introspection must not die
-            reraise_control(e)
-            _device_kind_cache.append("unknown")
+        _device_kind_cache.append(jax.devices()[0].device_kind)
     return _device_kind_cache[0]
 
 

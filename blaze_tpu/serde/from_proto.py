@@ -17,6 +17,7 @@ from ..exprs.ir import (
     GetStructField, InList, IsNotNull, IsNull, Like, Lit, NamedStruct, Not,
     ScalarFunc, SparkUdfWrapper,
 )
+from ..exprs.compile import RawUnscaled
 from ..schema import DataType, Field, Schema, TypeKind
 from . import plan_pb2 as pb
 
@@ -59,36 +60,10 @@ def _lit_from_proto(l: pb.LiteralValue) -> Lit:
         v = l.bytes_value
         return Lit(v.decode("utf-8") if t.kind == TypeKind.STRING else v, t)
     # int_value: decimals arrive unscaled; Lit stores logical values, so
-    # wrap through a raw-int constructor
+    # mark the int as already-unscaled
     if t.is_decimal:
-        lit = Lit(0, t)
-        lit.value = _RawUnscaled(l.int_value)
-        return lit
+        return Lit(RawUnscaled(l.int_value), t)
     return Lit(l.int_value, t)
-
-
-class _RawUnscaled(int):
-    """Marker: the literal int is ALREADY the unscaled decimal value."""
-
-
-# teach the lowering about _RawUnscaled without touching its fast path
-def _patch_lit_lowering():
-    from ..exprs import compile as C
-
-    orig = C._lit_column
-
-    def lit_column(value, dtype, n):
-        if isinstance(value, _RawUnscaled) and dtype.is_decimal:
-            import jax.numpy as jnp
-
-            return C.Column(dtype, jnp.full(n, int(value), jnp.int64), jnp.ones(n, jnp.bool_))
-        return orig(value, dtype, n)
-
-    if orig.__name__ != "lit_column":
-        C._lit_column = lit_column
-
-
-_patch_lit_lowering()
 
 
 def expr_from_proto(n: pb.ExprNode) -> Expr:

@@ -67,7 +67,7 @@ def schema_to_proto(s: Schema) -> pb.SchemaProto:
 
 
 def _lit_to_proto(e: Lit) -> pb.LiteralValue:
-    from ..exprs.compile import infer_lit_dtype
+    from ..exprs.compile import decimal_unscaled, infer_lit_dtype
 
     t = infer_lit_dtype(e.value, e.dtype)
     out = pb.LiteralValue(dtype=dtype_to_proto(t))
@@ -81,21 +81,7 @@ def _lit_to_proto(e: Lit) -> pb.LiteralValue:
     elif t.is_float:
         out.float_value = float(v)
     elif t.is_decimal:
-        from .from_proto import _RawUnscaled
-
-        if isinstance(v, _RawUnscaled):
-            # already the unscaled representation (a scalar-subquery
-            # result round-tripping back out) — scaling it again would
-            # inflate the literal 10^scale-fold
-            out.int_value = int(v)
-        elif isinstance(v, str):
-            from decimal import Decimal
-
-            out.int_value = int(Decimal(v).scaleb(t.scale).to_integral_value())
-        elif isinstance(v, float):
-            out.int_value = int(round(v * 10**t.scale))
-        else:
-            out.int_value = int(v) * 10**t.scale
+        out.int_value = decimal_unscaled(v, t.scale)
     elif t.kind == TypeKind.DATE32:
         if isinstance(v, str):
             v = datetime.date.fromisoformat(v)

@@ -102,10 +102,9 @@ class _StrategyContext(ConversionContext):
             # batch_to_pydict returns decimals UNSCALED; Lit is logical
             # (same contract as tpch.queries.scalar_subquery_row) — a
             # raw int here would inflate the literal by 10^scale
-            from ..serde.from_proto import _RawUnscaled
+            from ..exprs.compile import RawUnscaled
 
-            out = Lit(0, t)
-            out.value = _RawUnscaled(value)
+            out = Lit(RawUnscaled(value), t)
         self._subquery_memo[id(sub_plan)] = (sub_plan, out)
         return out
 

@@ -125,11 +125,9 @@ def scalar_subquery_row(plan: ExecNode, columns: List[str]) -> List[Expr]:
         value = values[c]
         if t.is_decimal and value is not None:
             # batch_to_pydict returns decimals unscaled; Lit is logical
-            from ..serde.from_proto import _RawUnscaled
+            from ..exprs.compile import RawUnscaled
 
-            lit_ = lit(0, t)
-            lit_.value = _RawUnscaled(value)
-            out.append(lit_)
+            out.append(lit(RawUnscaled(value), t))
         else:
             out.append(lit(value, t))
     return out

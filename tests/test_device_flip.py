@@ -467,11 +467,12 @@ def test_device_ring_fifo_and_overlap_metric():
 # ----------------------------- 5. pallas hash-join probe kernel
 
 
-def test_sorted_lookup_matches_searchsorted():
+def test_sorted_lookup_matches_searchsorted(interpret):
     from blaze_tpu.kernels import pallas_ops
 
     rng = np.random.default_rng(11)
-    for t_n, p_n in ((17, 100), (1024, 3000), (4096, 257)):
+    for t_n, p_n in ((17, 100), (1024, 3000),
+                     (pallas_ops.SORTED_LOOKUP_MAX_TABLE, 257)):
         table = np.sort(rng.integers(0, 2**63, t_n, dtype=np.uint64))
         # duplicates + exact hits + misses + extremes
         probes = np.concatenate([

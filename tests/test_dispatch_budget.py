@@ -187,6 +187,56 @@ def test_fused_update_overflow_falls_back_to_eager(data):
     assert len(seen) == n and all(v == 3 for v in seen.values())
 
 
+def test_fused_update_merges_at_twice_the_bucket_not_the_batch():
+    """The update program merges accumulator + the partial's first
+    ``out_cap`` rows (2 x out_cap), never accumulator + a whole
+    batch-sized buffer: that program sorted a batch of padding per
+    update and the v5e compiler did not survive it at 1,024 + 65,536
+    rows (PR 22).  The count it returns is int32 like the seed count —
+    a second dtype is a second compile of the same program — and the
+    twin that merges a PARTIAL agg's accumulators sorts ONE hash key
+    (its duplicate groups are re-merged downstream); a FINAL agg's
+    twin stays exact."""
+    import jax
+    import jax.numpy as jnp
+
+    from blaze_tpu.batch import Column
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops import AggExec, AggFunction, AggMode, GroupingExpr
+    from blaze_tpu.ops.agg import _StateMerger
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    schema = Schema([Field("k", DataType.int64()), Field("v", DataType.int64())])
+    scan = MemoryScanExec([[]], schema)
+    partial = AggExec(scan, AggMode.PARTIAL, [GroupingExpr(col("k"), "k")],
+                      [AggFunction("sum", col("v"), "s")])
+
+    def shapes(sch, cap):
+        return tuple(Column(f.dtype,
+                            jax.ShapeDtypeStruct((cap,), f.dtype.np_dtype),
+                            jax.ShapeDtypeStruct((cap,), jnp.bool_))
+                     for f in sch.fields)
+
+    acc_cap, batch_cap = 1024, 8192
+    update = dispatch.raw(partial._update_kernels()[0])
+    args = (shapes(partial._state_schema, acc_cap),
+            jax.ShapeDtypeStruct((), jnp.int32), shapes(schema, batch_cap),
+            batch_cap, acc_cap)
+    hlo = update.lower(*args).as_text()
+    assert f"tensor<{2 * acc_cap}x" in hlo
+    assert f"tensor<{acc_cap + batch_cap}x" not in hlo
+    cols, count = jax.eval_shape(update, *args)
+    assert count.dtype == jnp.int32 and count.shape == ()
+    assert {c.validity.shape for c in cols} == {(acc_cap,)}
+
+    assert _StateMerger.for_agg(partial)._twin._dup_groups_ok
+    final = AggExec(MemoryScanExec([[]], partial.schema), AggMode.FINAL,
+                    [GroupingExpr(col("k"), "k")],
+                    [AggFunction("sum", col("v"), "s")])
+    assert not _StateMerger.for_agg(final)._twin._dup_groups_ok
+    assert partial._kernel_key != _StateMerger.for_agg(partial)._twin._kernel_key
+
+
 def test_fused_update_rollback_after_eager_interleave_exact():
     """Regression: when the fused path resumes from a state the EAGER
     pending-merge built (a plain RecordBatch), that state must become

@@ -7,8 +7,7 @@
    (shuffle spills forced),
 
 both validated against the numpy oracles.  This is the repo's analogue
-of the reference's pseudo-distributed testenv (dev/testenv/) and the
-basis of ``__graft_entry__.dryrun_multichip``.
+of the reference's pseudo-distributed testenv (dev/testenv/).
 """
 
 import numpy as np

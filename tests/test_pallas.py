@@ -1,4 +1,5 @@
-"""Pallas kernel tests (interpret mode on the CPU mesh).
+"""Pallas kernel tests (interpret mode on the CPU mesh — forced: off a
+TPU nothing but ``force_interpret(True)`` interprets a kernel).
 
 Differential oracles: the pure-XLA implementations in exprs/hash.py
 (themselves validated against Spark golden vectors in test_hash.py)
@@ -20,7 +21,7 @@ def _ref_pids(cols, n_parts):
     return np.asarray(pmod(murmur3_columns(cols), n_parts))
 
 
-def test_murmur3_pids_i64_matches_xla():
+def test_murmur3_pids_i64_matches_xla(interpret):
     rng = np.random.default_rng(0)
     n = 3000  # not a multiple of the 1024-row tile
     keys = rng.integers(-(2**62), 2**62, n, dtype=np.int64)
@@ -32,7 +33,7 @@ def test_murmur3_pids_i64_matches_xla():
     np.testing.assert_array_equal(got, _ref_pids([col.to_device()], 200))
 
 
-def test_murmur3_pids_multi_col_with_nulls():
+def test_murmur3_pids_multi_col_with_nulls(interpret):
     rng = np.random.default_rng(1)
     n = 1500
     a = rng.integers(-(2**31), 2**31, n, dtype=np.int32)
@@ -62,7 +63,7 @@ def test_murmur3_pids_multi_col_with_nulls():
     ],
     ids=["int32", "float64", "float32", "decimal", "date32", "bool"],
 )
-def test_murmur3_pids_every_key_dtype(dtype, gen):
+def test_murmur3_pids_every_key_dtype(interpret, dtype, gen):
     """Every column_word_planes branch must agree with the XLA hash —
     partition ids are a Spark-compat correctness gate."""
     rng = np.random.default_rng(7)
@@ -75,7 +76,7 @@ def test_murmur3_pids_every_key_dtype(dtype, gen):
     np.testing.assert_array_equal(got, _ref_pids([col], 31))
 
 
-def test_pid_histogram_matches_bincount():
+def test_pid_histogram_matches_bincount(interpret):
     rng = np.random.default_rng(2)
     n, p = 5000, 37
     pids = rng.integers(0, p, n).astype(np.int32)
@@ -83,7 +84,7 @@ def test_pid_histogram_matches_bincount():
     np.testing.assert_array_equal(got, np.bincount(pids, minlength=p))
 
 
-def test_fused_group_sums_with_filtered_rows():
+def test_fused_group_sums_with_filtered_rows(interpret):
     rng = np.random.default_rng(3)
     n, g, k = 4000, 6, 3
     gids = rng.integers(-1, g, n).astype(np.int32)  # -1 = filtered out
@@ -97,7 +98,7 @@ def test_fused_group_sums_with_filtered_rows():
     np.testing.assert_allclose(got, want, rtol=2e-5)
 
 
-def test_fused_group_sums_counts():
+def test_fused_group_sums_counts(interpret):
     # count(*) per group = sum of a ones column
     gids = np.array([0, 1, 1, 2, -1, 2, 2], np.int32)
     ones = jnp.ones(7, jnp.float32)

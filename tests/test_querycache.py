@@ -124,6 +124,39 @@ def test_structural_literal_args_never_become_slots():
     assert t.is_decimal and t.precision == 12 and t.scale == 2
 
 
+def test_decimal_literal_from_plan_bytes_slots_unscaled_once():
+    """A decimal literal decoded from TaskDefinition bytes is ALREADY
+    unscaled (``RawUnscaled``).  Its slot must ship that value as is:
+    scaling it again inflated ``1 - l_discount`` to ``100 - l_discount``
+    in every non-absorbed Project/Filter of the served path (q03's
+    revenue through the scheduler was 100x off, found on the PR 22
+    bring-up).  The baked constant, the slot and the serializer share
+    one definition."""
+    from blaze_tpu.exprs.compile import (RawUnscaled, decimal_unscaled,
+                                         expr_key, slotify_literals)
+    from blaze_tpu.schema import DataType
+    from blaze_tpu.serde.from_proto import expr_from_proto
+    from blaze_tpu.serde.to_proto import expr_to_proto
+
+    dec = DataType.decimal(12, 2)
+    logical = lit(1, dec) - col("d")
+    decoded = expr_from_proto(expr_to_proto(logical))
+    assert isinstance(decoded.left.value, RawUnscaled)
+    (_,), vals_logical = slotify_literals([logical])
+    (_,), vals_decoded = slotify_literals([decoded])
+    assert [int(v) for v in vals_logical] == [100]
+    assert [int(v) for v in vals_decoded] == [100]
+    # ...and out again: a second serde hop does not scale it either
+    assert expr_to_proto(decoded).SerializeToString() \
+        == expr_to_proto(logical).SerializeToString()
+    for value, want in ((1, 100), ("0.05", 5), (0.07, 7),
+                        (RawUnscaled(100), 100)):
+        assert decimal_unscaled(value, 2) == want
+    # a baked-constant kernel is never shared between the logical 100
+    # and the unscaled 100: their structural keys differ
+    assert expr_key(lit(100, dec)) != expr_key(lit(RawUnscaled(100), dec))
+
+
 def test_result_cache_never_serves_other_slot_values():
     """Same digest, different slot values: the result key differs, so
     a WHERE v > 5 entry can never answer WHERE v > 9."""
