@@ -423,6 +423,24 @@ class RecordBatch:
     def to_device(self) -> "RecordBatch":
         return RecordBatch(self.schema, [c.to_device() for c in self.columns], self.num_rows)
 
+    def host_nbytes(self) -> int:
+        """Bytes ``to_device()`` would stage: the ``nbytes`` of every
+        numpy buffer, from shapes (opaque columns never leave the host;
+        a device-resident buffer is not staged again).  Runs once per
+        scanned batch: a plain loop over ``type(a) is ndarray``."""
+        n = 0
+        stack = list(self.columns)
+        while stack:
+            c = stack.pop()
+            if c.dtype.kind == TypeKind.OPAQUE:
+                continue
+            for a in (c.data, c.validity, c.lengths):
+                if type(a) is np.ndarray:
+                    n += a.nbytes
+            if c.children is not None:
+                stack.extend(c.children)
+        return n
+
     def to_host(self) -> "RecordBatch":
         return RecordBatch(self.schema, [c.to_host() for c in self.columns], self.num_rows)
 

@@ -239,11 +239,13 @@ EVENT_LOG_MAX_BYTES = ConfEntry("spark.blaze.eventLog.maxBytes", 0, int)
 # time every Nth instrumented program instead of all of them (attributed
 # device times are scaled back up by the sampling factor in --report),
 # so attribution is cheap enough to leave on in production.  1 = time
-# every program (the full-fidelity profile default).  Caveat: on a
-# device that truly queues async work, the sampled program's drain also
-# waits out the N-1 unsampled programs queued ahead of it, so the
-# scaled device time is an UPPER BOUND, not an unbiased estimate (the
-# report flags it '~').
+# every program (the full-fidelity profile default); 0 = never block:
+# launches and compiles still attribute per label and --report prints
+# device time as "not sampled" (the armed mode a benchmark cell can run
+# in).  Caveat: on a device that truly queues async work, the sampled
+# program's drain also waits out the N-1 unsampled programs queued
+# ahead of it, so the scaled device time is an UPPER BOUND, not an
+# unbiased estimate (the report flags it '~').
 TRACE_SAMPLE_RATE = ConfEntry("spark.blaze.trace.sampleRate", 1, int)
 
 # OpenTelemetry export (runtime/otel.py): map each traced query's

@@ -44,7 +44,7 @@ from ..batch import Column, RecordBatch, bucket_capacity, concat_batches
 from ..exprs.compile import infer_dtype, lower
 from ..exprs.ir import Expr
 from ..io.batch_serde import deserialize_batch, serialize_batch
-from ..runtime import faults
+from ..runtime import faults, trace
 from ..runtime.context import TaskContext
 from ..runtime.memmgr import MemConsumer, MemManager, Spill, try_new_spill
 from ..schema import (
@@ -1270,7 +1270,7 @@ class AggExec(ExecNode):
         """One device reduce of a batch against schema -> state batch."""
         if self.groupings:
             cols, n_out = self._grouped_kernel(tuple(batch.columns), batch.num_rows)
-            return RecordBatch(self._state_schema, list(cols), int(n_out))
+            return RecordBatch(self._state_schema, list(cols), trace.read_scalar(n_out))
         cols = self._scalar_kernel(tuple(batch.columns), batch.num_rows)
         return RecordBatch(self._state_schema, list(cols), 1)
 
@@ -1595,7 +1595,7 @@ class _LazyAccState:
         return RecordBatch(self.schema, self.cols, self.hint).memory_size()
 
     def materialize(self) -> RecordBatch:
-        n = self.hint if not self.pending_check else int(self.n_dev)
+        n = self.hint if not self.pending_check else trace.read_scalar(self.n_dev)
         return RecordBatch(self.schema, list(self.cols), n)
 
 
@@ -1701,7 +1701,7 @@ class _FusedGroupedUpdate:
         from ..runtime import dispatch
 
         in_st, in_batch, out_st, out_cap = pending
-        n = int(out_st.n_dev)
+        n = trace.read_scalar(out_st.n_dev)
         dispatch.record(counter)
         if n <= out_cap:
             out_st.hint = n

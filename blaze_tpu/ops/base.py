@@ -13,10 +13,11 @@ datafusion-ext-plans implements them).  Differences, TPU-first:
 
 from __future__ import annotations
 
+import time
 from typing import Iterator, List, Optional, Sequence
 
 from ..batch import RecordBatch
-from ..runtime import monitor
+from ..runtime import dispatch, monitor, trace
 from ..runtime.context import TaskContext
 from ..runtime.metrics import MetricsSet
 from ..schema import Schema
@@ -150,6 +151,28 @@ class ExecNode:
         self.metrics.add(
             "output_bytes",
             sum(getattr(c.data, "nbytes", 0) for c in b.columns))
+
+    def _staged(self, host_batches) -> BatchStream:
+        """A scan's H2D staging: each host batch through ``to_device()``
+        under a ``scan_stage`` annotation — host time in the enqueue,
+        not the transfer.  The one per-batch site: it sums locally and
+        reaches the tally once, when the stream ends (``scan_stage_ns``,
+        ``scan_stage_n`` batches, ``h2d_bytes`` from shapes), with the
+        same nanoseconds as this node's ``input_io_time``."""
+        ns = n = nbytes = 0
+        try:
+            for b in host_batches:
+                with trace.annotation("scan_stage"):
+                    t0 = time.perf_counter_ns()
+                    out = b.to_device()
+                    ns += time.perf_counter_ns() - t0
+                n += 1
+                nbytes += b.host_nbytes()
+                yield out
+        finally:
+            if n:
+                dispatch.record_span("scan_stage", ns, n, h2d_bytes=nbytes)
+                self.metrics.add("input_io_time", ns)
 
     def _count_output(self, stream: BatchStream) -> BatchStream:
         for b in stream:

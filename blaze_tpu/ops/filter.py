@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ..batch import Column, RecordBatch
 from ..exprs.compile import host_eval, infer_dtype, lower, split_host_exprs
 from ..exprs.ir import Expr
+from ..runtime import trace
 from ..runtime.context import TaskContext
 from ..schema import DataType, Field, Schema
 from .base import BatchStream, ExecNode
@@ -165,7 +166,7 @@ class FilterExec(ExecNode):
                         cols.append(host_eval(sub, batch))
                     out_cols, count = self._kernel(
                         tuple(cols) + self._slot_args, batch.num_rows)
-                    n = int(count)  # one-scalar device->host sync
+                    n = trace.read_scalar(count)  # one-scalar device->host sync
                 if n == 0:
                     continue
                 out = RecordBatch(self.schema, list(out_cols), n)

@@ -25,6 +25,7 @@ from ...batch import Column, RecordBatch, bucket_capacity, concat_batches
 from ...exprs.compile import lower
 from ...exprs.hash import xxhash64_columns
 from ...exprs.ir import Expr
+from ...runtime import dispatch, trace
 from ...schema import DataType, Field, Schema
 from ..filter import compact_columns
 
@@ -328,7 +329,10 @@ class Joiner:
             _, counts = probe_counts(jmap_keys, pkeys, use_pallas=use_pallas)
             return jnp.sum(counts)
 
-        self._candidate_kernel = candidate_kernel
+        # under the dispatch counters like every cached kernel: the
+        # Joiner object is what cached_kernel holds, so its jitted
+        # closures are wrapped here
+        self._candidate_kernel = dispatch.instrument(candidate_kernel, "join_candidate")
 
         from functools import partial
 
@@ -362,18 +366,19 @@ class Joiner:
             all_cols, pair_count = compact_columns(probe_g + build_g, keep)
             return all_cols, pair_count, vcounts, matched_build
 
-        self._probe_kernel = probe_kernel
+        self._probe_kernel = dispatch.instrument(probe_kernel, "join_probe")
 
         @jax.jit
         def compact_kernel(cols, keep):
             return compact_columns(cols, keep)
 
-        self._compact_kernel = compact_kernel
+        self._compact_kernel = dispatch.instrument(compact_kernel, "join_compact")
 
     # ------------------------------------------------------------ build
 
     def build_map(self, batch: RecordBatch) -> JoinMap:
-        return build_join_map(batch, self._build_kernel)
+        with trace.annotation("join_build"):
+            return build_join_map(batch, self._build_kernel)
 
     # ------------------------------------------------------------ probe
 
@@ -381,7 +386,8 @@ class Joiner:
         self, jmap: JoinMap, batch: RecordBatch, state: JoinerState
     ) -> Optional[RecordBatch]:
         jt = self.join_type
-        cand = int(self._candidate_kernel(tuple(batch.columns), jmap.sorted_keys, batch.num_rows))
+        cand = self._candidate_kernel(tuple(batch.columns), jmap.sorted_keys, batch.num_rows)
+        cand = trace.read_scalar(cand)  # the round trip that picks out_cap
         out_cap = bucket_capacity(max(1, cand))
         pair_cols, pair_count, vcounts, matched = self._probe_kernel(
             tuple(batch.columns), jmap, batch.num_rows, out_cap
@@ -407,10 +413,10 @@ class Joiner:
                 return None  # emitted from build side at finish
             want = has if jt == JoinType.LEFT_SEMI else ~has
             out_cols, count = self._compact_kernel(tuple(batch.columns), want & live)
-            n = int(count)
+            n = trace.read_scalar(count)
             return RecordBatch(self.out_schema, list(out_cols), n) if n else None
 
-        n = int(pair_count)
+        n = trace.read_scalar(pair_count)
         parts: List[RecordBatch] = []
         if n:
             np_ = len(batch.columns)
@@ -421,7 +427,7 @@ class Joiner:
         if self._probe_outer:
             live = jnp.arange(batch.capacity) < batch.num_rows
             un_cols, un_count = self._compact_kernel(tuple(batch.columns), (vcounts == 0) & live)
-            un = int(un_count)
+            un = trace.read_scalar(un_count)
             if un:
                 nulls = _null_columns(self.build_schema, batch.capacity)
                 cols = (list(un_cols) + nulls) if self.probe_is_left else (nulls + list(un_cols))
@@ -445,7 +451,7 @@ class Joiner:
         else:  # RIGHT_ANTI or build-preserved outer
             want = ~matched & live
         out_cols, count = self._compact_kernel(tuple(jmap.batch.columns), want)
-        n = int(count)
+        n = trace.read_scalar(count)
         if not n:
             return None
         if jt in (JoinType.RIGHT_SEMI, JoinType.RIGHT_ANTI):

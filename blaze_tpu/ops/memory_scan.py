@@ -70,13 +70,11 @@ class MemoryScanExec(ExecNode):
 
         def stream():
             if partition < len(self._partitions):
-                for b in self._partitions[partition]:
-                    # device staging is the scan's own work: timing it
-                    # lets EXPLAIN ANALYZE attribute the H2D/layout
-                    # cost to this node instead of leaving it as
-                    # unattributed task wall
-                    with self.metrics.timer("input_io_time"):
-                        out = b.to_device()
+                # device staging is the scan's own work: input_io_time
+                # lets EXPLAIN ANALYZE attribute host time in the
+                # enqueue (to_device() is asynchronous: not the
+                # transfer) to this node
+                for out in self._staged(self._partitions[partition]):
                     self._record_batch(out)
                     # heartbeat hookpoint: every plan bottoms out in a
                     # scan, so a task beats per source batch even when

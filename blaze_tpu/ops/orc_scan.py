@@ -217,9 +217,9 @@ class OrcScanExec(ExecNode):
                                 )
                         b = RecordBatch(self._schema, cols, e - s)
                         self._record_batch(b)
-                        yield b.to_device()
+                        yield b
 
         from ..runtime.pipeline import maybe_pipelined
 
         # file decode overlaps downstream device compute (≙ rt.rs:100-133)
-        return maybe_pipelined(stream(), ctx, "orc_scan")
+        return maybe_pipelined(self._staged(stream()), ctx, "orc_scan")

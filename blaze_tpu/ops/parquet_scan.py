@@ -201,7 +201,7 @@ class ParquetScanExec(ExecNode):
                     full = RecordBatch(self._schema, cols, rg.rows)
                     if rg.rows <= self.batch_rows:
                         self.metrics.add("output_rows", rg.rows)
-                        yield full.to_device()
+                        yield full
                     else:
                         host = full
                         for s in range(0, rg.rows, self.batch_rows):
@@ -222,12 +222,12 @@ class ParquetScanExec(ExecNode):
                                 )
                             b = RecordBatch(self._schema, sl, e - s)
                             self._record_batch(b)
-                            yield b.to_device()
+                            yield b
 
         from ..runtime.pipeline import maybe_pipelined
 
         # file decode overlaps downstream device compute (≙ rt.rs:100-133)
-        return maybe_pipelined(stream(), ctx, "parquet_scan")
+        return maybe_pipelined(self._staged(stream()), ctx, "parquet_scan")
 
 
 from ..batch import _pad_1d  # noqa: E402  (used in stream closures)

@@ -36,7 +36,7 @@ from ..batch import Column, RecordBatch, _pad_1d, bucket_capacity, concat_batche
 from ..exprs.compile import lower
 from ..exprs.ir import Expr
 from ..io.batch_serde import deserialize_batch, serialize_batch
-from ..runtime import faults
+from ..runtime import faults, trace
 from ..runtime.context import TaskContext
 from ..runtime.memmgr import MemConsumer, Spill, try_new_spill
 from ..schema import Schema
@@ -281,8 +281,10 @@ class SortExec(ExecNode):
         with self.metrics.timer("sort_time"):
             merged = concat_batches(batches)
             run = self._sorted_batch(merged.to_device(), self.fetch)
-            words_all = np.asarray(self._key_words(tuple(run.columns), run.num_rows))
-        host = run.to_host()
+            words = self._key_words(tuple(run.columns), run.num_rows)
+        with trace.span("device_read"):
+            words_all = np.asarray(words)
+            host = run.to_host()
         sp = try_new_spill()
         bs = int(conf.BATCH_SIZE.get())
         try:
@@ -309,8 +311,10 @@ class SortExec(ExecNode):
     ) -> Iterator[Tuple[RecordBatch, np.ndarray]]:
         merged = concat_batches(batches)
         run = self._sorted_batch(merged.to_device(), self.fetch)
-        words_all = np.asarray(self._key_words(tuple(run.columns), run.num_rows))
-        host = run.to_host()
+        words = self._key_words(tuple(run.columns), run.num_rows)
+        with trace.span("device_read"):
+            words_all = np.asarray(words)
+            host = run.to_host()
         bs = int(conf.BATCH_SIZE.get())
         for start in range(0, run.num_rows, bs):
             n = min(bs, run.num_rows - start)
@@ -436,7 +440,9 @@ class SortExec(ExecNode):
                     if self.fetch is not None and batch.num_rows > self.fetch:
                         with self.metrics.timer("sort_time"):
                             batch = self._sorted_batch(batch, self.fetch)
-                    state.add(batch.to_host())
+                    with trace.span("device_read"):
+                        host = batch.to_host()
+                    state.add(host)
                 buffered, spills = state.freeze()
                 if not buffered and not spills:
                     return

@@ -18,7 +18,7 @@ from ..io.ipc_compression import (
     block_trailer, compress_frame, iter_blob_frames,
 )
 from ..ops.base import BatchStream, ExecNode
-from ..runtime import faults, integrity
+from ..runtime import dispatch, faults, integrity, trace
 from ..runtime.context import RESOURCES, TaskContext
 from ..schema import Schema
 
@@ -34,13 +34,17 @@ def _collect_blob(batches, site: str) -> bytes:
     frames: List[bytes] = []
     xor = 0
     for b in batches:
-        frame = compress_frame(serialize_batch(b), checksum_algo=algo)
+        # D2H + serialise of one batch; the child's compute, which the
+        # loop pulls, stays outside the span
+        with trace.span("exchange_write"):
+            frame = compress_frame(serialize_batch(b), checksum_algo=algo)
         if algo is not None:
             xor ^= struct.unpack("<BI", frame[-5:])[1]
         frames.append(frame)
     if algo is not None:
         frames.append(block_trailer(len(frames), xor, algo))
     blob = b"".join(frames)
+    dispatch.record("shuffle_bytes_written", len(blob))
     if faults.corrupt("broadcast.write", detail=site):
         blob = integrity.flip_byte(blob, 5 + max(0, (len(blob) - 16) // 2))
     return blob

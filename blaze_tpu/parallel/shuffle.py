@@ -45,7 +45,7 @@ from ..io.ipc_compression import (
 )
 from ..ops.base import BatchStream, ExecNode
 from ..runtime import monitor
-from ..runtime import diskmgr, faults, integrity, ledger, lockset, trace
+from ..runtime import diskmgr, dispatch, faults, integrity, ledger, lockset, trace
 from ..runtime.context import TaskContext
 from ..runtime.diskmgr import DiskExhaustedError
 from ..runtime.integrity import BlockCorruptionError
@@ -388,7 +388,9 @@ class ShuffleRepartitioner(MemConsumer):
             # actually committed" (a failed commit never consumes — or
             # vacuously emits — a corruption rule).
             integrity.flip_byte_in_file(data_path)
-        trace.emit("shuffle_write", bytes=sum(lengths),
+        nbytes = sum(lengths)
+        dispatch.record("shuffle_bytes_written", nbytes)
+        trace.emit("shuffle_write", bytes=nbytes,
                    blocks=sum(1 for ln in lengths if ln),
                    attempt=self.task_attempt_id, path=data_path)
         return lengths
@@ -485,8 +487,6 @@ def _commit_with_recovery(rep: "ShuffleRepartitioner", data_path: str,
       (drain-once + file-half retry) and escalates as the typed
       ``DiskExhaustedError``, which is deliberately NOT retried here.
     """
-    from ..runtime import dispatch
-
     try:
         # the corruption accounting wraps BOTH commit attempts: a
         # corrupt spill frame surfacing inside the disk-retry path
@@ -670,10 +670,11 @@ def _insert_host(rep: "ShuffleRepartitioner", schema: Schema, item) -> None:
     num_rows None means "resolve from counts" (the fused write path:
     the live row count after the fused chain IS the counts total)."""
     cols, counts, n = item
-    counts = np.asarray(counts)
-    if n is None:
-        n = int(counts.sum())
-    host = RecordBatch(schema, list(cols), n).to_host()
+    with trace.span("device_read"):
+        counts = np.asarray(counts)
+        if n is None:
+            n = int(counts.sum())
+        host = RecordBatch(schema, list(cols), n).to_host()
     rep.insert_sorted(host, counts)
 
 
@@ -731,7 +732,8 @@ class _AsyncInserter:
             if self._errs or self._aborted:
                 continue  # task failing/cancelled: discard, don't stage
             try:
-                with self._metrics.timer("shuffle_host_stage_time"):
+                with self._metrics.timer("shuffle_host_stage_time",
+                                         trace.span("exchange_write")):
                     _insert_host(self._rep, self._schema, item)
             except BaseException as e:  # noqa: BLE001 — surfaced to producer
                 self._errs.append(e)
@@ -918,9 +920,7 @@ class ShuffleWriterExec(ExecNode):
             # the chain as its bottom transform over the STATE schema;
             # pid exprs still evaluate over the chain OUTPUT schema
             agg = bottom
-            from ..runtime import dispatch as _dispatch
-
-            fin_raw = _dispatch.raw(agg._finalize_kernel)
+            fin_raw = dispatch.raw(agg._finalize_kernel)
             fns = [lambda cols, n, _f=fin_raw: (_f(cols, n), n)] + fns
             keys = (("agg_finalize",) + agg._kernel_key,) + keys
             slot_groups = ((),) + slot_groups
@@ -965,8 +965,6 @@ class ShuffleWriterExec(ExecNode):
             from ..ops.fusion import BufferPartitionExec
 
             self.children[0] = BufferPartitionExec(cur) if buffered else cur
-            from ..runtime import dispatch
-
             dispatch.record_max("fused_stage_len", len(ops) + 1)
 
     def _degraded_chain(self, cols, num_rows):
@@ -998,7 +996,6 @@ class ShuffleWriterExec(ExecNode):
 
         def stream():
             from ..batch import DeviceRing
-            from ..runtime import dispatch as _dispatch
             from ..runtime import oom as _oom
             from ..runtime.kernel_cache import cached_kernel
 
@@ -1072,7 +1069,7 @@ class ShuffleWriterExec(ExecNode):
                                         sorted_cols, counts = fw(
                                             cols_arg, self._fused_slot_args,
                                             batch.num_rows)
-                                    _dispatch.record("donated_buffers")
+                                    dispatch.record("donated_buffers")
                                 elif isinstance(part_t, RoundRobinPartitioning):
                                     sorted_cols, counts, rr_dev = self._fused_write(
                                         tuple(batch.columns) + self._fused_slot_args,
@@ -1106,7 +1103,7 @@ class ShuffleWriterExec(ExecNode):
                                           RoundRobinPartitioning):
                                 # resync the device-resident offset so
                                 # the host-side path continues exactly
-                                rr = int(rr_dev)
+                                rr = trace.read_scalar(rr_dev)
                     if item is None:
                         with self.metrics.timer("elapsed_compute"):
                             cols, n = list(batch.columns), batch.num_rows
@@ -1145,7 +1142,8 @@ class ShuffleWriterExec(ExecNode):
                         for due in ring.put(item):
                             inserter.put(due)
                     else:
-                        _insert_host(rep, out_schema, item)
+                        with trace.span("exchange_write"):
+                            _insert_host(rep, out_schema, item)
                 if inserter is not None:
                     for due in ring.flush():
                         inserter.put(due)
@@ -1158,7 +1156,8 @@ class ShuffleWriterExec(ExecNode):
                     # overwrite the winner's committed output with an
                     # empty/partial one (chaos-sweep-found)
                     return
-                with self.metrics.timer("output_io_time"):
+                with self.metrics.timer("output_io_time",
+                                        trace.span("exchange_write")):
                     self.partition_lengths = _commit_with_recovery(
                         rep, self.data_path, self.index_path)
                 self.metrics.add("data_size", sum(self.partition_lengths))
@@ -1256,8 +1255,6 @@ class IpcReaderExec(ExecNode):
         same path is QUARANTINED (renamed ``.corrupt``, kept for
         forensics, its ``.index`` dropped) so recovery regenerates it
         in full instead of a third identical failure."""
-        from ..runtime import dispatch
-
         mid = block_map_id(block)
         path = None if isinstance(block, bytes) else block[0]
         site = ("broadcast.fetch"
@@ -1281,7 +1278,8 @@ class IpcReaderExec(ExecNode):
     def _read_blocks(self, blocks, partition: int, ctx: TaskContext,
                      fetched: dict) -> BatchStream:
         for block in blocks:
-            with self.metrics.timer("shuffle_read_total_time"):
+            with self.metrics.timer("shuffle_read_total_time",
+                                    trace.span("exchange_read")):
                 faults.hit(
                     "shuffle.fetch",
                     attempt=ctx.task_attempt_id,
@@ -1318,18 +1316,23 @@ class IpcReaderExec(ExecNode):
                     len(block) if isinstance(block, bytes) else block[2]
                 )
             for p in payloads:
-                try:
-                    # decode stays streaming (one payload at a
-                    # time) but INSIDE the fetch guard: a
-                    # committed-but-corrupt block can survive
-                    # decompress and only fail here — still bad
-                    # producer bytes, not a transient compute error
-                    b = deserialize_batch(p, self._schema)
-                except (struct.error, ValueError, EOFError) as e:
-                    raise self._fetch_failed(block, partition, e) from e
-                if b.num_rows:
+                # decode + H2D of one payload: the span again, closed
+                # before the yield
+                with trace.span("exchange_read"):
+                    try:
+                        # decode stays streaming (one payload at a
+                        # time) but INSIDE the fetch guard: a
+                        # committed-but-corrupt block can survive
+                        # decompress and only fail here — still bad
+                        # producer bytes, not a transient compute error
+                        b = deserialize_batch(p, self._schema)
+                    except (struct.error, ValueError, EOFError) as e:
+                        raise self._fetch_failed(block, partition, e) from e
+                    if not b.num_rows:
+                        continue
                     self._record_batch(b)
-                    yield b.to_device()
+                    b = b.to_device()
+                yield b
 
 
 class LocalShuffleManager:

@@ -7,9 +7,18 @@ tree said so.  Every jitted operator kernel (they all register through
 ``runtime.kernel_cache.cached_kernel``) is wrapped here so that
 
 - ``xla_dispatches``   — program launches (one per kernel call),
+- ``launch_ns``/``launch_n`` — host time inside those calls (the
+                         enqueue, not the device's run) and their
+                         count, which equals ``xla_dispatches``,
 - ``xla_compiles``     — calls that triggered a fresh XLA compile
                          (detected via the jit cache-size delta),
-- ``compile_ms``       — wall time of those compiling calls,
+- ``compile_ms``       — host time of those compiling calls (printed
+                         by ``--warmup``'s cold line and
+                         ``chip_smoke.py``; ``--report`` prints the
+                         kernel capture's ``compile_ns`` instead),
+- ``<span>_ns``/``<span>_n`` — host time and openings of every
+                         ``trace.span`` (:func:`record_span`), with the
+                         bytes ``h2d_bytes``/``shuffle_bytes_written``,
 - ``fused_stage_len``  — LONGEST fused segment built (a max-gauge via
                          :func:`record_max`, recorded by ``ops.fusion``
                          — plans are rebuilt per task/iteration, so a
@@ -66,6 +75,21 @@ def record(name: str, v: int = 1) -> None:
         _GLOBAL[name] = _GLOBAL.get(name, 0) + int(v)
         for c in _CAPTURES:
             c[name] = c.get(name, 0) + int(v)
+
+
+def record_span(name: str, ns: int, n: int = 1, **more: int) -> None:
+    """Host time in the tally: ``<name>_ns`` += ``ns``, ``<name>_n`` +=
+    ``n`` and every ``more`` counter (what the time was spent on: a
+    launch is a dispatch, a staged batch is bytes), under ONE lock
+    acquisition.  ``trace.span`` closes here."""
+    k_ns, k_n = name + "_ns", name + "_n"
+    with _LOCK:
+        lockset.check(_TALLY, "_GLOBAL", "_CAPTURES")
+        for c in (_GLOBAL, *_CAPTURES):
+            c[k_ns] = c.get(k_ns, 0) + ns
+            c[k_n] = c.get(k_n, 0) + n
+            for k, v in more.items():
+                c[k] = c.get(k, 0) + v
 
 
 def record_max(name: str, v: int) -> None:
@@ -337,17 +361,18 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
     size = getattr(fn, "_cache_size", None)
     if size is None:  # not a jit function (host helper): count calls only
         def plain(*a, **k):
-            record("xla_dispatches")
-            if not trace._KERNEL_TIMING:
-                return fn(*a, **k)
             t0 = time.perf_counter_ns()
             out = fn(*a, **k)
+            ns = time.perf_counter_ns() - t0
+            record_span("launch", ns, xla_dispatches=1)
+            if not trace._KERNEL_TIMING:
+                return out
             bytes_est = flops_est = 0
             if perf._ARMED:  # one bool read disarmed (perf contract)
                 bytes_est, flops_est = perf._estimate(a, k, out)
                 record("hbm_bytes_est", bytes_est)
                 record("flops_est", flops_est)
-            trace.record_kernel(label, 0, time.perf_counter_ns() - t0, 0,
+            trace.record_kernel(label, 0, ns, 0,
                                 bytes_est=bytes_est, flops_est=flops_est)
             return out
 
@@ -364,17 +389,21 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
 
     def wrapper(*a, **k):
         if not trace._KERNEL_TIMING:  # pre-existing non-blocking path
-            t0 = time.perf_counter()
+            # host time inside the call, in the same tally update as
+            # the dispatch.  Counters only: JAX's own PjitFunction
+            # event already marks the call in a trace
+            t0 = time.perf_counter_ns()
             out = _oom_call(fn, label, *a, **k)
+            ns = time.perf_counter_ns() - t0
+            record_span("launch", ns, xla_dispatches=1)
             after = size()
-            record("xla_dispatches")
             if after > state["seen"]:
                 with state_lock:
                     delta = after - state["seen"]
                     if delta > 0:
                         state["seen"] = after
                         record("xla_compiles", delta)
-                        record("compile_ms", int((time.perf_counter() - t0) * 1000))
+                        record("compile_ms", int(ns / 1e6))
             return out
         # traced: split the call into launch vs device drain.  Async
         # dispatch returns once the program is enqueued, so the
@@ -385,14 +414,17 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
         # Under spark.blaze.trace.sampleRate=N only every Nth program
         # pays the block (trace.sample_kernel); unsampled calls still
         # count and still attribute their launch overhead, and the
-        # report scales device time back up by programs/timed.
+        # report scales device time back up by programs/timed.  At
+        # sampleRate=0 no program blocks: the event log is armed and
+        # the device is not serialised.
         import jax
 
         t0 = time.perf_counter_ns()
         out = _oom_call(fn, label, *a, **k)
         t1 = time.perf_counter_ns()
+        ns = t1 - t0
+        record_span("launch", ns, xla_dispatches=1)
         after = size()
-        record("xla_dispatches")
         compiled = False
         if after > state["seen"]:
             with state_lock:
@@ -401,7 +433,7 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
                     state["seen"] = after
                     compiled = True
                     record("xla_compiles", delta)
-                    record("compile_ms", int((t1 - t0) / 1e6))
+                    record("compile_ms", int(ns / 1e6))
         timed = trace.sample_kernel()
         if timed:
             jax.block_until_ready(out)
@@ -420,8 +452,8 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
         trace.record_kernel(
             label,
             device_ns=device_ns,
-            dispatch_ns=0 if compiled else t1 - t0,
-            compile_ns=t1 - t0 if compiled else 0,
+            dispatch_ns=0 if compiled else ns,
+            compile_ns=ns if compiled else 0,
             timed=timed,
             bytes_est=bytes_est,
             flops_est=flops_est,
@@ -430,7 +462,7 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
         # carry a meaningful device/dispatch split (compiles would
         # read as huge dispatch overhead and trigger runaway growth)
         if timed and not compiled and autotune_enabled():
-            autotune_observe(label, device_ns, t1 - t0)
+            autotune_observe(label, device_ns, ns)
         return out
 
     wrapper.__wrapped__ = fn

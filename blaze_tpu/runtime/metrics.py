@@ -108,8 +108,17 @@ class MetricsSet:
             self.add(k, v)
 
     @contextmanager
-    def timer(self, name: str):
-        """Accumulates nanoseconds under ``name`` (elapsed_compute etc.)."""
+    def timer(self, name: str, span=None):
+        """Accumulates nanoseconds under ``name`` (elapsed_compute etc.).
+        With a ``trace.span`` the timer opens it around the block and
+        adds the span's own nanoseconds: one clock for both."""
+        if span is not None:
+            try:
+                with span:
+                    yield
+            finally:
+                self.add(name, span.ns)
+            return
         t0 = time.perf_counter_ns()
         try:
             yield

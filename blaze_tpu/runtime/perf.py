@@ -323,6 +323,11 @@ def sum_kernel_rows(kernels: Dict[str, Dict[str, int]]) -> Dict[str, int]:
 
     return {
         "programs": sum(v.get("programs", 0) for v in kernels.values()),
+        # programs that paid the block-until-ready: 0 under
+        # spark.blaze.trace.sampleRate=0, where device time is not
+        # sampled (which is not the same as 0)
+        "timed": sum(v.get("timed", v.get("programs", 0))
+                     for v in kernels.values()),
         "device_ns": sum(trace.scaled_device_ns(v)
                          for v in kernels.values()),
         "dispatch_ns": sum(v.get("dispatch_ns", 0)
@@ -367,6 +372,7 @@ def query_perf(events: List[Dict[str, Any]],
                    totals["bytes_est"], totals["flops_est"], peaks)
     doc.update(
         programs=totals["programs"],
+        timed=totals["timed"],
         device_ns=totals["device_ns"],
         dispatch_ns=totals["dispatch_ns"],
         compile_ns=totals["compile_ns"],
@@ -618,12 +624,19 @@ def render_explain(events: List[Dict[str, Any]],
             f"  !! query ended {status.upper()} — metrics below cover "
             f"only what ran before the terminal event")
     p = doc["perf"]
+    # programs ran and none paid the block (sampleRate=0): device time
+    # is not a 0, and what is reckoned from it is not known either
+    if p["timed"] or not p["programs"]:
+        bound, device = p["bound"], _fmt_ns(p["device_ns"])
+        hbm, mfu = f"{100 * p['hbm_util']:.2f}%", f"{100 * p['mfu_est']:.4f}%"
+    else:
+        bound, device, hbm, mfu = "n/a", "not sampled", "n/a", "n/a"
     lines.append(
-        f"perf: {p['bound']}  programs={p['programs']}  "
-        f"device={_fmt_ns(p['device_ns'])}  "
+        f"perf: {bound}  programs={p['programs']}  "
+        f"device={device}  "
         f"dispatch={_fmt_ns(p['dispatch_ns'])}  "
-        f"hbm_util={100 * p['hbm_util']:.2f}%  "
-        f"mfu_est={100 * p['mfu_est']:.4f}%  "
+        f"hbm_util={hbm}  "
+        f"mfu_est={mfu}  "
         f"(peaks: {p['peak']['device']}, "
         f"{p['peak']['hbm_gbps']:g} GB/s, {p['peak']['tflops']:g} TF)")
     at = doc.get("autotune") or {}

@@ -655,12 +655,14 @@ def run_stages(
         resources = ScopedResources(RESOURCES, remap) if remap else None
         try:
             batches: List = []
-            drain(stage, t,
-                  from_proto.run_task(td, task_attempt_id=attempt,
-                                      resources=resources,
-                                      cancel_event=cancel_event,
-                                      on_beat=on_beat),
-                  batches, delta)
+            with trace.annotation("task", stage=stage.stage_id, partition=t,
+                                  attempt=attempt):
+                drain(stage, t,
+                      from_proto.run_task(td, task_attempt_id=attempt,
+                                          resources=resources,
+                                          cancel_event=cancel_event,
+                                          on_beat=on_beat),
+                      batches, delta)
             if cancel_event is not None and cancel_event.is_set():
                 # a cancelled LOSER exits cleanly without consuming
                 # its one-shot registrations — drop them (pop-if-
@@ -798,9 +800,16 @@ def run_stages(
             yielded = False
             try:
                 deadline = policy.deadline()
-                for b in from_proto.run_task(
+                # the task annotation closes before every yield and
+                # re-opens for the next pull
+                ids = dict(stage=stage.stage_id, partition=t, attempt=attempt)
+                done = object()
+                with trace.annotation("task", **ids):
+                    it = iter(from_proto.run_task(
                         td, task_attempt_id=attempt,
-                        cancel_event=scope.event if scope else None):
+                        cancel_event=scope.event if scope else None))
+                    b = next(it, done)
+                while b is not done:
                     # the pulled batch is a cancellation checkpoint
                     # BEFORE it is surfaced to the caller
                     if scope is not None:
@@ -815,6 +824,8 @@ def run_stages(
                     yielded = True
                     progress.add_batch(b)
                     yield b
+                    with trace.annotation("task", **ids):
+                        b = next(it, done)
                 if scope is not None:
                     # a cancelled operator STOPS yielding instead of
                     # raising (the cooperative seams), so a cancel that

@@ -30,6 +30,13 @@ OFF by default: the disarmed check is one module-global bool read per
 kernel call (``_KERNEL_TIMING``) and one per lifecycle site
 (``enabled()``), with zero allocation.
 
+Host spans (:class:`span`) are a different thing from events and are
+always on: a ``jax.profiler.TraceAnnotation`` on the profiler's clock
+plus ``<name>_ns``/``<name>_n`` in the dispatch tally, so a
+``dispatch.capture()`` reads where a query's host time went with this
+log disarmed.  ``spark.blaze.trace.sampleRate=0`` arms the log and the
+per-label attribution without any block-until-ready.
+
 Consumers: the stage scheduler emits lifecycle events
 (stage submit/complete, task attempt start/end/retry/timeout,
 fetch-failure -> map-stage rerun), runtime.faults records each injected
@@ -52,6 +59,8 @@ import threading
 import time
 import uuid
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from .. import conf
 from ..analysis.locks import make_lock
@@ -237,7 +246,9 @@ def _load() -> None:
         _armed = bool(conf.TRACE_ENABLE.get())
         d = str(conf.EVENT_LOG_DIR.get() or "")
         _dir = d or os.path.join(tempfile.gettempdir(), "blaze_eventlog")
-        _sample_rate = max(1, int(conf.TRACE_SAMPLE_RATE.get()))
+        # 0 = attribute launches and compiles per label and never
+        # block (the armed mode a benchmark cell can run in)
+        _sample_rate = max(0, int(conf.TRACE_SAMPLE_RATE.get()))
         _max_bytes = max(0, int(conf.EVENT_LOG_MAX_BYTES.get()))
         _loaded = True
 
@@ -428,6 +439,60 @@ def query(query_id: str, trace_id: Optional[str] = None,
             _current_path = _path or _default_path
 
 
+# ---------------------------------------------------------- host spans
+
+def annotation(name: str, **ids: Any) -> TraceAnnotation:
+    """``jax.profiler.TraceAnnotation("blaze:<name>", **ids)``: under a
+    profiler session the block lands in the same ``.xplane.pb`` as the
+    ``XLA Ops`` line, on the profiler's one clock, nested under whatever
+    its thread already has open (``stage``/``partition``/``attempt`` are
+    the shared ids); with no session it is a flag check.  Alone it marks
+    the enclosing spans no counter is read from (``task``,
+    ``join_build``): they give a trace its ids and nesting."""
+    return TraceAnnotation("blaze:" + name, **ids)
+
+
+class span:
+    """One host span of the program: ``with trace.span("task_decode"):``.
+
+    It does two things and nothing else: it opens :func:`annotation`,
+    and on exit it adds the elapsed host nanoseconds to the dispatch
+    tally as ``<name>_ns`` and 1 to ``<name>_n`` (one lock
+    acquisition), so every ``dispatch.capture()`` sees where a query's
+    host time went with the event log off.  ``ns`` holds the elapsed
+    time after exit: ``MetricsSet.timer(name, span)`` reads it, so a
+    boundary that has both has one clock.
+
+    Never leave one open across a ``yield`` to the consumer."""
+
+    __slots__ = ("_name", "_ann", "_t0", "ns")
+
+    def __init__(self, name: str, **ids: Any):
+        self._name = name
+        self._ann = annotation(name, **ids)
+        self.ns = 0
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        from .dispatch import record_span  # dispatch imports this module
+
+        self.ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        record_span(self._name, self.ns)
+
+
+def read_scalar(x) -> int:
+    """``int(x)`` of a device scalar under the ``device_read`` span:
+    the host blocks here until the program that produces ``x`` has
+    run — the round trips that pick an ``out_cap`` or a row count."""
+    with span("device_read"):
+        return int(x)
+
+
 # -------------------------------------------------- kernel attribution
 
 @contextlib.contextmanager
@@ -476,10 +541,14 @@ def sample_kernel() -> bool:
     device timing?  True for every call at sampleRate=1 (the default
     full-fidelity profile); at N>1 true for every Nth program, so an
     armed production trace costs one device serialization per N
-    programs instead of per program."""
+    programs instead of per program; never at 0 (launches and
+    compiles still attribute per label, the device is not serialised
+    and its time reads as not sampled)."""
     rate = _sample_rate
-    if rate <= 1:
+    if rate == 1:
         return True
+    if rate <= 0:
+        return False
     global _sample_counter
     with _sample_lock:
         lockset.check(_LOG, "_sample_counter")

@@ -76,6 +76,19 @@ def _pair_requests(events, is_request, accept):
     return pairs, unpaired
 
 
+def _device(text: str, programs: int, timed: int,
+            absent: str = "not sampled") -> str:
+    """A rendered device time (or what is reckoned from one), or
+    ``absent`` where programs ran and none paid the block-until-ready
+    (spark.blaze.trace.sampleRate=0): that is not a measured 0."""
+    return text if timed or not programs else absent
+
+
+def _timed(kernels) -> int:
+    return sum(v.get("timed", v.get("programs", 0))
+               for v in (kernels or {}).values())
+
+
 def _fmt_s(ns: float) -> str:
     return f"{ns / 1e9:.3f}s"
 
@@ -647,7 +660,8 @@ def render(events: List[Dict[str, Any]]) -> str:
     if completes:
         lines.append("")
         lines.append("stage timeline (device vs dispatch-floor vs compile):")
-        total = {"wall": 0, "dev": 0, "disp": 0, "comp": 0}
+        total = {"wall": 0, "dev": 0, "disp": 0, "comp": 0,
+                 "programs": 0, "timed": 0}
         for e in completes:
             sid = e.get("stage_id")
             sub = submits.get(sid, {})
@@ -660,18 +674,21 @@ def render(events: List[Dict[str, Any]]) -> str:
             total["dev"] += dev
             total["disp"] += disp
             total["comp"] += comp
+            programs, timed = e.get("programs", 0), _timed(e.get("kernels"))
+            total["programs"] += programs
+            total["timed"] += timed
             lines.append(
                 f"  stage {sid} {e.get('kind', '?'):9s} +{start:7.3f}s "
                 f"wall {_fmt_s(wall):>9s}  tasks {e.get('n_tasks', '?')}  "
-                f"programs {e.get('programs', 0):>4d}  "
-                f"device {_fmt_s(dev)} ({_pct(dev, wall)})  "
+                f"programs {programs:>4d}  "
+                f"device {_device(f'{_fmt_s(dev)} ({_pct(dev, wall)})', programs, timed)}  "
                 f"dispatch {_fmt_s(disp)} ({_pct(disp, wall)})  "
                 f"compile {_fmt_s(comp)}"
                 + ("" if e.get("status", "ok") == "ok" else "  <-- FAILED")
             )
         unattr = max(0, total["wall"] - total["dev"] - total["disp"] - total["comp"])
         lines.append(
-            f"  total: device {_pct(total['dev'], total['wall'])}  "
+            f"  total: device {_device(_pct(total['dev'], total['wall']), total['programs'], total['timed'])}  "
             f"dispatch-floor {_pct(total['disp'], total['wall'])}  "
             f"compile {_pct(total['comp'], total['wall'])}  "
             f"host/other {_pct(unattr, total['wall'])} of "
@@ -710,12 +727,13 @@ def render(events: List[Dict[str, Any]]) -> str:
                 sampled = v["timed"] < v["programs"]
                 dev = _trace.scaled_device_ns(v)
                 kp = perf.kernel_perf(v, qp["peak"])
+                hbm = f"{100 * kp['hbm_util']:.2f}%  {kp['bound']}"
                 lines.append(
                     f"  {label:24s} programs {v['programs']:>5d}  "
-                    f"device {('~' if sampled else '') + _fmt_s(dev):>9s}  "
+                    f"device {_device(('~' if sampled else '') + _fmt_s(dev), v['programs'], v['timed']):>9s}  "
                     f"dispatch {_fmt_s(v['dispatch_ns']):>9s}  "
                     f"compile {_fmt_s(v['compile_ns'])}  "
-                    f"hbm {100 * kp['hbm_util']:.2f}%  {kp['bound']}"
+                    f"hbm {_device(hbm, v['programs'], v['timed'], 'n/a')}"
                     + (f"  (timed {v['timed']}/{v['programs']})"
                        if sampled else "")
                 )

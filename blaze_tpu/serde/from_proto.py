@@ -385,15 +385,15 @@ def run_task(task_def_bytes: bytes, task_attempt_id: int = 0,
     cooperatively, and ``on_beat`` is a liveness callback fired at the
     heartbeat cadence from inside the plan drive — the wedge detector's
     clock, armed even when tracing and the monitor are off."""
-    from ..runtime import faults
+    from ..ops.fusion import optimize_plan
+    from ..runtime import faults, trace
     from ..runtime.context import TaskContext
 
-    td = pb.TaskDefinition()
-    td.ParseFromString(task_def_bytes)
-    from ..ops.fusion import optimize_plan
-
-    faults.hit("task.compute", attempt=task_attempt_id, detail=td.task_id)
-    plan = optimize_plan(plan_from_proto(td.plan))
+    with trace.span("task_decode"):
+        td = pb.TaskDefinition()
+        td.ParseFromString(task_def_bytes)
+        faults.hit("task.compute", attempt=task_attempt_id, detail=td.task_id)
+        plan = optimize_plan(plan_from_proto(td.plan))
     if _log.isEnabledFor(logging.DEBUG):
         # ≙ the reference's native plan display at task start
         # (blaze/src/exec.rs:101-106)
@@ -405,7 +405,7 @@ def run_task(task_def_bytes: bytes, task_attempt_id: int = 0,
         resources=resources, cancel_event=cancel_event,
     )
     stream = plan.execute(td.partition, ctx)
-    from ..runtime import monitor, trace
+    from ..runtime import monitor
 
     if not trace.enabled() and not monitor.enabled() and on_beat is None:
         return stream

@@ -37,6 +37,20 @@ from blaze_tpu.spark import BlazeSparkSession
 
 import spark_fixtures as F
 
+
+@pytest.fixture(autouse=True)
+def _own_tempdir(tmp_path, monkeypatch):
+    """``ledger.leak_audit()`` globs the temp directory for spill files,
+    and the test workers share one: a sibling's live spill would read
+    as this test's leak.  Each test here gets its own (pooled workers
+    inherit ``TMPDIR``); the audit itself is as strict as it was."""
+    import tempfile
+
+    own = tmp_path / "tmp"
+    own.mkdir()
+    monkeypatch.setenv("TMPDIR", str(own))
+    monkeypatch.setattr(tempfile, "tempdir", None)  # gettempdir() re-reads
+
 SCHEMA = Schema([
     Field("l_quantity", DataType.int64()),
     Field("l_extendedprice", DataType.int64()),

@@ -875,7 +875,9 @@ def _source_metric_literals():
     """Every metric-name string literal in blaze_tpu source: first-arg
     literals of MetricsSet.add/set/timer and dispatch.record/record_max
     (+ counter= kwargs), plus the histogram/timer observation sites
-    (observe_hist / record_timer) that carry full family names."""
+    (observe_hist / record_timer) that carry full family names, plus
+    what a host span tallies: ``trace.span("x")`` / ``record_span("x")``
+    give ``x_ns`` and ``x_n``, and ``record_span("x", ns, y=v)`` ``y``."""
     names = set()
     hist_re = re.compile(
         r'(?:observe_hist|record_timer)\(\s*"([a-z][a-z_0-9]*)"')
@@ -903,6 +905,12 @@ def _source_metric_literals():
             for m in re.finditer(
                     r'(?:\.(?:add|set|timer)\(|record\(|record_max\(|counter=)'
                     r'\s*"([a-z][a-z_0-9]*)"', src):
+                names.add(m.group(1))
+            for m in re.finditer(
+                    r'\b(?:span|record_span)\(\s*"([a-z][a-z_0-9]*)"', src):
+                names.update((m.group(1) + "_ns", m.group(1) + "_n"))
+            for m in re.finditer(
+                    r'\brecord_span\("[a-z_]+",[^)=]*\b([a-z][a-z_0-9]*)=', src):
                 names.add(m.group(1))
     return names
 

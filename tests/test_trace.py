@@ -627,3 +627,197 @@ def test_event_log_rotation_never_clobbers_prior_segments(tmp_path):
             f"{len(watermarks)}/60 events in {p}")
         # exactly ONE trace id per file — the OTLP export invariant
         assert len({e["trace_id"] for e in events if "trace_id" in e}) == 1
+
+
+# ------------------------- 8. host spans + host-time counters (PR 27)
+
+def _host_nbytes(batch):
+    """What to_device() stages for one host batch, reckoned apart from
+    RecordBatch.host_nbytes: every numpy buffer's nbytes."""
+    import numpy as np
+
+    return sum(a.nbytes for c in batch.columns
+               for a in (c.data, c.validity, c.lengths)
+               if isinstance(a, np.ndarray))
+
+
+#: query -> the tables its plan scans
+_SCANNED = {"q6": ("lineitem",), "q3": ("customer", "orders", "lineitem")}
+
+
+@pytest.mark.parametrize("q", sorted(_SCANNED))
+def test_span_counters_with_tracing_off(data, q, monkeypatch):
+    """A scheduler run under dispatch.capture() with tracing OFF tallies
+    the host spans exactly: one task_decode per task, one scan_stage per
+    source batch with its bytes from shapes, one launch per dispatch,
+    and what the exchanges wrote is what they read."""
+    conf.TRACE_ENABLE.set(False)
+    trace.reset()
+
+    def poisoned(*a, **k):  # pragma: no cover - failure path
+        raise AssertionError("kernel timing entered with tracing disabled")
+
+    monkeypatch.setattr(trace, "record_kernel", poisoned)
+    n_parts = 2
+    scans = _scans(data, n_parts, 16384)
+    host_batches = [b for t in _SCANNED[q] for p in scans[t]._partitions for b in p]
+    stages, manager = split_stages(build_query(q, scans, n_parts))
+    with dispatch.capture() as c:
+        rows = sum(b.num_rows for b in run_stages(stages, manager, max_task_attempts=1))
+    assert rows > 0
+    n_tasks = sum(s.n_tasks for s in stages)
+    assert c["task_decode_n"] == n_tasks > 1
+    assert c["scan_stage_n"] == len(host_batches) > n_parts
+    assert c["h2d_bytes"] == sum(_host_nbytes(b) for b in host_batches) > 0
+    assert c["launch_n"] == c["xla_dispatches"] > 0
+    assert c["shuffle_bytes_written"] > 0
+    assert c["exchange_write_n"] > 0 and c["exchange_read_n"] > 0
+    assert c["device_read_n"] > 0
+    spans = {k for k in c if k.endswith("_ns") and k[:-3] + "_n" in c}
+    # task and join_build are annotations only: no metric reads them
+    assert spans == {"task_decode_ns", "scan_stage_ns", "launch_ns",
+                     "device_read_ns", "exchange_write_ns", "exchange_read_ns"}
+    for k in spans:
+        assert c[k] > 0, k
+    # tracing stayed off: the span is not an event and not a query span
+    assert trace.counters() == {"events": 0, "spans": 0}
+    assert trace.current_path() is None
+
+
+def test_span_is_disarmed_safe_and_tallies_its_host_time():
+    conf.TRACE_ENABLE.set(False)
+    trace.reset()
+    with dispatch.capture() as c:
+        with trace.span("task_decode", stage=3, partition=1, attempt=0) as sp:
+            pass
+        assert trace.read_scalar(7) == 7
+        with trace.annotation("task", stage=3, partition=1, attempt=0):
+            pass  # an annotation alone tallies nothing
+    assert (c["task_decode_n"], c["task_decode_ns"]) == (1, sp.ns)
+    assert sp.ns > 0
+    assert c["device_read_n"] == 1 and c["device_read_ns"] > 0
+    assert set(c) == {"task_decode_ns", "task_decode_n",
+                      "device_read_ns", "device_read_n"}
+    assert trace.counters() == {"events": 0, "spans": 0}
+
+
+def test_timer_that_opens_a_span_has_one_clock():
+    """MetricsSet.timer(name, span): the operator's timer and the tally
+    hold the same nanoseconds, when the body raises too."""
+    from blaze_tpu.runtime.metrics import MetricsSet
+
+    m = MetricsSet()
+    with dispatch.capture() as c:
+        with m.timer("output_io_time", trace.span("exchange_write")):
+            pass
+        first = c["exchange_write_ns"]
+        assert m.get("output_io_time") == first > 0
+        with pytest.raises(KeyError):
+            with m.timer("output_io_time", trace.span("exchange_write")):
+                raise KeyError("x")
+    assert m.get("output_io_time") == c["exchange_write_ns"] > first
+    assert c["exchange_write_n"] == 2
+
+
+def test_staged_scan_sums_locally_and_records_once(data):
+    """The per-batch site: ExecNode._staged reaches the tally when its
+    stream ends, with its batches, their bytes and the nanoseconds the
+    scan's own input_io_time gets."""
+    scan = _scans(data, 1, 16384)["lineitem"]
+    host = scan._partitions[0]
+    with dispatch.capture() as c:
+        it = scan._staged(host)
+        next(it)
+        assert "scan_stage_n" not in c
+        assert sum(1 for _ in it) == len(host) - 1
+    assert c["scan_stage_n"] == len(host) > 1
+    assert c["h2d_bytes"] == sum(_host_nbytes(b) for b in host)
+    assert c["scan_stage_ns"] == scan.metrics.get("input_io_time") > 0
+
+
+def test_span_closes_and_tallies_when_its_body_raises():
+    with dispatch.capture() as c:
+        with pytest.raises(KeyError):
+            with trace.span("exchange_read"):
+                raise KeyError("x")
+    assert c["exchange_read_n"] == 1 and c["exchange_read_ns"] > 0
+
+
+def test_joiner_kernels_are_under_the_dispatch_counters():
+    """candidate/probe/compact launches are counted, attributed to
+    their own labels, and a repeated probe compiles nothing."""
+    from blaze_tpu.batch import batch_from_pydict
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins.core import JoinerState, JoinType, cached_joiner
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    ls = Schema([Field("k", DataType.int64()), Field("a", DataType.int64())])
+    rs = Schema([Field("k2", DataType.int64()), Field("b", DataType.int64())])
+    probe = batch_from_pydict({"k": [1, 2, 3, 4], "a": [10, 20, 30, 40]}, ls)
+    build = batch_from_pydict({"k2": [2, 4, 4], "b": [1, 2, 3]}, rs)
+    j = cached_joiner(ls, rs, [col("k")], [col("k2")], JoinType.LEFT, True)
+    jmap = j.build_map(build)
+
+    def probe_once():
+        out = j.probe_batch(jmap, probe, JoinerState())
+        return out.num_rows
+
+    assert probe_once() == 5  # 2->1, 4->2 matches, 1 and 3 unmatched
+    with dispatch.capture() as c, trace.kernel_capture() as kc:
+        assert probe_once() == 5
+    assert {"join_candidate", "join_probe", "join_compact"} <= set(kc)
+    assert c["xla_dispatches"] >= 3 and c["launch_n"] == c["xla_dispatches"]
+    assert c.get("xla_compiles", 0) == 0
+    assert c["device_read_n"] == 3  # candidate total, pair count, unmatched count
+
+
+def test_q03_joiner_launches_counted_and_warm_run_compiles_nothing(data):
+    def run_once():
+        stages, manager = split_stages(build_query("q3", _scans(data, 2, 16384), 2))
+        with dispatch.capture() as c, trace.kernel_capture() as kc:
+            rows = sum(b.num_rows for b in run_stages(stages, manager, max_task_attempts=1))
+        assert rows > 0
+        return c, kc
+
+    run_once()
+    c, kc = run_once()
+    assert c.get("xla_compiles", 0) == 0
+    joiner = sum(kc[k]["programs"] for k in ("join_candidate", "join_probe"))
+    assert joiner > 0
+    # every program the kernel capture attributes is a counted dispatch
+    assert sum(v["programs"] for v in kc.values()) == c["xla_dispatches"]
+
+
+def test_sample_rate_zero_arms_the_log_without_blocking(data, tmp_path, monkeypatch):
+    """spark.blaze.trace.sampleRate=0: the event log is written, every
+    launch and compile is attributed per label, and nothing calls
+    block_until_ready (poisoned)."""
+    import jax
+
+    _run_traced(data, "q6", tmp_path / "warm", runs=1)  # compiles out of the way
+
+    def poisoned(*a, **k):  # pragma: no cover - failure path
+        raise AssertionError("block_until_ready at sampleRate=0")
+
+    conf.TRACE_SAMPLE_RATE.set(0)
+    monkeypatch.setattr(jax, "block_until_ready", poisoned)
+    try:
+        events, path = _run_traced(data, "q6", tmp_path / "armed", runs=1)
+    finally:
+        conf.TRACE_SAMPLE_RATE.set(1)
+        trace.reset()
+    kernels = [e for e in events if e["type"] == "task_kernels"]
+    assert kernels
+    assert sum(e["programs"] for e in kernels) > 0
+    assert sum(e["dispatch_overhead_ns"] for e in kernels) > 0
+    assert all(e["device_time_ns"] == 0 for e in kernels)
+    for e in kernels:
+        assert all(v["timed"] == 0 for v in e["kernels"].values())
+    # the report says so, and does not print a measured 0
+    text = trace_report.render(trace.read_event_log(path))
+    assert "not sampled" in text
+    from blaze_tpu.runtime import perf
+
+    explained = perf.render_explain(events)
+    assert "perf: n/a" in explained and "device=not sampled" in explained
+    assert "hbm_util=n/a" in explained and "mfu_est=n/a" in explained
