@@ -31,8 +31,10 @@ Spill and merged back chunk-wise at finish (associative re-reduce).
 from __future__ import annotations
 
 import enum
+import itertools
 import threading
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -40,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import conf
-from ..batch import Column, RecordBatch, bucket_capacity, concat_batches
+from ..batch import Column, RecordBatch, bucket_capacity, concat_batches, head_rows
 from ..exprs.compile import infer_dtype, lower
 from ..exprs.ir import Expr
 from ..io.batch_serde import deserialize_batch, serialize_batch
@@ -175,6 +177,31 @@ def agg_state_fields(fn: str, in_t: Optional[DataType], name: str) -> List[Field
     raise NotImplementedError(f"agg fn {fn}")
 
 
+#: group slots of the sort-free dense update: a stream whose PROVEN
+#: group count is at most this folds each batch into the accumulator by
+#: key match + masked reduces (``DenseSegs``) instead of sort + gather
+#: + scan.  Fixed from one reading on the chip (PERF.md section 6, PR 28).
+DENSE_SLOTS = 16
+
+
+def dense_eligible(fn: str, in_t: Optional[DataType]) -> bool:
+    """True when the aggregate's reduction is exact in any order, so a
+    masked tree reduce gives the sort path's result bit for bit:
+    counts, and sums, averages, min and max over integers, decimals,
+    dates and timestamps (wide or not).  Anything over floats (a sum
+    rounds by order; min/max pick -0.0 or 0.0 by order), variances,
+    ``first*``, ``collect_*`` and string min/max depend on row order or
+    on the segment layout."""
+    if fn in ("count", "count_star"):
+        return True
+    if fn in ("sum", "avg"):
+        return in_t.is_integer or in_t.is_decimal
+    if fn in ("min", "max"):
+        return (in_t.is_integer or in_t.is_decimal
+                or in_t.kind in (TypeKind.DATE32, TypeKind.TIMESTAMP))
+    return False
+
+
 # ------------------------------------------------------- key word encode
 
 def encode_key_words(cols: Sequence[Column]) -> List[jnp.ndarray]:
@@ -221,6 +248,9 @@ def encode_key_words(cols: Sequence[Column]) -> List[jnp.ndarray]:
 # associative scans + cumsum-difference + gathers — NO scatter at all
 # (jax.ops.segment_* and jnp.nonzero's bincount both lower to scatter,
 # the other TPU cliff).
+#
+# The third kind is :class:`DenseSegs`: rows in ANY order, each tagged
+# with one of a few known groups — masked tree reductions per group.
 
 
 @dataclass
@@ -238,6 +268,35 @@ class SortedSegs:
     boundary: jnp.ndarray
     starts: jnp.ndarray
     ends: jnp.ndarray
+
+
+@dataclass
+class DenseSegs:
+    """Rows matched against ``k`` known groups, in any order.
+
+    - ``slot``: (cap,) int32, the row's group in ``[0, k)``, or ``k``
+      for a row that is dead or matched none
+    - ``k``: static slot count
+
+    Reduces are a (k, cap) broadcast compare + select + tree reduce
+    along rows: no sort, no gather, no scan, no scatter.  Only for
+    reductions that are exact in any order (``dense_eligible``)."""
+
+    slot: jnp.ndarray
+    k: int
+
+
+def _dense_reduce(op, values, seg: DenseSegs, fill):
+    hit = seg.slot[None, :] == jnp.arange(seg.k, dtype=seg.slot.dtype)[:, None]
+    return op(jnp.where(hit, values[None, :], fill), axis=1)
+
+
+def _extreme(dt, largest: bool):
+    """The value every other one of dtype ``dt`` reduces past."""
+    if jnp.issubdtype(dt, jnp.floating):
+        return jnp.array(jnp.inf if largest else -jnp.inf, dt)
+    info = jnp.iinfo(dt)
+    return jnp.array(info.max if largest else info.min, dt)
 
 
 def _segscan(op, vals, flags):
@@ -293,6 +352,8 @@ def _seg_min_reduce(values, seg, cap):
     directly (seg=None must stay a tree reduce, not a scatter)."""
     if seg is None:
         return jnp.min(values, keepdims=True)
+    if isinstance(seg, DenseSegs):
+        return _dense_reduce(jnp.min, values, seg, _extreme(values.dtype, True))
     if isinstance(seg, SortedSegs):
         return jnp.take(_segscan(jnp.minimum, values, seg.boundary), seg.ends)
     return jax.ops.segment_min(values, seg, num_segments=cap, indices_are_sorted=True)
@@ -301,6 +362,8 @@ def _seg_min_reduce(values, seg, cap):
 def _seg_max_reduce(values, seg, cap):
     if seg is None:
         return jnp.max(values, keepdims=True)
+    if isinstance(seg, DenseSegs):
+        return _dense_reduce(jnp.max, values, seg, _extreme(values.dtype, False))
     if isinstance(seg, SortedSegs):
         return jnp.take(_segscan(jnp.maximum, values, seg.boundary), seg.ends)
     return jax.ops.segment_max(values, seg, num_segments=cap, indices_are_sorted=True)
@@ -310,6 +373,8 @@ def _seg_sum(values, valid, seg, cap):
     z = jnp.where(valid, values, jnp.zeros((), values.dtype))
     if seg is None:
         return jnp.sum(z, keepdims=True)
+    if isinstance(seg, DenseSegs):
+        return _dense_reduce(jnp.sum, z, seg, jnp.zeros((), z.dtype))
     if isinstance(seg, SortedSegs):
         if jnp.issubdtype(z.dtype, jnp.floating):
             # floats: a global-cumsum difference catastrophically
@@ -333,13 +398,7 @@ def _seg_count(valid, seg, cap):
 
 
 def _seg_minmax(values, valid, seg, cap, is_min: bool):
-    dt = values.dtype
-    if jnp.issubdtype(dt, jnp.floating):
-        sentinel = jnp.array(jnp.inf if is_min else -jnp.inf, dt)
-    else:
-        info = jnp.iinfo(dt)
-        sentinel = jnp.array(info.max if is_min else info.min, dt)
-    z = jnp.where(valid, values, sentinel)
+    z = jnp.where(valid, values, _extreme(values.dtype, is_min))
     return (_seg_min_reduce if is_min else _seg_max_reduce)(z, seg, cap)
 
 
@@ -777,8 +836,13 @@ class AggExec(ExecNode):
         else:
             self._schema = self._state_schema
 
+        # the sort-free dense update serves this agg (the stream's
+        # proven group count decides per batch, _FusedGroupedUpdate)
+        self._dense_ok = bool(self.groupings) and all(
+            dense_eligible(a.fn, t) for a, t in zip(self.aggs, self._in_types))
         self._merger: Optional["_StateMerger"] = None
         self._update_k = None
+        self._dense_k = None
         from ..exprs.compile import expr_key
         from ..runtime.kernel_cache import cached_kernel, schema_key
         from .sort import sort_fields_key
@@ -828,7 +892,10 @@ class AggExec(ExecNode):
 
     # -------------------------------------------------------- kernels
 
-    def _build_kernels(self, in_schema: Schema):
+    def _build_kernels(self, in_schema: Schema, dense: bool = False):
+        """(grouped_kernel, scalar_kernel, finalize_kernel) — or, with
+        ``dense``, the one ``dense_update`` program over the same input
+        evaluation and reduce expressions."""
         groupings = self.groupings
         aggs = self.aggs
         mode = self.mode
@@ -937,7 +1004,10 @@ class AggExec(ExecNode):
                 if v.dtype.is_string:
                     return [_seg_string_minmax(v, seg, cap, a.fn == "min")]
                 vals = _seg_minmax(v.data, v.validity, seg, cap, a.fn == "min")
-                has = _seg_max_reduce(v.validity.astype(jnp.int32), seg, cap).astype(jnp.bool_)
+                # ``> 0``, not a cast: a dense slot that no row of the
+                # batch matched reduces to the fill (INT32_MIN), which
+                # a cast would read as "has a value"
+                has = _seg_max_reduce(v.validity.astype(jnp.int32), seg, cap) > 0
                 return [Column(v.dtype, jnp.where(has, vals, jnp.zeros((), vals.dtype)), has)]
             if a.fn in ("first", "first_ignores_null"):
                 v = inputs[0]
@@ -1008,15 +1078,19 @@ class AggExec(ExecNode):
 
         merging = mode != AggMode.PARTIAL
 
+        def live_rows(env, cap: int, num_rows):
+            live = jnp.arange(cap) < num_rows
+            if pre_filter is not None:
+                pf = lower(pre_filter, in_schema, env, cap)
+                live = live & pf.validity & pf.data.astype(jnp.bool_)
+            return live
+
         @jax.jit
         def grouped_kernel(cols: Tuple[Column, ...], num_rows):
             schema = in_schema
             env, key_cols, _ = eval_inputs(cols, schema)
             cap = cols[0].validity.shape[0]
-            live = jnp.arange(cap) < num_rows
-            if pre_filter is not None:
-                pf = lower(pre_filter, schema, env, cap)
-                live = live & pf.validity & pf.data.astype(jnp.bool_)
+            live = live_rows(env, cap, num_rows)
             key_words = [
                 jnp.where(live, w, jnp.uint64(0)) for w in encode_key_words(key_cols)
             ]
@@ -1157,10 +1231,7 @@ class AggExec(ExecNode):
             schema = in_schema
             env, _, _ = eval_inputs(cols, schema)
             cap = cols[0].validity.shape[0]
-            live = jnp.arange(cap) < num_rows
-            if pre_filter is not None:
-                pf = lower(pre_filter, schema, env, cap)
-                live = live & pf.validity & pf.data.astype(jnp.bool_)
+            live = live_rows(env, cap, num_rows)
             seg = None  # global reduce fast path (no scatter)
             inputs = partial_inputs(env, schema, cap) if not merging else state_inputs(env)
             masked = [
@@ -1172,6 +1243,61 @@ class AggExec(ExecNode):
                 state_cols.extend(reduce_one(a, t, ins, seg, 1, merging))
             return tuple(state_cols)
 
+        if dense:
+            k = DENSE_SLOTS
+
+            @jax.jit
+            def dense_update(acc_cols, acc_n, in_cols, in_n):
+                """Fold one batch into an accumulator that already holds
+                its groups, without sorting: match every row against the
+                accumulator's first ``k`` keys, reduce per slot under masks
+                (``DenseSegs``), then combine accumulator and partial slot
+                by slot with the merge form of the same ``reduce_one``.
+                Returns (state columns at the accumulator's capacity, the
+                unchanged group count — or capacity + 1, the overflow the
+                driver rolls back, when a live row matched no held key:
+                the batch brought a new group, or one past slot ``k``).
+                Group-key columns are not returned: no group is added."""
+                env, key_cols, cap = eval_inputs(in_cols, in_schema)
+                live = live_rows(env, cap, in_n)
+                slots = jnp.arange(k, dtype=jnp.int32)
+                held = slots < acc_n
+                match = held[:, None] & live[None, :]
+                for a_key, b_key in zip(acc_cols[:n_groups_cols], key_cols):
+                    # per column: a string key's word count follows its
+                    # width bucket, which the batch and the seed may not
+                    # share (missing words are zero padding)
+                    for wa, wb in itertools.zip_longest(
+                            encode_key_words([head_rows(a_key, k)]),
+                            encode_key_words([b_key])):
+                        match = match & (
+                            (jnp.uint64(0) if wa is None else wa[:, None])
+                            == (jnp.uint64(0) if wb is None else wb[None, :]))
+                slot = jnp.min(jnp.where(match, slots[:, None], jnp.int32(k)), axis=0)
+                n_miss = jnp.sum(live & (slot == k))
+
+                inputs = partial_inputs(env, in_schema, cap) if not merging else state_inputs(env)
+                seg = DenseSegs(slot, k)
+                # accumulator rows [0, k) over partial rows [0, k), slot by slot
+                both, held2 = DenseSegs(jnp.tile(slots, 2), k), jnp.tile(held, 2)
+                out: List[Column] = []
+                at = n_groups_cols
+                for a, t, ins in zip(aggs, in_types, inputs):
+                    part = reduce_one(a, t, ins, seg, k, merging)
+                    acc = acc_cols[at:at + len(part)]
+                    at += len(part)
+                    merged = reduce_one(a, t, [
+                        Column(p.dtype, jnp.concatenate([c.data[:k], p.data]),
+                               jnp.concatenate([c.validity[:k], p.validity]) & held2)
+                        for c, p in zip(acc, part)], both, k, True)
+                    out.extend(
+                        Column(m.dtype, jnp.concatenate([m.data, c.data[k:]]),
+                               jnp.concatenate([m.validity & held, c.validity[k:]]))
+                        for c, m in zip(acc, merged))
+                cap_a = acc_cols[0].validity.shape[0]
+                return tuple(out), jnp.where(n_miss > 0, cap_a + 1, acc_n).astype(jnp.int32)
+
+            return dense_update
 
         # finalization: state batch -> output batch (FINAL mode)
 
@@ -1291,9 +1417,7 @@ class AggExec(ExecNode):
         re-buckets the grown accumulator to a power-of-two capacity.
         scalar_update(acc_cols, in_cols, in_n) -> 1-row state cols."""
         if self._update_k is None:
-            from functools import partial
-
-            from ..batch import _concat_device_cols, head_rows
+            from ..batch import _concat_device_cols
             from ..runtime import dispatch
             from ..runtime.kernel_cache import cached_kernel
 
@@ -1352,6 +1476,19 @@ class AggExec(ExecNode):
                 ("agg_update",) + self._kernel_key, build
             )
         return self._update_k
+
+    def _dense_update_kernel(self):
+        """dense_update(acc_cols, acc_n, in_cols, in_n): the sort-free
+        twin of ``grouped_update`` (``_build_kernels``), a program and
+        a dispatch label of its own."""
+        if self._dense_k is None:
+            from ..runtime.kernel_cache import cached_kernel
+
+            in_schema = self.children[0].schema
+            self._dense_k = cached_kernel(
+                ("agg_dense_update",) + self._kernel_key,
+                lambda: self._build_kernels(in_schema, dense=True))
+        return self._dense_k
 
     def _fused_scalar_update(self, batch: RecordBatch, in_schema: Schema,
                              consumer: "_AggConsumer") -> None:
@@ -1615,11 +1752,22 @@ class _FusedGroupedUpdate:
     re-buckets the grown accumulator) — the pre-existing overflow
     semantics, paid only when cardinality actually outgrows the bucket.
 
+    Which program: while the last PROVEN group count fits
+    ``DENSE_SLOTS`` and every aggregate is order-free
+    (``dense_eligible``), the batch goes to ``dense_update`` — matched
+    against the accumulator's own keys, no sort; a key it does not hold
+    comes back as an overflow and takes the rollback above.  Past the
+    slots, after such a miss (keys that arrive over time would pay a
+    rollback each), or with an order-sensitive aggregate:
+    ``grouped_update``.
+
     Observability (runtime.dispatch counters):
     ``fused_agg_deferred_syncs`` — post-dispatch count fetches (the
     happy path), ``fused_agg_stall_syncs`` — fetches that DID gate a
     dispatch (mode switches; zero on the steady-state path, pinned by
-    tests), ``fused_agg_rollbacks`` — overflow rebuilds."""
+    tests), ``fused_agg_rollbacks`` — overflow rebuilds (dense misses
+    among them), ``agg_grouped_updates`` — update programs launched,
+    ``agg_dense_updates`` — those that were ``dense_update``."""
 
     def __init__(self, agg: "AggExec", consumer: "_AggConsumer",
                  in_schema: Schema):
@@ -1629,12 +1777,17 @@ class _FusedGroupedUpdate:
         self._good: Optional[Tuple[tuple, int]] = None  # (cols, n) proven
         # (input state, input batch, produced state, bucket capacity)
         self._pending = None
+        # a dense update met a key the accumulator lacked: this stream's
+        # keys arrive over time, so it stays on the sort update, which
+        # takes a new key in with no rollback — one miss a stream at most
+        self._dense_missed = False
 
     def update(self, batch: RecordBatch) -> bool:
         """Fold one input batch into the accumulator; False = this
         batch must take the eager pending/doubling path (accumulator
         outgrew one batch bucket)."""
         from ..batch import slice_rows_device
+        from ..runtime import dispatch
 
         agg = self._agg
         consumer = self._consumer
@@ -1657,7 +1810,6 @@ class _FusedGroupedUpdate:
                 consumer.set_state(resolved)
             return False
         out_cap = st.capacity
-        grouped_update, _ = agg._update_kernels()
         if isinstance(st, _LazyAccState):
             acc_cols, acc_n = tuple(st.cols), st.n_dev
         else:
@@ -1668,16 +1820,31 @@ class _FusedGroupedUpdate:
             # stale accumulator and silently drop its merged groups
             self._good = (tuple(st.columns), st.num_rows)
             acc_cols, acc_n = tuple(st.columns), jnp.int32(st.num_rows)
-        cols, m_n = grouped_update(
-            acc_cols, acc_n, tuple(batch.columns), batch.num_rows, out_cap
-        )
         good_n = self._good[1] if self._good is not None else out_cap
-        new = _LazyAccState(
-            agg._state_schema, cols, m_n,
-            hint=min(good_n + batch.num_rows, out_cap),
-        )
+        # few PROVEN groups and order-free aggregates: match the batch
+        # against the accumulator's keys instead of sorting it.  A key
+        # the accumulator lacks comes back as an overflow, like a full
+        # bucket, and rolls back through the same deferred check
+        # (out_cap: minBatchCapacity may be set under the slot count)
+        dense = (agg._dense_ok and not self._dense_missed
+                 and good_n <= DENSE_SLOTS <= out_cap)
+        if dense:
+            state_cols, m_n = agg._dense_update_kernel()(
+                acc_cols, acc_n, tuple(batch.columns), batch.num_rows
+            )
+            cols = acc_cols[:len(agg.groupings)] + state_cols
+            hint = good_n
+            dispatch.record("agg_dense_updates")
+        else:
+            grouped_update, _ = agg._update_kernels()
+            cols, m_n = grouped_update(
+                acc_cols, acc_n, tuple(batch.columns), batch.num_rows, out_cap
+            )
+            hint = min(good_n + batch.num_rows, out_cap)
+        dispatch.record("agg_grouped_updates")
+        new = _LazyAccState(agg._state_schema, cols, m_n, hint=hint)
         consumer.set_state(new)
-        prev, self._pending = self._pending, (st, batch, new, out_cap)
+        prev, self._pending = self._pending, (st, batch, new, out_cap, dense)
         if prev is not None:
             # deferred: the fetched program precedes the one just
             # dispatched in device queue order — no pipeline stall
@@ -1700,7 +1867,7 @@ class _FusedGroupedUpdate:
     def _resolve(self, pending, counter: str) -> None:
         from ..runtime import dispatch
 
-        in_st, in_batch, out_st, out_cap = pending
+        in_st, in_batch, out_st, out_cap, dense = pending
         n = trace.read_scalar(out_st.n_dev)
         dispatch.record(counter)
         if n <= out_cap:
@@ -1714,6 +1881,7 @@ class _FusedGroupedUpdate:
         # overflowed input batch AND — when a later update already
         # consumed the invalid state — the in-flight batch after it
         dispatch.record("fused_agg_rollbacks")
+        self._dense_missed = self._dense_missed or dense
         agg = self._agg
         good_cols, good_n = self._good
         acc = RecordBatch(agg._state_schema, list(good_cols), good_n)

@@ -189,7 +189,10 @@ def programs():
     capacity, captured at the dispatch seam while the queries run here
     on the CPU at scale 0.02 — two lineitem batches, so the fused agg
     UPDATE programs (accumulator + batch) are among them:
-    {query: [(label, fn, args, kwargs)]}."""
+    {query: [(label, fn, args, kwargs)]}.  q01's four groups take the
+    dense update; ``q1_sort_update`` is q01 over a lineitem of 20 line
+    statuses, 60 groups: the sort update every stream of more groups
+    than the dense slots launches, at q01's own shapes."""
     from blaze_tpu.ops import MemoryScanExec
     from blaze_tpu.runtime import dispatch
     from blaze_tpu.runtime.scheduler import run_stages, split_stages
@@ -219,14 +222,22 @@ def programs():
             TPCH_SCHEMAS[name])
         for name in TPCH_SCHEMAS
     }
+    status, lengths = data["lineitem"]["l_linestatus"]
+    status = status.copy()
+    status[:, 0] = ord("A") + np.arange(len(status)) % 20
+    many = dict(data["lineitem"], l_linestatus=(status, lengths))
+    many_groups = dict(scans, lineitem=MemoryScanExec(
+        table_to_batches(many, TPCH_SCHEMAS["lineitem"], 1, batch_rows=CAPACITY),
+        TPCH_SCHEMAS["lineitem"]))
     out = {}
     dispatch._oom_call = recording
     try:
-        for q in ("q6", "q1", "q3"):
+        for name, q, tables in (("q6", "q6", scans), ("q1", "q1", scans), ("q3", "q3", scans),
+                                ("q1_sort_update", "q1", many_groups)):
             before = set(seen)
-            stages, manager = split_stages(build_query(q, scans, 1))
+            stages, manager = split_stages(build_query(q, tables, 1))
             assert sum(b.num_rows for b in run_stages(stages, manager)) > 0
-            out[q] = [seen[k] for k in seen if k not in before]
+            out[name] = [seen[k] for k in seen if k not in before]
     finally:
         dispatch._oom_call = real
     return out
@@ -234,17 +245,19 @@ def programs():
 
 @pytest.mark.parametrize("query,labels", [
     ("q6", {"agg", "agg_update"}),
-    ("q1", {"agg", "agg_update", "sort"}),
+    ("q1", {"agg", "agg_dense_update", "sort"}),
     ("q3", {"filter", "join_build_kernel", "shuffle_pid_sort", "agg",
             "sort"}),
+    ("q1_sort_update", {"agg_update"}),
 ])
 def test_query_programs_compile_for_the_chip(one_chip, programs, as_chip,
                                              query, labels):
-    """The fused q06 stage, the q01 fused agg update and q03's
+    """The fused q06 stage, the q01 dense agg update (4 groups: the
+    sort-free program at batch capacity 65,536) and q03's
     filter/join-build/shuffle-write/sort programs, each at the shapes
-    the scheduler path really launched — x64 and all.  (The q01 update
-    that merged at accumulator + batch capacity crashed this compiler;
-    interpret mode and the CPU never noticed.)"""
+    the scheduler path really launched — x64 and all.  (The q01 sort
+    update that merged at accumulator + batch capacity crashed this
+    compiler; interpret mode and the CPU never noticed.)"""
     found = programs[query]
     assert labels <= {p[0] for p in found}, sorted({p[0] for p in found})
     widest = 0

@@ -599,3 +599,130 @@ def test_scheduler_stage_dispatch_counters(data):
     assert root.get("fused_stage_len") > 0  # run_task fused the map side
     per_stage = [c.metrics.get("xla_dispatches") for c in node.children]
     assert sum(per_stage) == root.get("xla_dispatches")
+
+
+# ------------------------- dense grouped update: engagement and bypass
+
+
+def _agg_stream(key_batches, aggs=(("sum", "v"),), v_type=None):
+    """PARTIAL agg over one partition of (k, v=3) batches, one per key
+    list: ({key: [state values...]} summed over the emitted state rows,
+    the dispatch tally)."""
+    from blaze_tpu.batch import batch_from_pydict
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops import AggExec, AggFunction, AggMode, GroupingExpr
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    schema = Schema([Field("k", DataType.int64()),
+                     Field("v", v_type or DataType.int64())])
+    batches = [batch_from_pydict({"k": list(keys), "v": [3] * len(keys)}, schema)
+               for keys in key_batches]
+    agg = AggExec(MemoryScanExec([batches], schema), AggMode.PARTIAL,
+                  [GroupingExpr(col("k"), "k")],
+                  [AggFunction(fn, col(c), f"a{i}") for i, (fn, c) in enumerate(aggs)])
+    seen = {}
+    with dispatch.capture() as tally:
+        for b in agg.execute(0, TaskContext(0, 1)):
+            d = batch_to_pydict(b)
+            names = [n for n in d if n != "k"]
+            for i, k in enumerate(d["k"]):
+                got = seen.setdefault(k, [0] * len(names))
+                for j, n in enumerate(names):
+                    got[j] += d[n][i]
+    return seen, tally
+
+
+def test_q1_warm_updates_are_all_dense(data):
+    """Warm q01 (4 groups, order-free aggregates): every fused grouped
+    update takes the sort-free dense program — no rollback, no stall,
+    no warm compile, and still one program a batch."""
+    n_rows = len(data["lineitem"]["l_quantity"][0])
+    n_batches = (n_rows + BATCH_ROWS - 1) // BATCH_ROWS
+    _run(_optimized("q1", data))
+    with dispatch.capture() as warm:
+        _run(_optimized("q1", data))
+    assert warm.get("agg_dense_updates", 0) == warm.get("agg_grouped_updates", 0) > 0, warm
+    assert warm.get("fused_agg_rollbacks", 0) == 0, warm
+    assert warm.get("fused_agg_stall_syncs", 0) == 0, warm
+    assert warm.get("xla_compiles", 0) == 0, warm
+    assert warm.get("xla_dispatches", 0) / n_batches <= DISPATCH_BUDGET, warm
+
+
+def test_high_ndv_stream_never_takes_the_dense_update():
+    seen, tally = _agg_stream([range(2000)] * 4)
+    assert tally.get("agg_grouped_updates", 0) == 3, tally
+    assert tally.get("agg_dense_updates", 0) == 0, tally
+    assert len(seen) == 2000 and all(v == [12, 4] for v in seen.values())
+
+
+def test_q3_through_the_scheduler_bypasses_the_dense_update(data):
+    """q03's aggregates group by order key: thousands of groups after
+    the seed, so its programs are the ones it had — none is the dense
+    update, and none is compiled for it."""
+    from blaze_tpu.runtime.scheduler import run_stages, split_stages
+
+    labels = set()
+    real = dispatch._oom_call
+
+    def recording(fn, label, *a, **k):
+        labels.add(label)
+        return real(fn, label, *a, **k)
+
+    dispatch._oom_call = recording
+    try:
+        with dispatch.capture() as tally:
+            stages, manager = split_stages(build_query("q3", _scans(data, n_parts=2), 2))
+            assert sum(b.num_rows for b in run_stages(stages, manager)) > 0
+    finally:
+        dispatch._oom_call = real
+    assert tally.get("agg_grouped_updates", 0) > 0, tally
+    assert tally.get("agg_dense_updates", 0) == 0, tally
+    assert "agg_update" in labels and "agg_dense_update" not in labels, sorted(labels)
+
+
+@pytest.mark.parametrize("key_batches,dense,rollbacks", [
+    # a key that first appears in batch 3 of a 2-group stream: the
+    # deferred check reads the miss as an overflow and rolls back
+    # through the eager reduce+merge (batch 4, launched dense behind
+    # it, is replayed); a stream that missed once stays on the sort
+    # update, which takes a new key in without a rollback
+    ([[1, 2] * 50, [2, 1] * 50, [1, 7, 2] * 30, [7, 1] * 40, [2, 7] * 40], 3, 1),
+    # keys that arrive over time (input clustered by the group key):
+    # one rollback for the stream, not one per new key
+    ([[1] * 100, [1] * 100, [1, 2] * 50, [2, 3] * 50, [3, 4] * 50, [4, 5] * 50,
+      [5, 6] * 50], 3, 1),
+    # a stream that grows past the slots: batch 3 brings 20 new keys
+    # (batch 4 is launched dense behind it, before the deferred check,
+    # and replayed), the rollback proves 22 groups and dense stops
+    ([[1, 2] * 50, [2, 1] * 50, list(range(1, 23)), [1, 2] * 50, list(range(1, 23)),
+      [2, 1] * 50], 3, 1),
+], ids=["miss_rolls_back", "clustered_keys_roll_back_once", "growth_past_slots_stops_dense"])
+def test_dense_update_miss_is_the_overflow_rollback(key_batches, dense, rollbacks):
+    seen, tally = _agg_stream(key_batches)
+    assert tally.get("agg_grouped_updates", 0) == len(key_batches) - 1, tally
+    assert tally.get("agg_dense_updates", 0) == dense, tally
+    assert tally.get("fused_agg_rollbacks", 0) == rollbacks, tally
+    assert tally.get("fused_agg_stall_syncs", 0) == 0, tally
+    want = {}
+    for keys in key_batches:
+        for k in keys:
+            want[k] = want.get(k, 0) + 3
+    assert {k: v[0] for k, v in seen.items()} == want
+    assert all(v[1] * 3 == v[0] for v in seen.values())  # the #nonnull counts
+
+
+@pytest.mark.parametrize("aggs,v_type", [
+    ((("stddev_samp", "v"),), None),
+    ((("sum", "v"),), "float64"),
+    ((("min", "v"), ("max", "v")), "float64"),
+    ((("count", "v"), ("first", "v")), None),
+], ids=["stddev_samp", "float_sum", "float_min_max", "first_beside_count"])
+def test_order_sensitive_aggregate_keeps_the_sort_update(aggs, v_type):
+    """Three groups, but an aggregate whose reduction depends on row
+    order or association: the stream stays on the sort program."""
+    from blaze_tpu.schema import DataType
+
+    _, tally = _agg_stream([[1, 2, 3] * 40] * 4, aggs=aggs,
+                           v_type=v_type and getattr(DataType, v_type)())
+    assert tally.get("agg_grouped_updates", 0) == 3, tally
+    assert tally.get("agg_dense_updates", 0) == 0, tally
