@@ -43,11 +43,12 @@ def main(argv=None):
     for seed in (int(s) for s in args.seeds.split(",")):
         cell = run.Cell(config, traffic, seed)
         window = run.run_window(cell, args.seconds)
-        cell.scans = None
+        cell.release()
         expected = cell.module.oracle(cell.tables)
-        program, ok = compare.compare(window["results"], expected, cell.module.canonical)
+        tolerance = getattr(cell.module, "TOLERANCE", None)  # the control is held to it too
+        program, ok = compare.compare(window["results"], expected, cell.module.canonical, tolerance)
         control, control_ok = compare.compare([cell.module.control(cell.tables)], expected,
-                                              cell.module.canonical)
+                                              cell.module.canonical, tolerance)
         for k in program:
             lower[k] = max(lower.get(k, 0), program[k]["value"])
             upper[k] = min(upper.get(k, float("inf")), control[k]["value"])

@@ -5,14 +5,25 @@
 
 One process on one chip.  It resolves the cell by name alone —
 ``BENCHMARK.json`` for the cell's configuration and traffic,
-``bench/configs/<configuration>.json``, ``bench/traffic/<traffic>.json``,
-``bench/suites/<suite>/<query>.py`` for the columns, the reference and
-its control, ``bench/metrics/<metric>.py`` for each per-layer reader —
-so a later PR adds a cell, a configuration or a metric with new files
-and new ``BENCHMARK.json`` entries only.
+``bench/configs/<configuration>.json`` (its ``suite``, ``schema`` and
+``entry``), ``bench/traffic/<traffic>.json``,
+``bench/suites/<suite>/<query>.py`` for the columns, the reference, its
+control and a float column's ``TOLERANCE``, ``bench/entries/<entry>.py``
+for how the plan arrives, ``bench/metrics/<metric>.py`` for each
+per-layer reader — and holds no suite's name itself.  So a later PR adds
+a cell, a configuration or a metric with new files and new
+``BENCHMARK.json`` entries only.  For a new suite those are:
+``bench/suites/<suite>/`` (``datagen.py`` with ``generate_table(table,
+scale, seed, columns)``, ``schema.json``, ``<query>.py`` and, where the
+entry is ``catalyst``, ``<query>.plan.json``), a configuration with
+``suite``, ``schema`` and ``entry``, a traffic file, and the manifest's
+entries; the program's side is ``blaze_tpu.<suite>`` with its
+``<SUITE>_SCHEMAS`` and ``build_query``.
 
 The timed path is the one ``chip_smoke.py`` proved on the chip: for every
-query a fresh ``tpch.build_query`` -> ``scheduler.split_stages`` ->
+query a fresh plan from the configuration's entry (``builder``: the
+program's ``build_query``; ``catalyst``: a catalyst ``toJSON`` dump
+through ``BlazeSparkSession.plan``) -> ``scheduler.split_stages`` ->
 ``run_stages(..., max_task_attempts=1)`` (every stage decoded from
 TaskDefinition bytes, no worker pool) -> ``batch_to_pydict`` of every
 result batch, which is the D2H.  Traffic is a closed loop: the next
@@ -44,8 +55,11 @@ from bench import compare as compare_mod  # noqa: E402
 from bench import least_bytes as least_bytes_mod  # noqa: E402
 from bench import trace_reduce  # noqa: E402
 
-#: the harness's own host spans, by which the trace names an idle gap
-SPANS = ("bench_query", "plan", "run_stages", "d2h")
+#: the host spans by which the trace names an idle gap: the window's,
+#: the harness's two around a query's ends, and the program's leaf spans
+#: between them (``runtime/trace.span``, PR 27)
+SPANS = ("bench_query", "plan", "d2h", "blaze:task_decode", "blaze:scan_stage",
+         "blaze:device_read", "blaze:exchange_read")
 
 
 def log(*a):
@@ -94,26 +108,38 @@ def chips_missing(stamp, cell):
     return None
 
 
+def trim_heap():
+    """Hands the allocator's free pages back to the system (glibc's
+    ``malloc_trim``; nothing where the C library has none).  A run that
+    compiled holds the compiler's freed heap, and glibc returns it the
+    first time the top of the heap comes free, in one call under the GIL:
+    1.7 s inside the 8th query of every q01 window that followed a
+    compile (``PERF.md`` section 2).  Called at the end of set-up, it is
+    set-up's."""
+    import ctypes
+
+    trim = getattr(ctypes.CDLL(None), "malloc_trim", None)
+    if trim is not None:
+        trim(0)
+
+
 def nearest_rank(values, q):
     s = sorted(values)
     return s[max(0, math.ceil(q * len(s)) - 1)]
 
 
 class Cell:
-    """One cell, set up: the seeded host tables, the scans over them
-    and the query's module.  ``query()`` is the timed path."""
+    """One cell, set up: the seeded host tables, the scans over them,
+    the query's module and the entry's plan source.  ``query()`` is the
+    timed path."""
 
     def __init__(self, config, traffic, seed, marks=None):
-        from blaze_tpu.ops import MemoryScanExec
-        from blaze_tpu.schema import Schema
-        from blaze_tpu.tpch import TPCH_SCHEMAS
-        from blaze_tpu.tpch.datagen import table_to_batches
+        from bench import entries
 
-        suite = "bench.suites." + config["suite"]
+        suite = config["suite"]
         self.query_name = traffic["query"]
-        self.module = importlib.import_module(f"{suite}.{self.query_name}")
-        datagen = importlib.import_module(suite + ".datagen")
-        self.n_parts = config["partitions"]
+        self.module = importlib.import_module(f"bench.suites.{suite}.{self.query_name}")
+        datagen = importlib.import_module(f"bench.suites.{suite}.datagen")
         self.tables = {
             t: datagen.generate_table(t, config["scale"], seed, cols)
             for t, cols in self.module.COLUMNS.items()
@@ -121,18 +147,16 @@ class Cell:
         self.rows = {t: int(next(iter(tab.values()))[0].shape[0]) for t, tab in self.tables.items()}
         if marks is not None:
             marks.append(("datagen", time.perf_counter()))
-        # each scan carries the columns the query references, as a
-        # column-pruned Spark scan would hand them over; the batches
-        # stay on the host and the scan stages them H2D on every query
-        self.scans = {}
-        for t, cols in self.module.COLUMNS.items():
-            schema = Schema([f for f in TPCH_SCHEMAS[t].fields if f.name in cols])
-            assert len(schema.fields) == len(cols), (t, cols)
-            self.scans[t] = MemoryScanExec(
-                table_to_batches(self.tables[t], schema, self.n_parts,
-                                 batch_rows=config["batch_rows"]), schema)
+        scans = entries.memory_scans(suite, self.tables, self.module.COLUMNS,
+                                     config["partitions"], config["batch_rows"])
+        entry = importlib.import_module("bench.entries." + config["entry"])
+        self.plan = entry.source(suite, self.query_name, scans, config["partitions"])
         if marks is not None:
             marks.append(("host_batches", time.perf_counter()))
+
+    def release(self):
+        """Drops the scans: the program's state goes before the reference runs."""
+        self.plan = None
 
     def query(self):
         """One query through the scheduler path.  Returns (result as
@@ -141,14 +165,13 @@ class Cell:
 
         from blaze_tpu.batch import batch_to_pydict
         from blaze_tpu.runtime.scheduler import run_stages, split_stages
-        from blaze_tpu.tpch import build_query
 
         span = jax.profiler.TraceAnnotation
         t0 = time.perf_counter()
         with span("plan"):
             # a fresh plan per query: exchanges memoize their map side
             # per exec instance, so a reused plan would skip the maps
-            plan = build_query(self.query_name, self.scans, self.n_parts)
+            plan = self.plan()
             stages, manager = split_stages(plan)
         plan_s = time.perf_counter() - t0
         got = {f.name: [] for f in plan.schema.fields}
@@ -226,6 +249,8 @@ def measure(cell_name, manifest, config, traffic, seed, seconds, trace, stamp):
     except Exception as e:  # the window's queries will fail too, and count
         log(f"warm-up query failed: {type(e).__name__}: {e}"[:2000])
     marks.append(("first_query", time.perf_counter()))
+    trim_heap()  # what compiling left behind goes now, not inside the window
+    marks.append(("heap_trim", time.perf_counter()))
     setup_s = marks[-1][1] - T_START
     setup_parts = {"imports_and_device": marks[0][1] - T_START}
     setup_parts.update({name: t - before for (_, before), (name, t) in zip(marks, marks[1:])})
@@ -244,11 +269,12 @@ def measure(cell_name, manifest, config, traffic, seed, seconds, trace, stamp):
 
     stats = jax.devices()[0].memory_stats() or {}
     device = dict(stamp, memory_peak_bytes=stats.get("peak_bytes_in_use"))
-    cell.scans = None  # the program's state goes before the reference runs
+    cell.release()
 
     t_ref = time.perf_counter()
     expected = cell.module.oracle(cell.tables)
-    compared, correct = compare_mod.compare(window["results"], expected, cell.module.canonical)
+    compared, correct = compare_mod.compare(window["results"], expected, cell.module.canonical,
+                                            getattr(cell.module, "TOLERANCE", None))
     reference_s = time.perf_counter() - t_ref
 
     n = len(window["results"])
@@ -290,9 +316,16 @@ def measure(cell_name, manifest, config, traffic, seed, seconds, trace, stamp):
                    "window_s": window["window_s"], "rows": cell.rows,
                    "latency_s": {"min": min(window["latencies"]),
                                  "median": nearest_rank(window["latencies"], 0.5),
-                                 "max": max(window["latencies"])},
+                                 "max": max(window["latencies"]),
+                                 "each": window["latencies"]},
                    "setup_parts": setup_parts, "reference_s": reference_s, "cache_dir": cache_dir,
                    "counters": window["counters"]}
+    if reduced:
+        # all the gaps by the span that names them, not the ten largest alone
+        by_span = out["info"]["idle_by_span_s"] = {}
+        for name, gap_s in reduced["idle_gaps"]:
+            span = name.split(">")[0]
+            by_span[span] = by_span.get(span, 0.0) + gap_s
     out["compared"] = compared  # last in the line, as the last lines of stderr too
     return out
 

@@ -15,9 +15,9 @@ All planes share one clock, in nanoseconds.
 * busy is the union of the device-operation intervals inside the
   window, averaged over the chips that ran anything;
 * an idle gap is an interval of the window in which no operation ran
-  on that chip.  It is named by the harness span (other than the
-  window's) that the host spent most of it in, and by the program
-  that ended it: ``run_stages>jit_fused_stage``.
+  on that chip.  It is named by the span (other than the window's)
+  that the host spent most of it in, and by the program that ended
+  it: ``blaze:scan_stage>jit_dense_update``.
 """
 
 import bisect
@@ -68,8 +68,8 @@ def read_planes(path):
 
 
 def reduce_planes(planes, spans=(), window_span="bench_query"):
-    """See the module's docstring.  ``spans``: the harness's span names,
-    outermost first.  Returns None where no device operation ran."""
+    """See the module's docstring.  ``spans``: the host spans' names,
+    the window's among them.  Returns None where no device operation ran."""
     device = {p: lines for p, lines in planes.items()
               if DEVICE_PLANE.match(p) and lines.get(OPS_LINE)}
     if not device:

@@ -66,6 +66,11 @@ def test_sound_run_reads_correct():
     assert set(out["metrics"]) == {"query_s", "query_p95_s", "setup_s"}
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert out["device"]["platform"] == "cpu"  # stamped as it ran: no chip's name on a CPU run
+    # the allocator's free pages go back inside set-up, after the warm-up query
+    parts = list(out["info"]["setup_parts"])
+    assert parts[-2:] == ["first_query", "heap_trim"]
+    assert out["metrics"]["setup_s"]["value"] >= sum(out["info"]["setup_parts"].values()) - 1e-6
+    assert len(out["info"]["latency_s"]["each"]) == out["attempted"]
 
 
 def test_half_of_the_batches_left_out_reads_not_correct(monkeypatch):

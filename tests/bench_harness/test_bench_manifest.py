@@ -54,6 +54,8 @@ def test_cell_resolves_by_name(cell):
     assert listed["reduced"] == config["reduced"] == ["scale"]
     assert (traffic["loop"], traffic["clients"]) == ("closed", 1)
     assert traffic["traced_queries"] >= 1
+    # how the plans arrive is the configuration's, stated and not defaulted
+    assert callable(importlib.import_module("bench.entries." + config["entry"]).source)
     query = importlib.import_module(f"bench.suites.{config['suite']}.{traffic['query']}")
     with open(os.path.join(ROOT, config["schema"])) as f:
         tables = json.load(f)["tables"]
@@ -64,6 +66,17 @@ def test_cell_resolves_by_name(cell):
     # every per-layer metric this cell reports has a reader of that name
     assert set(run.metric_readers(manifest, cell)) == {
         m["name"] for m in manifest["per_layer"] if cell in m.get("workloads", [cell])}
+
+
+@pytest.mark.parametrize("listed", MANIFEST["configs"], ids=lambda c: c["name"])
+def test_configuration_states_its_suite_schema_and_entry(listed):
+    with open(os.path.join(ROOT, listed["file"])) as f:
+        config = json.load(f)
+    for key in ("suite", "schema", "entry", "scale", "partitions", "batch_rows"):
+        assert key in config, key
+    assert os.path.isfile(os.path.join(ROOT, "bench", "entries", config["entry"] + ".py"))
+    assert os.path.isfile(os.path.join(ROOT, "bench", "suites", config["suite"], "datagen.py"))
+    assert os.path.isfile(os.path.join(ROOT, config["schema"]))
 
 
 @pytest.mark.parametrize("metric", MANIFEST["per_layer"], ids=lambda m: m["name"])
