@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Sequence
 
 from ...batch import RecordBatch, column_from_strings, concat_batches
 from ...exprs.ir import Expr
+from ...runtime import dispatch, trace
 from ...runtime.context import TaskContext
 from ...schema import DataType, Field, Schema
 from ..base import BatchStream, ExecNode
@@ -88,7 +89,7 @@ class BroadcastJoinBuildHashMapExec(ExecNode):
             if self._payload is None:
                 child = self.children[0]
                 data = _collect_child_batch(child, range(child.num_partitions()), ctx)
-                with self.metrics.timer("build_hash_map_time"):
+                with self.metrics.timer("build_hash_map_time", trace.span("broadcast_build")):
                     self._payload = build_join_map(data, self._build_kernel).serialize()
             return self._payload
 
@@ -210,10 +211,12 @@ class BroadcastJoinExec(ExecNode):
             m = _cache_get(cache_key)
             if m is not None:
                 self.metrics.add("hashmap_cache_hit", 1)
+                dispatch.record("join_map_cache_hits")
                 with self._map_lock:
                     self._cached_map = m
                 return m
-        with self.metrics.timer("build_hash_map_time"):
+        # a miss: this task turns the broadcast into the executor's map
+        with self.metrics.timer("build_hash_map_time", trace.span("broadcast_build")):
             if self._map_mode:
                 # O(1) rebuild: buffer copies only, no re-sort/re-hash
                 m = JoinMap.deserialize(self._read_map_payload(ctx), self.build_data_schema)
@@ -221,6 +224,7 @@ class BroadcastJoinExec(ExecNode):
                 # broadcast child is replicated: read partition 0
                 data = _collect_child_batch(self.children[0], [0], ctx)
                 m = self._joiner.build_map(data)
+        dispatch.record("join_map_builds")
         with self._map_lock:
             self._cached_map = m
         if self.cached_build_id is not None:

@@ -385,6 +385,19 @@ class Joiner:
     def probe_batch(
         self, jmap: JoinMap, batch: RecordBatch, state: JoinerState
     ) -> Optional[RecordBatch]:
+        """One probe batch against the map, under the ``join_probe``
+        span (its ``device_read`` round trips nest inside); the rows in
+        and out are host-known already, so counting them reads nothing
+        more from the device."""
+        with trace.span("join_probe"):
+            out = self._probe_batch(jmap, batch, state)
+        dispatch.record("join_probe_rows_in", batch.num_rows)
+        dispatch.record("join_rows_out", out.num_rows if out is not None else 0)
+        return out
+
+    def _probe_batch(
+        self, jmap: JoinMap, batch: RecordBatch, state: JoinerState
+    ) -> Optional[RecordBatch]:
         jt = self.join_type
         cand = self._candidate_kernel(tuple(batch.columns), jmap.sorted_keys, batch.num_rows)
         cand = trace.read_scalar(cand)  # the round trip that picks out_cap

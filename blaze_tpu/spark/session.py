@@ -75,18 +75,23 @@ class BlazeSparkSession:
     # -------------------------------------------------------- conversion
 
     def plan(self, plan_json: Union[str, list, SparkNode]) -> ExecNode:
-        """Spark physical plan (toJSON) -> executable ExecNode tree."""
-        node = (
-            plan_json
-            if isinstance(plan_json, SparkNode)
-            else parse_plan_json(plan_json)
-        )
-        ctx = ConversionContext(
-            catalog=self.catalog,
-            default_parallelism=self.default_parallelism,
-            host_fallback=self.host_fallback,
-        )
-        converted = convert_spark_plan(node, ctx)
+        """Spark physical plan (toJSON) -> executable ExecNode tree.
+        Parse, strategy and conversion lie under the ``plan_convert``
+        span: what a query pays for arriving as a catalyst dump."""
+        from ..runtime import trace
+
+        with trace.span("plan_convert"):
+            node = (
+                plan_json
+                if isinstance(plan_json, SparkNode)
+                else parse_plan_json(plan_json)
+            )
+            ctx = ConversionContext(
+                catalog=self.catalog,
+                default_parallelism=self.default_parallelism,
+                host_fallback=self.host_fallback,
+            )
+            converted = convert_spark_plan(node, ctx)
         if _log.isEnabledFor(logging.DEBUG):
             # ≙ the reference's plan dump at conversion
             # (BlazeSparkSessionExtension.scala:52-61,80-88)

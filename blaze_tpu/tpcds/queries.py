@@ -55,6 +55,12 @@ def q3(t: Dict[str, ExecNode], n_parts: int) -> ExecNode:
 
 
 def q7(t: Dict[str, ExecNode], n_parts: int) -> ExecNode:
+    """Hand-built q7: build-left broadcast joins and decimal ``avg``.
+    NOT the shape Spark 3.5.1 emits (BuildRight joins in the order
+    demographics, date, item, promotion, ``Project`` between them,
+    ``isnotnull`` filters, ``avg(UnscaledValue(x))`` with the ``/ 100.0``
+    cast): that one is the benchmark's
+    ``bench/suites/tpcds/q7.plan.json`` (cell ``tpcds_q07_sf1``)."""
     cd = FilterExec(
         t["customer_demographics"],
         (col("cd_gender") == lit("M"))

@@ -142,6 +142,11 @@ def _execute_both(sess, plan):
 
 def _demo_avg_plan(st, fact, cdemo_c, date_c, promo_c, item_c, qty_c,
                    list_c, coupon_c, sales_c):
+    """q7/q26 in the simplified dump: build-left joins, decimal ``avg``.
+    Spark 3.5.1 emits neither; its true shape for q7 (BuildRight,
+    ``avg(UnscaledValue(x)) / 100.0`` cast to decimal(11,6)) is the
+    benchmark's ``bench/suites/tpcds/q7.plan.json``, driven in tier-1 by
+    ``tests/bench_harness/test_bench_tpcds_q7.py``."""
     cd = F.project(
         [a("cd_demo_sk")],
         F.filter_(

@@ -675,8 +675,15 @@ def test_span_counters_with_tracing_off(data, q, monkeypatch):
     assert c["device_read_n"] > 0
     spans = {k for k in c if k.endswith("_ns") and k[:-3] + "_n" in c}
     # task and join_build are annotations only: no metric reads them
-    assert spans == {"task_decode_ns", "scan_stage_ns", "launch_ns",
-                     "device_read_ns", "exchange_write_ns", "exchange_read_ns"}
+    joins = {"join_probe_ns", "broadcast_build_ns"} if q == "q3" else set()
+    assert spans == joins | {"task_decode_ns", "scan_stage_ns", "launch_ns",
+                             "device_read_ns", "exchange_write_ns", "exchange_read_ns"}
+    if joins:
+        # one broadcast, built by the first probe task and found in the
+        # executor's cache by the others; every probe counts its rows
+        assert (c["broadcast_build_n"], c["join_map_builds"]) == (1, 1)
+        assert c["join_map_cache_hits"] == n_parts - 1
+        assert c["join_probe_n"] > 0 and c["join_probe_rows_in"] >= c["join_rows_out"] > 0
     for k in spans:
         assert c[k] > 0, k
     # tracing stayed off: the span is not an event and not a query span
