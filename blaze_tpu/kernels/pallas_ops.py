@@ -344,7 +344,8 @@ def _sorted_lookup_kernel(q_hi_ref, q_lo_ref, t_hi_ref, t_lo_ref,
 def sorted_lookup(table_keys: jnp.ndarray, probe_keys: jnp.ndarray):
     """(lo, hi) candidate-range bounds per probe key — the hash-join
     probe inner loop (ops/joins/core.py ``probe_counts``) as one fused
-    pallas program instead of two XLA searchsorted dispatches.
+    pallas program giving both bounds, instead of XLA's searchsorted
+    loop plus the run-length gathers.
 
     ``table_keys``: sorted (T,) uint64 hashes (the JoinMap key table);
     ``probe_keys``: (N,) uint64 probe hashes.  Table padding fills with

@@ -2,9 +2,11 @@
 
 ≙ reference join_hash_map.rs (open-addressing u32 map with raw-bytes
 serialization for broadcast) — rebuilt for XLA: no pointer chasing, no
-data-dependent probe loops; everything is sort, searchsorted, cumsum,
-gather.  The map itself is a pytree of three device arrays, trivially
-serializable/broadcastable like the reference's raw-bytes map.
+data-dependent probe loops; everything is sort, cumulative scans,
+gather and ONE searchsorted of the key table per probe batch (the upper
+bound of a candidate range is its run length, kept in the map).  The
+map itself is a pytree of three device arrays beside the build batch,
+trivially serializable/broadcastable like the reference's raw-bytes map.
 
 All kernels are per-Joiner jitted closures — Exprs never appear as jit
 static arguments (Expr.__eq__ builds IR nodes, which poisons any
@@ -48,22 +50,24 @@ class JoinMap:
     """Sorted build-side key table + the build batch it indexes.
 
     Raw-bytes serializable (≙ join_hash_map.rs:290-454): the serialized
-    form carries the sorted table AND the data batch, so a probe-side
-    executor rebuilds it with buffer copies only — no re-sort, no key
-    re-hash."""
+    form carries the sorted table, its run lengths AND the data batch,
+    so a probe-side executor rebuilds it with buffer copies only — no
+    re-sort, no key re-hash, no run-length pass."""
 
     sorted_keys: jnp.ndarray   # uint64 (cap,) sorted
     sorted_rows: jnp.ndarray   # int32 (cap,) original row per key
+    run_lens: jnp.ndarray      # int32 (cap,) positions j >= i holding sorted_keys[i]
     num_rows: int              # live build rows (static)
     batch: RecordBatch         # build-side data
 
     def tree_flatten(self):
-        return (self.sorted_keys, self.sorted_rows, self.batch), (self.num_rows,)
+        return ((self.sorted_keys, self.sorted_rows, self.run_lens, self.batch),
+                (self.num_rows,))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        sk, sr, batch = children
-        return cls(sk, sr, aux[0], batch)
+        sk, sr, rl, batch = children
+        return cls(sk, sr, rl, aux[0], batch)
 
     def serialize(self) -> bytes:
         import struct
@@ -72,8 +76,10 @@ class JoinMap:
 
         sk = np.asarray(self.sorted_keys, dtype=np.uint64)
         sr = np.asarray(self.sorted_rows, dtype=np.int32)
+        rl = np.asarray(self.run_lens, dtype=np.int32)
         head = struct.pack("<II", self.num_rows, sk.shape[0])
-        return head + sk.tobytes() + sr.tobytes() + serialize_batch(self.batch)
+        return (head + sk.tobytes() + sr.tobytes() + rl.tobytes()
+                + serialize_batch(self.batch))
 
     @classmethod
     def deserialize(cls, data: bytes, build_schema: Schema) -> "JoinMap":
@@ -87,13 +93,15 @@ class JoinMap:
         off += 8 * cap
         sr = np.frombuffer(data, np.int32, cap, off).copy()
         off += 4 * cap
+        rl = np.frombuffer(data, np.int32, cap, off).copy()
+        off += 4 * cap
         # memoryview slice: no second full-payload copy
         batch = (
             deserialize_batch(memoryview(data)[off:], build_schema)
             .with_capacity(cap)
             .to_device()
         )
-        return cls(jnp.asarray(sk), jnp.asarray(sr), num_rows, batch)
+        return cls(jnp.asarray(sk), jnp.asarray(sr), jnp.asarray(rl), num_rows, batch)
 
 
 def make_build_kernel(build_schema: Schema, build_keys: Sequence[Expr]):
@@ -118,14 +126,28 @@ def _make_build_kernel_impl(build_schema: Schema, build_keys):
         live = jnp.arange(cap) < num_rows
         keys = jnp.where(live, _key_hash(key_cols), _SENTINEL)
         rows = jnp.arange(cap, dtype=jnp.int32)
-        return jax.lax.sort((keys, rows), num_keys=1)
+        sorted_keys, sorted_rows = jax.lax.sort((keys, rows), num_keys=1)
+        return sorted_keys, sorted_rows, run_lengths(sorted_keys)
 
     return build_kernel
 
 
+def run_lengths(sorted_keys) -> jnp.ndarray:
+    """int32 (cap,): for every position i of a sorted key table, how
+    many positions j >= i hold the same key — at a run's start, the
+    run's length.  From the sorted keys alone: run starts by neighbour
+    compare, the next start by a reverse cumulative minimum."""
+    cap = sorted_keys.shape[0]
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    starts_here = jnp.where(sorted_keys[1:] != sorted_keys[:-1], pos[1:], cap)
+    next_start = jax.lax.cummin(
+        jnp.append(starts_here, jnp.int32(cap)), reverse=True)
+    return next_start - pos
+
+
 def build_join_map(batch: RecordBatch, build_kernel) -> JoinMap:
-    sk, sr = build_kernel(tuple(batch.columns), batch.num_rows)
-    return JoinMap(sk, sr, batch.num_rows, batch)
+    sk, sr, rl = build_kernel(tuple(batch.columns), batch.num_rows)
+    return JoinMap(sk, sr, rl, batch.num_rows, batch)
 
 
 _SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -141,27 +163,31 @@ def _key_hash(cols: Sequence[Column]) -> jnp.ndarray:
     return jnp.where(all_valid, h, _SENTINEL)
 
 
-def probe_counts(jmap_keys, probe_keys, use_pallas: bool = False):
-    """(lo, counts) of candidate ranges per probe row.
+def probe_counts(jmap_keys, run_lens, probe_keys, use_pallas: bool = False):
+    """(lo, counts) of candidate ranges per probe row: ONE left
+    searchsorted of the sorted key table; where the key found at ``lo``
+    is the probe's, the range is that key's run (``run_lens``, built
+    with the table), else it is empty.  ``lo == cap`` clips to the last
+    key, which a probe above every key cannot equal.
 
-    ``use_pallas`` routes the two searchsorted dispatches through the
-    fused pallas counting-lookup kernel (kernels/pallas_ops.py) — a
-    trace-time constant (the Joiner cache key carries it), applied only
-    when the build table fits the kernel's all-pairs work bound.  A
-    lowering or compile failure of the kernel raises: a table over the
-    bound is dispatch on size, a broken kernel is not."""
+    ``use_pallas`` routes the search through the fused pallas
+    counting-lookup kernel (kernels/pallas_ops.py), which returns both
+    bounds from one program — a trace-time constant (the Joiner cache
+    key carries it), applied only when the build table fits the
+    kernel's all-pairs work bound.  A lowering or compile failure of
+    the kernel raises: a table over the bound is dispatch on size, a
+    broken kernel is not."""
+    is_sent = probe_keys == _SENTINEL
     if use_pallas:
         from ...kernels import pallas_ops
 
         if jmap_keys.shape[0] <= pallas_ops.SORTED_LOOKUP_MAX_TABLE:
             lo, hi = pallas_ops.sorted_lookup(jmap_keys, probe_keys)
-            is_sent = probe_keys == _SENTINEL
             return lo, jnp.where(is_sent, 0, hi - lo)
     lo = jnp.searchsorted(jmap_keys, probe_keys, side="left")
-    hi = jnp.searchsorted(jmap_keys, probe_keys, side="right")
-    is_sent = probe_keys == _SENTINEL
-    counts = jnp.where(is_sent, 0, hi - lo)
-    return lo, counts
+    at = jnp.clip(lo, 0, jmap_keys.shape[0] - 1)
+    found = (jmap_keys[at] == probe_keys) & ~is_sent
+    return lo, jnp.where(found, run_lens[at], 0)
 
 
 def expand_pairs(lo, counts, out_cap: int):
@@ -263,9 +289,12 @@ class JoinerState:
 
 class Joiner:
     """Build/probe driver for one join exec instance.  Kernels compile
-    once per (schema, capacity) via instance-owned jitted closures; the
-    host syncs one scalar per probe batch (candidate total) for output
-    bucketing."""
+    once per (schema, capacity) via instance-owned jitted closures.  A
+    probe batch searches the key table once, in the candidate program,
+    which hands ``lo``/``counts`` to the probe program on the device;
+    the host syncs the candidate total (it picks the output bucket),
+    then the count of rows it emits, and the unmatched count where the
+    probe side is preserved."""
 
     def __init__(
         self,
@@ -320,14 +349,15 @@ class Joiner:
         self._build_kernel = make_build_kernel(build_schema, build_keys)
 
         @jax.jit
-        def candidate_kernel(cols, jmap_keys, num_rows):
+        def candidate_kernel(cols, jmap_keys, run_lens, num_rows):
             cap = cols[0].validity.shape[0]
             env = {f.name: c for f, c in zip(probe_schema.fields, cols)}
             key_cols = [lower(e, probe_schema, env, cap) for e in probe_keys]
             live = jnp.arange(cap) < num_rows
             pkeys = jnp.where(live, _key_hash(key_cols), _SENTINEL)
-            _, counts = probe_counts(jmap_keys, pkeys, use_pallas=use_pallas)
-            return jnp.sum(counts)
+            lo, counts = probe_counts(jmap_keys, run_lens, pkeys,
+                                      use_pallas=use_pallas)
+            return jnp.sum(counts), lo, counts
 
         # under the dispatch counters like every cached kernel: the
         # Joiner object is what cached_kernel holds, so its jitted
@@ -337,15 +367,13 @@ class Joiner:
         from functools import partial
 
         @partial(jax.jit, static_argnames=("out_cap",))
-        def probe_kernel(probe_cols, jmap: JoinMap, probe_rows, out_cap: int):
+        def probe_kernel(probe_cols, jmap: JoinMap, lo, counts, out_cap: int):
+            # lo/counts are the candidate program's, still on the
+            # device: the key table is not searched again here, and the
+            # key columns are lowered for the exact verification only
             cap = probe_cols[0].validity.shape[0]
             env = {f.name: c for f, c in zip(probe_schema.fields, probe_cols)}
             probe_key_cols = [lower(e, probe_schema, env, cap) for e in probe_keys]
-            live = jnp.arange(cap) < probe_rows
-            pkeys = jnp.where(live, _key_hash(probe_key_cols), _SENTINEL)
-
-            lo, counts = probe_counts(jmap.sorted_keys, pkeys,
-                                      use_pallas=use_pallas)
             p_idx, b_pos, pair_live = expand_pairs(lo, counts, out_cap)
             b_idx = jnp.take(jmap.sorted_rows, jnp.clip(b_pos, 0, jmap.sorted_rows.shape[0] - 1))
 
@@ -399,11 +427,12 @@ class Joiner:
         self, jmap: JoinMap, batch: RecordBatch, state: JoinerState
     ) -> Optional[RecordBatch]:
         jt = self.join_type
-        cand = self._candidate_kernel(tuple(batch.columns), jmap.sorted_keys, batch.num_rows)
+        cand, lo, counts = self._candidate_kernel(
+            tuple(batch.columns), jmap.sorted_keys, jmap.run_lens, batch.num_rows)
         cand = trace.read_scalar(cand)  # the round trip that picks out_cap
         out_cap = bucket_capacity(max(1, cand))
         pair_cols, pair_count, vcounts, matched = self._probe_kernel(
-            tuple(batch.columns), jmap, batch.num_rows, out_cap
+            tuple(batch.columns), jmap, lo, counts, out_cap
         )
         if self._need_matched:
             state.matched_build = (

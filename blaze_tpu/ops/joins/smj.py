@@ -261,8 +261,9 @@ class SortMergeJoinExec(ExecNode):
         m[: matched_rows.shape[0]] = matched_rows
         state = JoinerState()
         state.matched_build = jnp.asarray(m)
-        zeros = jnp.zeros(batch.capacity, jnp.uint64)
-        fake = JoinMap(zeros, zeros.astype(jnp.int32), batch.num_rows, batch)
+        # finish() reads the batch and its row count only: no key table
+        zeros = jnp.zeros(batch.capacity, jnp.int32)
+        fake = JoinMap(zeros.astype(jnp.uint64), zeros, zeros, batch.num_rows, batch)
         return self._joiner.finish(fake, state)
 
     def _empty_build(self) -> RecordBatch:

@@ -7,6 +7,7 @@ crosses the broadcast, probe executors rebuild it with buffer copies
 only, and re-instantiated plans hit the executor-wide cache.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -57,7 +58,8 @@ def _map_build_side():
 
 @pytest.mark.parametrize(
     "jt", [JoinType.INNER, JoinType.LEFT, JoinType.LEFT_SEMI, JoinType.LEFT_ANTI,
-           JoinType.EXISTENCE]
+           JoinType.EXISTENCE, JoinType.RIGHT, JoinType.FULL, JoinType.RIGHT_SEMI,
+           JoinType.RIGHT_ANTI]
 )
 def test_map_mode_matches_legacy(jt):
     clear_join_map_cache()
@@ -78,7 +80,27 @@ def test_serialize_deserialize_roundtrip():
     assert rt.num_rows == jmap.num_rows
     np.testing.assert_array_equal(np.asarray(rt.sorted_keys), np.asarray(jmap.sorted_keys))
     np.testing.assert_array_equal(np.asarray(rt.sorted_rows), np.asarray(jmap.sorted_rows))
+    np.testing.assert_array_equal(np.asarray(rt.run_lens), np.asarray(jmap.run_lens))
+    assert rt.run_lens.dtype == jmap.run_lens.dtype
     assert batch_to_pydict(rt.batch) == batch_to_pydict(jmap.batch)
+
+
+def test_serialized_map_carries_its_run_lengths():
+    """The wire form holds run_lens as built (keys 2,2 one run, the
+    NULL key and the dead rows the sentinel run): the reading side
+    copies buffers, it computes nothing."""
+    from blaze_tpu.ops.joins.core import run_lengths
+
+    kern = make_build_kernel(BUILD_SCHEMA, [col("k")])
+    jmap = build_join_map(batch_from_pydict(BUILD_DATA, BUILD_SCHEMA), kern)
+    cap = jmap.sorted_keys.shape[0]
+    rt = JoinMap.deserialize(jmap.serialize(), BUILD_SCHEMA)
+    got = np.asarray(rt.run_lens)
+    np.testing.assert_array_equal(got, np.asarray(run_lengths(rt.sorted_keys)))
+    assert sorted(got[:4].tolist()) == [1, 1, 1, 2]  # 1, 2, 2, 5 in hash order
+    assert got[4] == cap - 4 and got[-1] == 1
+    leaves = jax.tree_util.tree_leaves(rt)
+    assert any(leaf is rt.run_lens for leaf in leaves)
 
 
 def test_per_executor_cache_hit():
