@@ -18,8 +18,6 @@ Usage:
     python -m blaze_tpu --lint --json -     # + machine-readable findings
     python -m blaze_tpu --lint --sarif -    # + SARIF 2.1.0 for code-scanning
     python -m blaze_tpu tpch q1 --explain   # EXPLAIN ANALYZE (runtime/perf.py)
-    python -m blaze_tpu --perfcheck         # perf-baseline gate; nonzero on drift
-    python -m blaze_tpu --perfcheck --update  # re-pin baselines with provenance
     python -m blaze_tpu --chaos             # seeded fault-injection smoke
                                             #  (+ plan verifier + lock-order
                                             #   + lockset checker armed)
@@ -116,8 +114,8 @@ def _load_suite(suite: str, names, scale: float, n_parts: int,
         )
         for name in SCHEMAS
     }
-    # stderr: --explain/--perfcheck promise a parseable stdout under
-    # --json -, and the line is operator chatter either way
+    # stderr: --explain promises a parseable stdout under --json -,
+    # and the line is operator chatter either way
     print(f"# datagen scale={scale}: {time.perf_counter() - t0:.2f}s",
           file=sys.stderr)
     return build_query, names, scans
@@ -379,9 +377,8 @@ def _run_explain(suite: str, names, scale: float, n_parts: int,
     prev_dir = conf.EVENT_LOG_DIR.get()
     # the command's whole point is the roofline table: force the
     # estimator armed for the profiled run even when the operator's
-    # conf/env disarmed it (the run_perfcheck contract) — a bytes~0 /
-    # bound=unknown explain with no hint why is worse than overriding
-    # a knob for one measurement
+    # conf/env disarmed it — a bytes~0 / bound=unknown explain with no
+    # hint why is worse than overriding a knob for one measurement
     perf.force(True)
     log_dir = tempfile.mkdtemp(prefix="blaze_explain_")
     docs = {}
@@ -458,46 +455,6 @@ def _run_explain(suite: str, names, scale: float, n_parts: int,
                 _json.dump(out, f, indent=2, default=str)
             print(f"# explain json: {json_path}")
     return 1 if failed else 0
-
-
-def _run_perfcheck(update: bool, inflate: float,
-                   json_path: str = "") -> int:
-    """``--perfcheck``: the perf-baseline regression gate
-    (runtime/perf.py over runtime/perf_baselines.json) — nonzero on
-    warm-dispatch/program/recompile/bound drift outside
-    ``spark.blaze.perf.tolerance``; ``--update`` re-pins the registry
-    with provenance; ``--perfcheck-inflate N`` is the gate's self-test
-    hook (a seeded N-x dispatch inflation MUST fail)."""
-    import json as _json
-
-    from .runtime import perf
-    from .runtime.kernel_cache import enable_persistent_cache
-
-    enable_persistent_cache()
-    # --json -: stdout is the PARSEABLE document and nothing else, so
-    # the per-query progress lines move to stderr (the --lint contract)
-    out = print if json_path != "-" else (
-        lambda *a, **k: print(*a, file=sys.stderr, **k))
-    rc, doc = perf.run_perfcheck(update=update, inflate=inflate, out=out)
-    for p in doc["problems"]:
-        print(f"perfcheck DRIFT: {p}", file=sys.stderr)
-    status = ("re-pinned" if update
-              else "clean" if rc == 0
-              else f"{len(doc['problems'])} drift finding(s)")
-    status_line = (f"# perfcheck: {status} — {len(doc['queries'])} "
-                   f"queries vs {doc['baselines']} "
-                   f"(tolerance {doc['tolerance']:.0%}, "
-                   f"device {doc['device_kind']})")
-    if json_path:
-        if json_path == "-":
-            print(_json.dumps(doc, indent=2, default=str))
-            print(status_line, file=sys.stderr)
-            return rc
-        with open(json_path, "w") as f:
-            _json.dump(doc, f, indent=2, default=str)
-        print(f"# perfcheck json: {json_path}")
-    print(status_line)
-    return rc
 
 
 def _check_perf_gate() -> int:
@@ -743,8 +700,8 @@ def _run_chaos(suite: str, names, scale: float, n_parts: int, seed: int,
         conf.MONITOR_HEARTBEAT_MS.set(50)
         monitor.reset()
     try:
-        # perfcheck-machinery structural gate: the estimator's
-        # disarmed/armed contract holds even while nothing measures
+        # estimator structural gate: its disarmed/armed contract
+        # holds even while nothing measures
         rc = _check_perf_gate()
         rc = _chaos_loop(suite, names, scans, build_query, n_parts, seed,
                          n_faults, speculate, inject_oom) or rc
@@ -2587,22 +2544,6 @@ def main(argv=None) -> int:
                          "per-kernel roofline, dispatch/memory/compute "
                          "bound classification); --json writes the "
                          "golden-pinned explain document")
-    ap.add_argument("--perfcheck", action="store_true",
-                    help="perf-baseline regression gate: measure the "
-                         "TPC-H slice pinned in runtime/perf_baselines.json "
-                         "(warm dispatches, programs, recompiles, bound "
-                         "class) and exit nonzero on drift outside "
-                         "spark.blaze.perf.tolerance")
-    ap.add_argument("--update", action="store_true",
-                    help="with --perfcheck: re-pin the baseline registry "
-                         "from fresh measurements, stamped with provenance "
-                         "(device kind, scale, pinned_at)")
-    ap.add_argument("--perfcheck-inflate", type=float, default=1.0,
-                    metavar="N",
-                    help="with --perfcheck: multiply measured dispatch/"
-                         "program counts by N before the check — the "
-                         "gate's self-test hook (N=2 must fail nonzero, "
-                         "proving drift detection fires)")
     ap.add_argument("--lint", action="store_true",
                     help="run the static-analysis passes (blaze_tpu/analysis/)"
                          ": AST lint (trace purity, stray jax.jit, "
@@ -2760,30 +2701,19 @@ def main(argv=None) -> int:
                     help="--watch: stop after N polls (0 = until ^C)")
     args = ap.parse_args(argv)
     if args.json and not (args.report or args.lint or args.explain
-                          or args.perfcheck or args.watch is not None):
+                          or args.watch is not None):
         ap.error("--json requires --report (profile as JSON), --lint "
                  "(findings as JSON), --explain (explain document), "
-                 "--perfcheck (measurement document), or --watch "
-                 "(one snapshot per poll)")
+                 "or --watch (one snapshot per poll)")
     if args.sarif and not args.lint:
         ap.error("--sarif requires --lint (findings as SARIF)")
     if args.sarif == "-" and args.json == "-":
         ap.error("--sarif - and --json - both claim stdout; write at "
                  "least one to a file")
-    if args.update and not args.perfcheck:
-        ap.error("--update requires --perfcheck (re-pin the baseline "
-                 "registry)")
-    if args.update and args.perfcheck_inflate != 1.0:
-        ap.error("--perfcheck-inflate is a self-test hook and cannot be "
-                 "combined with --update (it would pin falsified counts "
-                 "as the golden baselines)")
     if args.chaos_seeds:
         args.chaos = True
     if args.lint:
         return _run_lint(args.json, args.sarif)
-    if args.perfcheck:
-        return _run_perfcheck(args.update, args.perfcheck_inflate,
-                              args.json)
     if args.flame and not args.report:
         ap.error("--flame requires --report (flame profile from an "
                  "event log)")

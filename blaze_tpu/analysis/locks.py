@@ -117,10 +117,6 @@ HIERARCHY: Tuple[str, ...] = (
     "metrics.set",           # per-operator counters
     "dispatch.kernel_state", # per-kernel compile high-water mark
     "dispatch.counters",     # process dispatch tally + captures
-    "dispatch.autotune",     # batch-autotune controller state (held
-                             # for dict arithmetic only; the counter
-                             # bump and autotune trace emission a
-                             # decision produces happen after release)
     "integrity.state",       # per-path corruption tallies (held for
                              # dict arithmetic only; quarantine renames
                              # and emission happen outside)

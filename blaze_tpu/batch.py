@@ -22,7 +22,7 @@ stays static.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Dict, Iterator, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -381,22 +381,11 @@ def strings_to_list(col: Column, num_rows: int) -> List[Optional[str]]:
 @dataclass
 class RecordBatch:
     """A set of equally-sized columns.  ``schema``/``num_rows`` are
-    static pytree aux data; columns are leaves.
-
-    ``consumable`` marks a batch whose device buffers are freshly
-    produced by THIS engine for a single downstream consumer (concat
-    coalescing outputs, fused-stage outputs, agg state) — the only
-    batches a donating kernel (spark.blaze.tpu.donateBuffers) may
-    consume.  Scan-, cache- or caller-owned batches stay False: their
-    buffers may be retained elsewhere, and donation would hand XLA
-    memory something else still reads.  Deliberately NOT part of the
-    pytree (neither leaf nor aux): it is host-side ownership metadata,
-    and putting it in aux would fork jit caches by ownership."""
+    static pytree aux data; columns are leaves."""
 
     schema: Schema
     columns: List[Column]
     num_rows: int
-    consumable: bool = False
 
     def tree_flatten(self):
         return tuple(self.columns), (self.schema, self.num_rows)
@@ -768,52 +757,19 @@ def concat_batches(batches: Sequence[RecordBatch]) -> RecordBatch:
                 tuple(jnp.int32(x) for x in ns),
             )
         )
-        # fresh single-consumer output buffers either way: eligible for
-        # donation downstream (RecordBatch.consumable contract)
-        return RecordBatch(schema, cols, n, consumable=True)
+        return RecordBatch(schema, cols, n)
     cols: List[Column] = []
     for ci, f in enumerate(schema.fields):
         parts = [b.columns[ci].to_host() for b in batches]
         cols.append(_concat_host_cols(f.dtype, parts, ns, cap).to_device())
-    return RecordBatch(schema, cols, n, consumable=True)
-
-
-def coalesce_stream(stream, target_rows) -> Iterator[RecordBatch]:
-    """Demand-driven bucket coalescing for the batch autotuner
-    (spark.blaze.tpu.batchAutotune): accumulate upstream batches until
-    ``target_rows()`` rows are pending, then emit them as ONE
-    concatenated batch — the downstream kernel's dispatch floor
-    amortizes over the whole bucket.  The target is re-polled per
-    input batch, so controller growth mid-stream takes effect at the
-    next bucket boundary; ``target_rows() <= 0`` (controller off)
-    passes batches through untouched.  Order-preserving, and a
-    single-batch bucket is forwarded as-is (no copy, no extra
-    program)."""
-    pending: List[RecordBatch] = []
-    rows = 0
-    for b in stream:
-        t = int(target_rows() or 0)
-        if t <= 0:
-            if pending:  # controller turned off mid-stream
-                yield pending[0] if len(pending) == 1 else concat_batches(pending)
-                pending, rows = [], 0
-            yield b
-            continue
-        pending.append(b)
-        rows += b.num_rows
-        if rows >= t:
-            yield pending[0] if len(pending) == 1 else concat_batches(pending)
-            pending, rows = [], 0
-    if pending:
-        yield pending[0] if len(pending) == 1 else concat_batches(pending)
+    return RecordBatch(schema, cols, n)
 
 
 class DeviceRing:
-    """Two-slot device staging ring (the double-buffer half of the
-    donated pipeline): the fused shuffle write pushes each batch's
-    device outputs here and only converts the OLDEST slot to host
-    bytes once the next batch's program is already dispatched — batch
-    N's device→host drain overlaps batch N+1's launch.  FIFO, so the
+    """Two-slot device staging ring: the fused shuffle write pushes
+    each batch's device outputs here and only converts the OLDEST slot
+    to host bytes once the next batch's program is already dispatched —
+    batch N's device→host drain overlaps batch N+1's launch.  FIFO, so the
     staged byte stream is identical to the synchronous path.
 
     ``put`` returns the items now due for host staging (0 or 1);

@@ -58,9 +58,8 @@ def _bool(s: str) -> bool:
     return s.strip().lower() in ("1", "true", "yes", "on")
 
 
-# ≙ BlazeConf.java defaults: BATCH_SIZE 10000, MEMORY_FRACTION 0.6, etc.
+# ≙ BlazeConf.java defaults: BATCH_SIZE 10000, etc.
 BATCH_SIZE = ConfEntry("spark.blaze.batchSize", 8192, int)
-MEMORY_FRACTION = ConfEntry("spark.blaze.memoryFraction", 0.6, float)
 ENABLE_PARTIAL_AGG_SKIPPING = ConfEntry("spark.blaze.partialAggSkipping.enable", True, _bool)
 PARTIAL_AGG_SKIPPING_RATIO = ConfEntry("spark.blaze.partialAggSkipping.ratio", 0.8, float)
 PARTIAL_AGG_SKIPPING_MIN_ROWS = ConfEntry("spark.blaze.partialAggSkipping.minRows", 20000, int)
@@ -71,25 +70,15 @@ PARQUET_FILTER_PUSHDOWN = ConfEntry("spark.blaze.parquet.enable.pageFiltering", 
 # TPU-only: hand-written pallas kernels for hot loops (kernels/); the
 # pure-XLA path is always kept as fallback
 PALLAS_ENABLE = ConfEntry("spark.blaze.tpu.pallas.enable", True, _bool)
-# hash-join probe inner loop as a fused pallas lookup (counting
-# searchsorted over the sorted build table): work is probes x table,
-# so it only engages for small build sides — default OFF until TPU
-# profiles justify it; tier-1 exercises it via interpret mode
-PALLAS_JOIN_PROBE = ConfEntry("spark.blaze.tpu.pallas.joinProbe", False, _bool)
-INPUT_BATCH_STATISTICS = ConfEntry("spark.blaze.inputBatchStatistics", False, _bool)
-UDF_WRAPPER_NUM_THREADS = ConfEntry("spark.blaze.udfWrapperNumThreads", 1, int)
 # pickled UDF/UDTF payloads in TaskDefinitions execute arbitrary code at
 # deserialization (round-1 advisor finding): a gateway deployed across a
 # trust boundary must run with this OFF and register generators by name
 ALLOW_PICKLED_UDFS = ConfEntry("spark.blaze.udf.allowPickled", True, _bool)
-SMJ_FALLBACK_ENABLE = ConfEntry("spark.blaze.smjfallback.enable", True, _bool)
 # fixed per-group element budget for collect_list/collect_set results
 # (the reference's lists are unbounded; the padded device layout is not —
 # elements past the budget are SILENTLY DROPPED: raise this knob when a
 # query's groups can exceed it)
 COLLECT_MAX_ELEMS = ConfEntry("spark.blaze.collect.maxElems", 64, int)
-SUGGESTED_BATCH_MEM_SIZE = ConfEntry("spark.blaze.suggested.batch.mem.size", 8 << 20, int)
-TOKIO_NUM_WORKER_THREADS = ConfEntry("spark.blaze.tokio.num.worker.threads", 2, int)
 # bounded producer queue depth between host staging and device compute
 # (≙ rt.rs sync_channel(1) + tokio stream drive); 0 = synchronous
 PIPELINE_DEPTH = ConfEntry("spark.blaze.pipeline.depth", 2, int)
@@ -403,7 +392,6 @@ FUSED_AGG_UPDATE = ConfEntry("spark.blaze.tpu.fusedAggUpdate", True, _bool)
 XLA_CACHE_DIR = ConfEntry("spark.blaze.xla.cacheDir", "", str)
 
 # TPU-specific knobs (no reference equivalent).
-ON_DEVICE = ConfEntry("spark.blaze.tpu.onDevice", True, _bool)
 # Grouped-agg segment reduces via segmented associative scans + cumsum
 # differences + gathers (scatter-free).  Off = jax.ops.segment_* +
 # jnp.nonzero (scatter-based — a cliff on XLA:TPU).
@@ -429,62 +417,14 @@ DEVICE_MEMORY_BUDGET = ConfEntry("spark.blaze.tpu.hbmBudget", 8 << 30, int)
 HOST_SPILL_BUDGET = ConfEntry("spark.blaze.tpu.hostSpillBudget", 4 << 30, int)
 MIN_CAPACITY = ConfEntry("spark.blaze.tpu.minBatchCapacity", 1024, int)
 
-# Dispatch-driven batch autotuning (runtime/dispatch.py controller):
-# while a trace kernel capture is active, the per-kernel device_ns /
-# dispatch_ns split feeds a controller that GROWS the agg input
-# coalescing bucket (powers of the step factor, bounded below/above)
-# until the device share of warm kernel time crosses the target —
-# the dispatch floor amortizes over more rows per program.  Memory
-# pressure (an OOM-ladder rung firing) pushes the bucket back down
-# and caps re-growth below the rows that exhausted the device.  OFF
-# (default) the whole controller is a structural no-op: decisions are
-# only made under the same capture scope that already pays
-# block-until-ready timing, so the untraced hot path never sees it.
-BATCH_AUTOTUNE = ConfEntry("spark.blaze.tpu.batchAutotune", False, _bool)
-# Coalescing-bucket bounds (rows) and growth step for the controller.
-# The floor doubles as the starting target; the ceiling bounds device
-# residency of one coalesced bucket.
-BATCH_AUTOTUNE_MIN_ROWS = ConfEntry(
-    "spark.blaze.tpu.batchAutotune.minRows", 8192, int)
-BATCH_AUTOTUNE_MAX_ROWS = ConfEntry(
-    "spark.blaze.tpu.batchAutotune.maxRows", 262144, int)
-BATCH_AUTOTUNE_STEP = ConfEntry("spark.blaze.tpu.batchAutotune.step", 4, int)
-# Warm device share (device_ns / (device_ns + dispatch_ns)) the
-# controller grows toward; past it the workload classifies
-# majority-device and growth stops.
-BATCH_AUTOTUNE_TARGET_SHARE = ConfEntry(
-    "spark.blaze.tpu.batchAutotune.deviceShareTarget", 0.5, float)
-# Timed-kernel observations aggregated per growth decision (smooths
-# single-program jitter without starving convergence at test scale).
-BATCH_AUTOTUNE_WINDOW = ConfEntry(
-    "spark.blaze.tpu.batchAutotune.window", 4, int)
-# Donate fused-shuffle-write input buffers to XLA (jax.jit
-# donate_argnums): the consumed batch's device buffers are reused for
-# the program's outputs instead of holding both alive.  Only
-# engine-produced single-consumer batches (RecordBatch.consumable) are
-# ever donated; scan/cache-owned batches never are.  A donating
-# program that hits a REAL device OOM forfeits the in-place retry
-# rungs (its inputs are already dead) and surfaces the retryable
-# task-level error instead.
-DONATE_BUFFERS = ConfEntry("spark.blaze.tpu.donateBuffers", False, _bool)
-
-# Performance introspection (runtime/perf.py): EXPLAIN ANALYZE,
-# per-kernel roofline/MFU attribution, and the perf-baseline gate.
+# Performance introspection (runtime/perf.py): EXPLAIN ANALYZE and
+# per-kernel roofline/MFU attribution.
 # Bytes-moved / flops estimation at the dispatch choke point — armed it
 # runs ONLY while a trace kernel capture is active (the same scope that
 # pays block-until-ready timing); disarmed it is one module-global bool
 # read per traced call, exactly the spark.blaze.trace.enabled contract,
 # and the untraced hot path never sees it at all.
 PERF_ESTIMATES = ConfEntry("spark.blaze.perf.estimates", True, _bool)
-# Relative drift tolerance for `--perfcheck` against the golden
-# baseline registry (runtime/perf_baselines.json): warm dispatches /
-# programs outside baseline*(1±tolerance) fail the gate.  0 (the
-# default) defers to the registry's own pinned ``tolerance`` field.
-PERF_TOLERANCE = ConfEntry("spark.blaze.perf.tolerance", 0.0, float)
-# Override path for the perf-baseline registry (empty = the packaged
-# runtime/perf_baselines.json) — tests and `--perfcheck --update`
-# round-trips point this at a scratch copy.
-PERF_BASELINES = ConfEntry("spark.blaze.perf.baselines", "", str)
 # Override path for the per-device-kind peak table (empty = the
 # packaged runtime/device_peaks.json).
 PERF_PEAKS = ConfEntry("spark.blaze.perf.peaks", "", str)

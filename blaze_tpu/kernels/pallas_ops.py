@@ -303,84 +303,3 @@ def _build_group_sums(g_pad: int, k: int, m: int, interpret: bool):
         interpret=interpret,
     )
 
-
-# ------------------------------------------------- hash-join probe lookup
-
-#: largest build-side key table the pallas probe path accepts: the
-#: kernel counts ALL (probe, table) pairs per tile, so work is N*T —
-#: a win only for the small sorted tables of broadcast-style builds
-#: where XLA's per-probe searchsorted dispatch dominates.  It is also
-#: what fits: the (8, 128, T) compare planes of one probe tile must
-#: stay inside the chip's 16 MiB scoped VMEM, and Mosaic refuses
-#: T = 8192 for a v5e (tests/test_chip_compile.py compiles this limit)
-SORTED_LOOKUP_MAX_TABLE = 4096
-
-
-def _sorted_lookup_kernel(q_hi_ref, q_lo_ref, t_hi_ref, t_lo_ref,
-                          lo_ref, hi_ref):
-    """Counting searchsorted over uint64 keys as hi/lo uint32 planes:
-    lo = #{t < q} (XLA side="left"), hi = #{t <= q} (side="right").
-    Unsigned 32-bit order via the sign-bias flip (x ^ 0x8000_0000
-    viewed int32 preserves uint32 order); uint64 order is the (hi, lo)
-    lexicographic combination.  The table enters as ONE full block per
-    grid step (it is the sorted build side, bounded by
-    SORTED_LOOKUP_MAX_TABLE); the grid walks probe tiles."""
-    bias = np.uint32(0x80000000)
-
-    def signed(ref):
-        return jax.lax.bitcast_convert_type(ref[...] ^ bias, jnp.int32)
-
-    q_hi, q_lo = signed(q_hi_ref), signed(q_lo_ref)
-    t_hi, t_lo = signed(t_hi_ref), signed(t_lo_ref)
-    th = t_hi.reshape(-1)[None, None, :]
-    tl = t_lo.reshape(-1)[None, None, :]
-    qh, ql = q_hi[:, :, None], q_lo[:, :, None]
-    lt = (th < qh) | ((th == qh) & (tl < ql))
-    le = lt | ((th == qh) & (tl == ql))
-    lo_ref[...] = jnp.sum(lt.astype(jnp.int32), axis=-1)
-    hi_ref[...] = jnp.sum(le.astype(jnp.int32), axis=-1)
-
-
-def sorted_lookup(table_keys: jnp.ndarray, probe_keys: jnp.ndarray):
-    """(lo, hi) candidate-range bounds per probe key — the hash-join
-    probe inner loop (ops/joins/core.py ``probe_counts``) as one fused
-    pallas program giving both bounds, instead of XLA's searchsorted
-    loop plus the run-length gathers.
-
-    ``table_keys``: sorted (T,) uint64 hashes (the JoinMap key table);
-    ``probe_keys``: (N,) uint64 probe hashes.  Table padding fills with
-    the all-ones sentinel, which sorts after every real key and is
-    never ``< q`` nor (for non-sentinel q) ``<= q`` — so lo matches
-    XLA's searchsorted exactly and hi matches for every probe the
-    caller doesn't already zero (sentinel probes carry count 0).
-    Returns ((N,) int32 lo, (N,) int32 hi).
-    """
-    def planes(a, fill):
-        lo32 = (a & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
-        hi32 = (a >> jnp.uint64(32)).astype(jnp.uint32)
-        return _pad_plane(hi32, fill), _pad_plane(lo32, fill)
-
-    n = probe_keys.shape[0]
-    q_hi, q_lo = planes(probe_keys, 0)
-    t_hi, t_lo = planes(table_keys, np.uint32(0xFFFFFFFF))
-    m, tm = q_hi.shape[0], t_hi.shape[0]
-    call = _build_sorted_lookup(m, tm, _interpret())
-    with _x32():
-        lo, hi = call(q_hi, q_lo, t_hi, t_lo)
-    return lo.reshape(-1)[:n], hi.reshape(-1)[:n]
-
-
-@functools.lru_cache(maxsize=256)
-def _build_sorted_lookup(m: int, tm: int, interpret: bool):
-    pl = _pl()
-    probe_spec = pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0))
-    table_spec = pl.BlockSpec((tm, LANES), lambda i: (0, 0))
-    out = jax.ShapeDtypeStruct((m, LANES), jnp.int32)
-    return pl.pallas_call(
-        _sorted_lookup_kernel,
-        out_shape=[out, out],
-        grid=(m // TILE_ROWS,),
-        in_specs=[probe_spec, probe_spec, table_spec, table_spec],
-        out_specs=[probe_spec, probe_spec],
-        interpret=interpret,
-    )

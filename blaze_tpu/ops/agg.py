@@ -1517,18 +1517,6 @@ class AggExec(ExecNode):
     def execute(self, partition: int, ctx: TaskContext) -> BatchStream:
         child_stream = self.children[0].execute(partition, ctx)
         in_schema = self.children[0].schema
-        # batch autotuning: the agg update is the dispatch-floor hot
-        # loop (q01 grouped / q06 scalar both land here after tier-1
-        # filter/project absorption), so the controller's coalescing
-        # bucket applies to ITS input stream — one update program per
-        # bucket instead of one per scan batch
-        from ..runtime import dispatch as _dispatch
-
-        if _dispatch.autotune_enabled():
-            from ..batch import coalesce_stream
-
-            child_stream = coalesce_stream(
-                child_stream, _dispatch.autotune_target_rows)
 
         def stream():
             merger = _StateMerger.for_agg(self)
@@ -1639,8 +1627,7 @@ class AggExec(ExecNode):
             if self.emit_state:
                 # boundary fusion: the downstream fused shuffle write
                 # owns the finalize (absorb_traceable_chain) — hand it
-                # the raw state, single-consumer so donation-eligible
-                state.consumable = True
+                # the raw state
                 return state
             cols = self._finalize_kernel(tuple(state.columns), state.num_rows)
             n = state.num_rows
@@ -1648,9 +1635,7 @@ class AggExec(ExecNode):
                 # fused Limit/fetch: rows past n are padding after the
                 # in-program post_sort, so a host-side clamp suffices
                 n = min(n, self.post_fetch)
-            out = RecordBatch(self._schema, list(cols), n)
-            out.consumable = True  # fresh finalize output, single consumer
-            return out
+            return RecordBatch(self._schema, list(cols), n)
         return state
 
 

@@ -163,27 +163,13 @@ def _key_hash(cols: Sequence[Column]) -> jnp.ndarray:
     return jnp.where(all_valid, h, _SENTINEL)
 
 
-def probe_counts(jmap_keys, run_lens, probe_keys, use_pallas: bool = False):
+def probe_counts(jmap_keys, run_lens, probe_keys):
     """(lo, counts) of candidate ranges per probe row: ONE left
     searchsorted of the sorted key table; where the key found at ``lo``
     is the probe's, the range is that key's run (``run_lens``, built
     with the table), else it is empty.  ``lo == cap`` clips to the last
-    key, which a probe above every key cannot equal.
-
-    ``use_pallas`` routes the search through the fused pallas
-    counting-lookup kernel (kernels/pallas_ops.py), which returns both
-    bounds from one program — a trace-time constant (the Joiner cache
-    key carries it), applied only when the build table fits the
-    kernel's all-pairs work bound.  A lowering or compile failure of
-    the kernel raises: a table over the bound is dispatch on size, a
-    broken kernel is not."""
+    key, which a probe above every key cannot equal."""
     is_sent = probe_keys == _SENTINEL
-    if use_pallas:
-        from ...kernels import pallas_ops
-
-        if jmap_keys.shape[0] <= pallas_ops.SORTED_LOOKUP_MAX_TABLE:
-            lo, hi = pallas_ops.sorted_lookup(jmap_keys, probe_keys)
-            return lo, jnp.where(is_sent, 0, hi - lo)
     lo = jnp.searchsorted(jmap_keys, probe_keys, side="left")
     at = jnp.clip(lo, 0, jmap_keys.shape[0] - 1)
     found = (jmap_keys[at] == probe_keys) & ~is_sent
@@ -251,32 +237,16 @@ def cached_joiner(
     from ...exprs.compile import expr_key
     from ...runtime.kernel_cache import cached_kernel, schema_key
 
-    use_pallas = _pallas_probe_enabled()
     key = (
         "joiner", schema_key(probe_schema), schema_key(build_schema),
         tuple(expr_key(e) for e in probe_key_exprs),
         tuple(expr_key(e) for e in build_key_exprs),
         join_type.value, probe_is_left, existence_col,
-        ("pallas",) if use_pallas else (),
     )
     return cached_kernel(key, lambda: Joiner(
         probe_schema, build_schema, probe_key_exprs, build_key_exprs,
-        join_type, probe_is_left, existence_col, use_pallas=use_pallas,
+        join_type, probe_is_left, existence_col,
     ))
-
-
-def _pallas_probe_enabled() -> bool:
-    """Backend-probe gate for the pallas probe lookup: both pallas
-    confs on AND the kernels runnable (real TPU, or tests forcing
-    interpret mode)."""
-    from ... import conf
-
-    if not (bool(conf.PALLAS_ENABLE.get())
-            and bool(conf.PALLAS_JOIN_PROBE.get())):
-        return False
-    from ...kernels import pallas_ops
-
-    return pallas_ops.available()
 
 
 class JoinerState:
@@ -305,9 +275,7 @@ class Joiner:
         join_type: JoinType,
         probe_is_left: bool,
         existence_col: str = "exists#0",
-        use_pallas: bool = False,
     ):
-        self.use_pallas = use_pallas
         self.probe_schema = probe_schema
         self.build_schema = build_schema
         self.probe_keys = list(probe_key_exprs)
@@ -355,8 +323,7 @@ class Joiner:
             key_cols = [lower(e, probe_schema, env, cap) for e in probe_keys]
             live = jnp.arange(cap) < num_rows
             pkeys = jnp.where(live, _key_hash(key_cols), _SENTINEL)
-            lo, counts = probe_counts(jmap_keys, run_lens, pkeys,
-                                      use_pallas=use_pallas)
+            lo, counts = probe_counts(jmap_keys, run_lens, pkeys)
             return jnp.sum(counts), lo, counts
 
         # under the dispatch counters like every cached kernel: the

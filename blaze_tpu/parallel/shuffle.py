@@ -560,7 +560,7 @@ def _build_pid_kernels(schema, exprs, n_out):
 
 
 def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
-                              slot_counts=(), donate=False):
+                              slot_counts=()):
     """ONE program per map-stage batch (fusion tier 5): the traceable
     map chain, the partition-id computation, the pid sort, and the
     per-partition bincount, all in a single XLA executable.  The
@@ -576,16 +576,7 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
     (trace_slots contract, ops/base.py): the caller appends the
     flattened slot values after the input columns and the chain deals
     each transform its own group, so parameter-shifted chains reuse
-    this one program.
-
-    ``donate=True`` builds the donated variant: the same program, but
-    the batch columns move to their OWN leading argument (the slot
-    group follows separately, never donated — its values are reused
-    across batches) and XLA may alias their buffers for the outputs.
-    The caller gates per batch on ``RecordBatch.consumable``; after a
-    donated launch the inputs are DEAD, which is why the dispatch
-    choke point refuses in-place OOM retries for it
-    (``_oom_call``'s ``_donating`` seam)."""
+    this one program."""
     n_slots = sum(slot_counts)
 
     def chain(cols, n):
@@ -598,11 +589,6 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
             i += cnt
         return cols, n
 
-    def _finish(kernel):
-        if donate:
-            kernel._donating = True
-        return kernel
-
     if pid_mode == "hash":
         pid_body = _hash_pids_body(out_schema, exprs, n_out)
 
@@ -612,13 +598,7 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
             sorted_cols, counts, _ = _sort_by_pid_body(tuple(cols), pids, n_out, n)
             return sorted_cols, counts
 
-        if donate:
-            @partial(jax.jit, donate_argnums=(0,))
-            def kernel(cols, slots, num_rows):
-                return body(tuple(cols) + tuple(slots), num_rows)
-        else:
-            kernel = jax.jit(body)
-        return _finish(kernel)
+        return jax.jit(body)
 
     if pid_mode == "range":
         from .exchange import _build_range_kernels
@@ -635,13 +615,7 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
             sorted_cols, counts, _ = _sort_by_pid_body(tuple(cols), pids, n_out, n)
             return sorted_cols, counts
 
-        if donate:
-            @partial(jax.jit, donate_argnums=(0,))
-            def kernel(cols, slots, num_rows, boundaries):
-                return range_body(tuple(cols) + tuple(slots), num_rows, boundaries)
-        else:
-            kernel = jax.jit(range_body)
-        return _finish(kernel)
+        return jax.jit(range_body)
 
     def rr_body(cols, num_rows, rr):
         cols, n = chain(cols, num_rows)
@@ -654,13 +628,7 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
         next_rr = (rr + jnp.int32(n)) % jnp.int32(n_out)
         return sorted_cols, counts, next_rr
 
-    if donate:
-        @partial(jax.jit, donate_argnums=(0,))
-        def rr_kernel(cols, slots, num_rows, rr):
-            return rr_body(tuple(cols) + tuple(slots), num_rows, rr)
-    else:
-        rr_kernel = jax.jit(rr_body)
-    return _finish(rr_kernel)
+    return jax.jit(rr_body)
 
 
 def _insert_host(rep: "ShuffleRepartitioner", schema: Schema, item) -> None:
@@ -776,8 +744,6 @@ class ShuffleWriterExec(ExecNode):
         # fusion tier 5 (absorb_traceable_chain): one program per batch
         # covering chain + pids + pid-sort + counts
         self._fused_write = None
-        self._fused_write_donate = None  # donated twin, built on demand
-        self._donate_builder = None
         self._fused_fns: List = []
         self._fused_fn_keys: tuple = ()
         self._fused_slot_args: tuple = ()   # flattened, chain order
@@ -945,14 +911,6 @@ class ShuffleWriterExec(ExecNode):
             mode, pid_arg = "rr", None
         builder = lambda: _build_fused_write_kernel(  # noqa: E731
             out_schema, fns, mode, pid_arg, n_out, slot_counts)
-        # donated twin (spark.blaze.tpu.donateBuffers): built lazily at
-        # execute() time so a conf flip after planning still applies
-        self._donate_builder = (
-            key + ("donate",),
-            lambda: _build_fused_write_kernel(
-                out_schema, fns, mode, pid_arg, n_out, slot_counts,
-                donate=True),
-        )
         if agg is not None:
             agg.emit_state = True
         self._fused_write = cached_kernel(key, builder)
@@ -997,7 +955,6 @@ class ShuffleWriterExec(ExecNode):
         def stream():
             from ..batch import DeviceRing
             from ..runtime import oom as _oom
-            from ..runtime.kernel_cache import cached_kernel
 
             n_out = self.partitioning.num_partitions
             out_schema = self.schema
@@ -1022,9 +979,9 @@ class ShuffleWriterExec(ExecNode):
                 rr = 0
                 rr_dev = jnp.int32(0)  # fused RR offset, device-resident
                 use_fused = self._fused_write is not None
-                # stream-hoisted per-batch invariants: boundary device
-                # arrays and the donation conf are resolved ONCE here,
-                # not inside the dispatch loop
+                # stream-hoisted per-batch invariant: boundary device
+                # arrays are resolved ONCE here, not inside the
+                # dispatch loop
                 boundaries_dev = None
                 if (
                     isinstance(self.partitioning, RangePartitioning)
@@ -1032,11 +989,6 @@ class ShuffleWriterExec(ExecNode):
                 ):
                     boundaries_dev = tuple(
                         jnp.asarray(b) for b in self.partitioning.boundaries)
-                use_donate = bool(conf.DONATE_BUFFERS.get())
-                if use_donate and use_fused and self._fused_write_donate is None \
-                        and self._donate_builder is not None:
-                    dkey, dbuilder = self._donate_builder
-                    self._fused_write_donate = cached_kernel(dkey, dbuilder)
                 for batch in self.children[0].execute(partition, ctx):
                     if not ctx.is_task_running():
                         return
@@ -1047,30 +999,10 @@ class ShuffleWriterExec(ExecNode):
                     if use_fused:
                         # tier 5: ONE program returns the chain output
                         # already pid-sorted plus per-pid counts
-                        donating = (
-                            use_donate and batch.consumable
-                            and self._fused_write_donate is not None
-                        )
                         try:
                             with self.metrics.timer("elapsed_compute"):
                                 part_t = self.partitioning
-                                if donating:
-                                    fw = self._fused_write_donate
-                                    cols_arg = tuple(batch.columns)
-                                    if isinstance(part_t, RoundRobinPartitioning):
-                                        sorted_cols, counts, rr_dev = fw(
-                                            cols_arg, self._fused_slot_args,
-                                            batch.num_rows, rr_dev)
-                                    elif isinstance(part_t, RangePartitioning):
-                                        sorted_cols, counts = fw(
-                                            cols_arg, self._fused_slot_args,
-                                            batch.num_rows, boundaries_dev)
-                                    else:
-                                        sorted_cols, counts = fw(
-                                            cols_arg, self._fused_slot_args,
-                                            batch.num_rows)
-                                    dispatch.record("donated_buffers")
-                                elif isinstance(part_t, RoundRobinPartitioning):
+                                if isinstance(part_t, RoundRobinPartitioning):
                                     sorted_cols, counts, rr_dev = self._fused_write(
                                         tuple(batch.columns) + self._fused_slot_args,
                                         batch.num_rows, rr_dev
@@ -1088,11 +1020,6 @@ class ShuffleWriterExec(ExecNode):
                             item = (list(sorted_cols), counts, None)
                         except Exception as exc:  # noqa: BLE001
                             if not _oom.is_resource_exhausted(exc):
-                                # a donated launch's REAL exhaustion
-                                # surfaces as DeviceOomError (inputs may
-                                # be dead — the attempt must regenerate
-                                # them), which classifies NON-absorbable
-                                # and propagates here
                                 raise
                             # OOM ladder (spill+retry already ran at the
                             # dispatch choke point): decompose to the

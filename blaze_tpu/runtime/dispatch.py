@@ -63,9 +63,8 @@ _TALLY = lockset.module_guard(__name__)
 #: every thread lands here, and capture registration races the
 #: recording hot path
 GUARDED_BY = {"_GLOBAL": "dispatch.counters",
-              "_CAPTURES": "dispatch.counters",
-              "_AUTOTUNE": "dispatch.autotune"}
-GUARDED_REFS = ("_GLOBAL", "_CAPTURES", "_AUTOTUNE")
+              "_CAPTURES": "dispatch.counters"}
+GUARDED_REFS = ("_GLOBAL", "_CAPTURES")
 
 
 def record(name: str, v: int = 1) -> None:
@@ -141,175 +140,6 @@ def capture() -> Iterator[Dict[str, int]]:
             _remove_by_identity(_CAPTURES, c)
 
 
-# ---------------------------------------------------------------------------
-# Dispatch-driven batch autotuning (spark.blaze.tpu.batchAutotune).
-#
-# The controller lives HERE because this module is the one place that
-# sees the traced device_ns/dispatch_ns split per program: while a
-# trace kernel capture is active, every timed call feeds
-# :func:`autotune_observe`, and once a window's aggregate device share
-# is still below the target the coalescing bucket grows by the step
-# factor (bounded by minRows/maxRows).  Consumers (the agg input
-# coalescer in ops/agg.py via batch.coalesce_stream) poll
-# :func:`autotune_target_rows` per batch.  Memory pressure — any
-# OOM-ladder rung firing through runtime/oom.py — calls
-# :func:`autotune_memory_pushback`, which halves the target and CAPS
-# re-growth below the size that exhausted the device.  Disabled
-# (default) every entry point is one bool/conf read.
-
-_AUTOTUNE_LOCK = make_lock("dispatch.autotune")
-_AUTOTUNE_FORCED: List = [None]  # None = defer to conf (perf.force pattern)
-_AUTOTUNE: Dict[str, int] = {}   # target/ceiling/device_ns/dispatch_ns/obs
-
-
-def autotune_force(flag) -> None:
-    """Override spark.blaze.tpu.batchAutotune for this process (None =
-    defer to conf) — how --perfcheck and the budget tests arm the
-    controller without mutating global conf.  Arming resets the
-    controller so every measurement converges from the floor."""
-    _AUTOTUNE_FORCED[0] = flag
-    autotune_reset()
-
-
-def autotune_enabled() -> bool:
-    forced = _AUTOTUNE_FORCED[0]
-    if forced is not None:
-        return bool(forced)
-    from .. import conf
-
-    return bool(conf.BATCH_AUTOTUNE.get())
-
-
-def autotune_reset() -> None:
-    """Drop all controller state (target re-seeds from minRows)."""
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        _AUTOTUNE.clear()
-
-
-def _autotune_bounds():
-    from .. import conf
-
-    lo = max(1, int(conf.BATCH_AUTOTUNE_MIN_ROWS.get()))
-    hi = max(lo, int(conf.BATCH_AUTOTUNE_MAX_ROWS.get()))
-    return lo, hi
-
-
-def autotune_target_rows() -> int:
-    """Current coalescing-bucket target in rows; 0 = controller off
-    (consumers pass batches through untouched)."""
-    if not autotune_enabled():
-        return 0
-    lo, hi = _autotune_bounds()
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        t = _AUTOTUNE.get("target", 0)
-        if t <= 0:
-            t = _AUTOTUNE["target"] = lo
-        return min(max(t, lo), min(hi, _AUTOTUNE.get("ceiling", hi) or hi))
-
-
-def autotune_state() -> Dict[str, int]:
-    """Snapshot for EXPLAIN/report surfaces (never the hot path)."""
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        return dict(_AUTOTUNE)
-
-
-def autotune_observe(label: str, device_ns: int, dispatch_ns: int) -> None:
-    """Feed one TIMED program's device/dispatch split to the
-    controller.  Called from the traced instrument branch only (the
-    untraced path never reaches here); decisions emit an ``autotune``
-    trace event and bump ``autotune_adjustments`` OUTSIDE the lock."""
-    from .. import conf
-
-    lo, hi = _autotune_bounds()
-    step = max(2, int(conf.BATCH_AUTOTUNE_STEP.get()))
-    target_share = float(conf.BATCH_AUTOTUNE_TARGET_SHARE.get())
-    window = max(1, int(conf.BATCH_AUTOTUNE_WINDOW.get()))
-    decision = None
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        if _AUTOTUNE.get("target", 0) <= 0:
-            _AUTOTUNE["target"] = lo
-        _AUTOTUNE["device_ns"] = _AUTOTUNE.get("device_ns", 0) + int(device_ns)
-        _AUTOTUNE["dispatch_ns"] = (
-            _AUTOTUNE.get("dispatch_ns", 0) + int(dispatch_ns))
-        _AUTOTUNE["obs"] = _AUTOTUNE.get("obs", 0) + 1
-        if _AUTOTUNE["obs"] >= window:
-            total = _AUTOTUNE["device_ns"] + _AUTOTUNE["dispatch_ns"]
-            share = _AUTOTUNE["device_ns"] / total if total else 0.0
-            ceiling = _AUTOTUNE.get("ceiling", hi) or hi
-            cap = min(hi, ceiling)
-            if share < target_share and _AUTOTUNE["target"] < cap:
-                _AUTOTUNE["target"] = min(cap, _AUTOTUNE["target"] * step)
-                decision = ("grow", _AUTOTUNE["target"], share)
-            _AUTOTUNE["device_ns"] = _AUTOTUNE["dispatch_ns"] = 0
-            _AUTOTUNE["obs"] = 0
-    if decision is not None:
-        action, target, share = decision
-        record("autotune_adjustments")
-        trace.emit("autotune", action=action, target_rows=int(target),
-                   device_share=round(share, 4), label=label)
-
-
-def autotune_saturate(label: str = "") -> int:
-    """Jump the controller straight to its dispatch-bound fixed point:
-    target = min(maxRows, pushback ceiling).  This is what timing-driven
-    growth converges to whenever the warm window stays dispatch-bound —
-    but on the CPU CI backend the per-window device share near
-    ``deviceShareTarget`` is a coin flip, so the perf-baseline gate
-    pins the SATURATED tuned path instead of racing the host timer
-    (convergence itself is exercised by ``tests/test_device_flip.py``).
-    Returns the saturated target; a no-op 0 when the controller is
-    off.  Memory pushback still caps it afterwards as usual."""
-    if not autotune_enabled():
-        return 0
-    lo, hi = _autotune_bounds()
-    decision = None
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        cap = min(hi, _AUTOTUNE.get("ceiling", hi) or hi)
-        target = max(lo, cap)
-        if _AUTOTUNE.get("target", 0) != target:
-            decision = ("saturate", target)
-        _AUTOTUNE["target"] = target
-        _AUTOTUNE["device_ns"] = _AUTOTUNE["dispatch_ns"] = 0
-        _AUTOTUNE["obs"] = 0
-    if decision is not None:
-        action, target = decision
-        record("autotune_adjustments")
-        trace.emit("autotune", action=action, target_rows=int(target),
-                   device_share=0.0, label=label)
-    return int(target)
-
-
-def autotune_memory_pushback(label: str = "") -> None:
-    """Device memory pressure: halve the bucket (floor minRows) and
-    cap re-growth below the size that exhausted the device.  Hooked
-    from every runtime/oom.py ladder rung; a no-op when the controller
-    is off or already at the floor."""
-    if not autotune_enabled():
-        return
-    lo, hi = _autotune_bounds()
-    decision = None
-    with _AUTOTUNE_LOCK:
-        lockset.check(_TALLY, "_AUTOTUNE")
-        t = _AUTOTUNE.get("target", 0) or lo
-        new = max(lo, t // 2)
-        if new < t or _AUTOTUNE.get("ceiling", 0) != new:
-            _AUTOTUNE["target"] = new
-            _AUTOTUNE["ceiling"] = new
-            _AUTOTUNE["device_ns"] = _AUTOTUNE["dispatch_ns"] = 0
-            _AUTOTUNE["obs"] = 0
-            decision = ("pushback", new)
-    if decision is not None:
-        action, target = decision
-        record("autotune_adjustments")
-        trace.emit("autotune", action=action, target_rows=int(target),
-                   device_share=0.0, label=label)
-
-
 def _oom_call(fn: Callable, label: str, *a, **k):
     """Run one instrumented program launch under the device-OOM
     recovery guard (rung 1 of the degradation ladder, runtime/oom.py):
@@ -330,18 +160,6 @@ def _oom_call(fn: Callable, label: str, *a, **k):
 
         if not oom.is_resource_exhausted(exc):
             raise
-        if (getattr(fn, "_donating", False)
-                and not isinstance(exc, faults.InjectedFault)):
-            # a REAL exhaustion after a donating launch may have
-            # already deleted the input buffers — an in-place retry
-            # (or any ladder rung re-running this batch) would read
-            # dead memory.  Shed pressure for the NEXT attempt, then
-            # surface the retryable task-level error so the attempt
-            # regenerates its inputs.  Injected @oom faults raise
-            # BEFORE the call (inputs intact) and keep the full
-            # ladder.
-            oom.recover_spill(label)
-            raise oom.DeviceOomError(label, exc) from exc
         oom.recover_spill(label)
     # retry outside the handler: a second RESOURCE_EXHAUSTED must reach
     # the caller's downshift/eager rungs, not recurse into spilling
@@ -458,11 +276,6 @@ def instrument(fn: Callable, label: str = "kernel") -> Callable:
             bytes_est=bytes_est,
             flops_est=flops_est,
         )
-        # batch-autotune feed: only timed, non-compiling programs
-        # carry a meaningful device/dispatch split (compiles would
-        # read as huge dispatch overhead and trigger runaway growth)
-        if timed and not compiled and autotune_enabled():
-            autotune_observe(label, device_ns, ns)
         return out
 
     wrapper.__wrapped__ = fn

@@ -80,7 +80,7 @@ def is_resource_exhausted(exc: BaseException) -> bool:
     if isinstance(exc, DeviceOomError):
         # the ladder's own terminal verdict: the message embeds the
         # cause's RESOURCE_EXHAUSTED text, but re-absorbing it would
-        # re-run a batch whose donated inputs may already be deleted
+        # climb the ladder again for a batch that already exhausted it
         return False
     s = str(exc)
     if "RESOURCE_EXHAUSTED" not in s and "Resource exhausted" not in s:
@@ -107,7 +107,6 @@ def recover_spill(label: str) -> int:
 
     freed = MemManager.get().force_spill()
     dispatch.record("oom_recoveries")
-    dispatch.autotune_memory_pushback(label)
     trace.emit("oom_recovery", label=label, action="spill",
                freed_bytes=freed)
     return freed
@@ -118,7 +117,6 @@ def record_downshift(label: str, rows: int, depth: int) -> None:
     from . import dispatch, trace
 
     dispatch.record("batch_downshifts")
-    dispatch.autotune_memory_pushback(label)
     trace.emit("oom_recovery", label=label, action="downshift",
                rows=rows, depth=depth)
 
@@ -129,7 +127,6 @@ def record_eager_fallback(label: str) -> None:
     from . import dispatch, trace
 
     dispatch.record("eager_fallbacks")
-    dispatch.autotune_memory_pushback(label)
     trace.emit("oom_recovery", label=label, action="eager")
 
 

@@ -1,12 +1,12 @@
-"""Performance introspection: EXPLAIN ANALYZE, roofline/MFU
-attribution, and the perf-baseline regression gate.
+"""Performance introspection: EXPLAIN ANALYZE and roofline/MFU
+attribution.
 
 The reference blaze plumbs per-operator native metrics back to the
 Spark UI so an operator can see *where* a query spends its time; PR 3
 and PR 12 recorded the raw material here (per-kernel
 ``device_ns``/``dispatch_ns``/``compile_ns`` splits, per-node
 MetricsSet trees in ``task_plan`` events) but nothing turned it into a
-judgment.  This module is that judgment layer, three surfaces over the
+judgment.  This module is that judgment layer, two surfaces over the
 same data:
 
 1. **EXPLAIN ANALYZE** (:func:`explain_doc` / :func:`render_explain`,
@@ -28,15 +28,6 @@ same data:
    (device + dispatch), so a chip idling between programs reads as
    low utilization + dispatch-bound rather than flattering itself
    with a device-seconds-only denominator.
-
-3. **Perf-baseline gate** (:func:`run_perfcheck`, CLI ``--perfcheck``,
-   tier-1 via tests/test_perf.py): a golden registry
-   (``perf_baselines.json``) pins warm dispatches, programs, zero
-   warm recompiles, and the bound class per TPC-H-slice query;
-   ``--perfcheck`` exits nonzero on drift outside
-   ``spark.blaze.perf.tolerance`` and ``--perfcheck --update`` re-pins
-   with provenance — the dispatch-budget protection generalized from
-   q01 to the whole slice.
 
 Estimator cost contract (the ``trace.enabled()`` pattern): bytes/flops
 estimation runs ONLY while a trace kernel capture is active (the scope
@@ -61,15 +52,12 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from .. import conf
 from .errors import reraise_control
 
 PEAKS_PATH = os.path.join(os.path.dirname(__file__), "device_peaks.json")
-BASELINES_PATH = os.path.join(
-    os.path.dirname(__file__), "perf_baselines.json")
 
 #: plan-node timer metrics that are DISJOINT phases of a node's own
 #: work (each wraps its own with-block; none nests another from this
@@ -82,8 +70,8 @@ NODE_TIMERS = (
     "shuffle_read_total_time", "shuffle_host_stage_time",
 )
 
-#: the bound classes :func:`classify` may return (API for dashboards,
-#: the bench line, and the baseline registry)
+#: the bound classes :func:`classify` may return (API for dashboards
+#: and the bench line)
 BOUND_CLASSES = ("dispatch-bound", "memory-bound", "compute-bound",
                  "unknown")
 
@@ -119,8 +107,8 @@ def force(armed: bool) -> None:
     """Directly arm/disarm the estimator for a measurement scope,
     overriding conf AND the ``BLAZE_PERF_ESTIMATES`` env (which wins
     over ``conf.set`` by ConfEntry design): the surfaces whose whole
-    point is JUDGING the estimates (``--perfcheck``, ``--explain``)
-    force it on around their runs.  :func:`reset` returns control to
+    point is JUDGING the estimates (``--explain``, the ``--chaos``
+    gate) force it on around their runs.  :func:`reset` returns control to
     conf/env."""
     global _ARMED, _loaded
     _ARMED = bool(armed)
@@ -274,35 +262,6 @@ def classify(device_ns: int, dispatch_ns: int, bytes_est: int,
     return out
 
 
-#: bound-class flips are only judged when the larger side of the
-#: device/dispatch split exceeds this — below it the whole
-#: measurement sits inside CPU-host scheduling noise (warm q06 at
-#: perfcheck scale: device 0.14-6.6 ms depending on host load, a 47x
-#: swing), while the guarded pathology (dispatch-floor
-#: re-fragmentation) lands dispatch in the hundreds of ms
-BORDERLINE_FLOOR_NS = 50_000_000
-
-
-def borderline(device_ns: int, dispatch_ns: int) -> bool:
-    """True when the dispatch/device split is too close to call —
-    within 10x either way, or too SMALL to trust (neither side past
-    :data:`BORDERLINE_FLOOR_NS`) — so the perfcheck bound-class
-    comparison treats a flip across it as measurement noise, not
-    drift.  The band is wide on purpose: on a loaded CI host the CPU
-    backend's device drain legitimately swings 4-8x run to run (and
-    collapses under load far below its idle reading), while the
-    regression this guards (the per-program dispatch floor
-    re-fragmenting — VERDICT r5's 100-programs-per-batch pathology)
-    moves the ratio by over an order of magnitude AND the absolute
-    dispatch wall into the hundreds of ms.  A re-fragmentation also
-    always moves the warm_dispatches/programs pins, which have no
-    noise band to hide in."""
-    if max(int(device_ns), int(dispatch_ns)) < BORDERLINE_FLOOR_NS:
-        return True
-    d = max(1, int(device_ns))
-    return 0.1 <= (int(dispatch_ns) / d) <= 10.0
-
-
 def kernel_perf(entry: Dict[str, int],
                 peaks: Dict[str, Any]) -> Dict[str, Any]:
     """Roofline fields for one kernel-sink entry (a ``kernels`` dict
@@ -389,7 +348,7 @@ def query_perf(events: List[Dict[str, Any]],
 #: gates it like the ``--report --json`` pins)
 EXPLAIN_JSON_KEYS = ("query_id", "status", "wall_ns", "attributed_ns",
                      "attributed_pct", "stages", "kernels", "perf",
-                     "cache", "autotune", "stats")
+                     "cache", "stats")
 
 
 def _node_own_ns(metrics: Dict[str, Any]) -> int:
@@ -509,7 +468,6 @@ def explain_doc(events: List[Dict[str, Any]],
         "kernels": kernels,
         "perf": query_perf(events, device_kind=peaks_kind, kernels=rows),
         "cache": _cache_doc(t),
-        "autotune": _autotune_doc(t),
         "stats": _stats_doc(t, stages),
     }
 
@@ -540,18 +498,6 @@ def _stats_doc(t: Dict[str, List[Dict[str, Any]]],
         "skew": skew,
         "reused": len(t.get("stats_reused", [])),
         "persisted": len(t.get("stats_persisted", [])),
-    }
-
-
-def _autotune_doc(t: Dict[str, List[Dict[str, Any]]]) -> Dict[str, int]:
-    """The batch-autotune story from this run's ``autotune`` trace
-    events (runtime/dispatch.py controller): how often the coalescing
-    bucket grew / was pushed back, and where it ended up."""
-    evs = t.get("autotune", [])
-    return {
-        "grows": sum(1 for e in evs if e.get("action") == "grow"),
-        "pushbacks": sum(1 for e in evs if e.get("action") == "pushback"),
-        "target_rows": int(evs[-1].get("target_rows", 0)) if evs else 0,
     }
 
 
@@ -639,11 +585,6 @@ def render_explain(events: List[Dict[str, Any]],
         f"mfu_est={mfu}  "
         f"(peaks: {p['peak']['device']}, "
         f"{p['peak']['hbm_gbps']:g} GB/s, {p['peak']['tflops']:g} TF)")
-    at = doc.get("autotune") or {}
-    if at.get("grows") or at.get("pushbacks"):
-        lines.append(
-            f"autotune: target_rows={at['target_rows']:,}  "
-            f"({at['grows']} grow, {at['pushbacks']} pushback)")
     cd = doc.get("cache") or {}
     if any(cd.values()):
         line = (f"cache: plan {cd['plan_hits']} hit"
@@ -698,244 +639,3 @@ def render_explain(events: List[Dict[str, Any]],
                 f"{v.get('bound', 'unknown')}")
     return "\n".join(lines)
 
-
-# ------------------------------------------------- perf-baseline gate
-
-#: golden-pinned top-level keys of the ``--perfcheck --json`` document
-PERFCHECK_JSON_KEYS = ("baselines", "tolerance", "device_kind",
-                       "queries", "problems", "ok")
-
-
-def baselines_path() -> str:
-    return str(conf.PERF_BASELINES.get() or "") or BASELINES_PATH
-
-
-def load_baselines(path: Optional[str] = None) -> Dict[str, Any]:
-    """The golden perf-baseline registry (``perf_baselines.json`` or
-    the ``spark.blaze.perf.baselines`` override)."""
-    with open(path or baselines_path()) as f:
-        return json.load(f)
-
-
-def measure_query(name: str, scans: Dict[str, Any], n_parts: int,
-                  n_batches: int, build_query=None) -> Dict[str, Any]:
-    """One query's warm perf measurement, the way ``run_task`` runs it
-    (fused + pruned, in-process): one cold pass (compiles allowed),
-    then one warm pass under a dispatch capture + kernel capture with
-    the estimator armed.  ``n_batches`` normalizes dispatches per input
-    batch (the scale-robust number the baseline pins)."""
-    from ..ops.fusion import optimize_plan
-    from .context import TaskContext
-    from . import dispatch, trace
-
-    if build_query is None:
-        from ..tpch import build_query
-
-    def run_once():
-        plan = optimize_plan(build_query(name, scans, n_parts))
-        rows = 0
-        for p in range(plan.num_partitions()):
-            for b in plan.execute(p, TaskContext(p, plan.num_partitions())):
-                rows += b.num_rows
-        return rows
-
-    if dispatch.autotune_enabled():
-        # pin the batch-autotune controller at its dispatch-bound
-        # fixed point (min(maxRows, pushback ceiling)) instead of
-        # racing timing-driven convergence: near deviceShareTarget the
-        # CPU backend's per-window device share is a coin flip, and a
-        # different converged target means a different coalesced batch
-        # count — flapping the pinned dispatch/program counts run to
-        # run.  Saturating BEFORE the cold pass makes that one pass
-        # compile the final bucket shapes, so the measured pass stays
-        # zero-warm-recompile; at the cap, further observations cannot
-        # move the target (growth is capped, pushback needs an OOM),
-        # so the measurement is stable.
-        dispatch.autotune_reset()
-        dispatch.autotune_saturate(name)
-    run_once()  # cold: compiles allowed
-    with dispatch.capture() as warm:
-        with trace.profile_kernels() as prof:
-            rows = run_once()
-    totals = sum_kernel_rows(trace.snapshot_kernels(prof))
-    peaks = peaks_for(current_device_kind())
-    cls = classify(totals["device_ns"], totals["dispatch_ns"],
-                   totals["bytes_est"], totals["flops_est"], peaks)
-    return {
-        "rows": rows,
-        "warm_dispatches": int(warm.get("xla_dispatches", 0)),
-        "dispatches_per_batch": round(
-            warm.get("xla_dispatches", 0) / max(1, n_batches), 2),
-        "programs": int(totals["programs"]),
-        "warm_compiles": int(warm.get("xla_compiles", 0)),
-        "device_ns": totals["device_ns"],
-        "dispatch_ns": totals["dispatch_ns"],
-        "hbm_bytes_est": cls["hbm_bytes_est"],
-        "flops_est": cls["flops_est"],
-        "hbm_util": cls["hbm_util"],
-        "mfu_est": cls["mfu_est"],
-        "bound": cls["bound"],
-    }
-
-
-def check_query(name: str, measured: Dict[str, Any],
-                base: Dict[str, Any], tolerance: float) -> List[str]:
-    """Drift findings for one query against its pinned baseline.
-    Drift in EITHER direction outside tolerance fails — an improvement
-    is re-pinned deliberately (``--perfcheck --update``), never
-    absorbed silently, so the registry keeps meaning something."""
-    problems: List[str] = []
-    for key in ("warm_dispatches", "programs"):
-        b = base.get(key)
-        m = measured.get(key, 0)
-        if b is None:
-            continue
-        lo, hi = b * (1 - tolerance), b * (1 + tolerance)
-        if not (lo <= m <= hi):
-            direction = "regressed" if m > hi else "improved"
-            problems.append(
-                f"{name}: {key} {m} outside [{lo:.1f}, {hi:.1f}] "
-                f"(baseline {b}, {direction} — "
-                f"{'fix the fragmentation' if m > hi else 're-pin with --perfcheck --update'})")
-    if measured.get("warm_compiles", 0) > base.get("warm_compiles", 0):
-        problems.append(
-            f"{name}: warm run recompiled "
-            f"{measured['warm_compiles']}x (baseline "
-            f"{base.get('warm_compiles', 0)}) — the kernel-cache / "
-            f"shape-bucketing contract broke")
-    base_bound = base.get("bound")
-    if (base_bound and measured.get("bound") != base_bound
-            and not borderline(measured.get("device_ns", 0),
-                               measured.get("dispatch_ns", 0))):
-        problems.append(
-            f"{name}: bound class flipped {base_bound} -> "
-            f"{measured.get('bound')} decisively "
-            f"(device {measured.get('device_ns', 0)}ns vs dispatch "
-            f"{measured.get('dispatch_ns', 0)}ns)")
-    return problems
-
-
-def _tpch_scans(scale: float, n_parts: int, batch_rows: int):
-    from ..ops import MemoryScanExec
-    from ..tpch import TPCH_SCHEMAS
-    from ..tpch.datagen import generate_all, table_to_batches
-
-    data = generate_all(scale)
-    scans = {
-        name: MemoryScanExec(
-            table_to_batches(data[name], TPCH_SCHEMAS[name], n_parts,
-                             batch_rows=batch_rows),
-            TPCH_SCHEMAS[name])
-        for name in TPCH_SCHEMAS
-    }
-    n_rows = len(data["lineitem"][next(iter(data["lineitem"]))][0])
-    per_part = (n_rows + n_parts - 1) // n_parts
-    n_batches = n_parts * ((per_part + batch_rows - 1) // batch_rows)
-    return scans, n_batches
-
-
-def run_perfcheck(update: bool = False, inflate: float = 1.0,
-                  registry_path: Optional[str] = None,
-                  out=print) -> Tuple[int, Dict[str, Any]]:
-    """The CLI ``--perfcheck`` body: measure every query pinned in the
-    baseline registry at the registry's pinned scale, diff against the
-    pins (nonzero on drift outside ``spark.blaze.perf.tolerance``), or
-    — with ``update`` — re-pin the registry with fresh measurements +
-    provenance.  ``inflate`` multiplies the measured dispatch/program
-    counts (the gate's own self-test hook: ``--perfcheck-inflate 2``
-    must fail, proving drift detection actually fires).  Returns
-    ``(rc, json_doc)`` with the golden-pinned
-    :data:`PERFCHECK_JSON_KEYS` shape."""
-    from . import dispatch
-
-    if update and inflate != 1.0:
-        # the self-test hook must never be able to pin falsified
-        # counts as the golden baselines (the CLI rejects this too)
-        raise ValueError("inflate is a drift-detection self-test hook "
-                         "and cannot be combined with update")
-    registry_path = registry_path or baselines_path()
-    registry = load_baselines(registry_path)
-    prov = registry.get("provenance", {})
-    scale = float(prov.get("scale", 0.01))
-    n_parts = int(prov.get("parts", 1))
-    batch_rows = int(prov.get("batch_rows", 4096))
-    # the registry's pinned tolerance is the default; the conf knob
-    # overrides when set nonzero (0 = defer to the registry, so the
-    # field in perf_baselines.json is live, not decorative)
-    tolerance = (float(conf.PERF_TOLERANCE.get())
-                 or float(registry.get("tolerance", 0.25)))
-    scans, n_batches = _tpch_scans(scale, n_parts, batch_rows)
-    device_kind = current_device_kind()
-    problems: List[str] = []
-    measured_all: Dict[str, Dict[str, Any]] = {}
-    # the gate JUDGES the estimator's numbers: force it armed for the
-    # measurement even when the operator's conf or env disarmed it
-    # (baseline hbm/bound pins would otherwise read as zero drift).
-    # The batch autotuner is likewise forced armed: the baselines pin
-    # the TUNED warm path (q01/q06 majority-device), and measuring the
-    # untuned path would read as a bound-class flip.
-    force(True)
-    dispatch.autotune_force(True)
-    try:
-        for name in sorted(registry.get("queries", {})):
-            measured_all[name] = measure_query(name, scans, n_parts,
-                                               n_batches)
-    finally:
-        reset()
-        dispatch.autotune_force(None)
-    for name in sorted(registry.get("queries", {})):
-        measured = measured_all[name]
-        if inflate != 1.0:
-            for key in ("warm_dispatches", "programs"):
-                measured[key] = int(round(measured[key] * inflate))
-            measured["dispatches_per_batch"] = round(
-                measured["dispatches_per_batch"] * inflate, 2)
-        measured_all[name] = measured
-        base = registry["queries"][name]
-        qp = [] if update else check_query(name, measured, base, tolerance)
-        problems.extend(qp)
-        out(f"perfcheck {name}: dispatches {measured['warm_dispatches']} "
-            f"({measured['dispatches_per_batch']}/batch)  "
-            f"programs {measured['programs']}  "
-            f"compiles {measured['warm_compiles']}  "
-            f"{measured['bound']}  hbm {100 * measured['hbm_util']:.2f}%"
-            + ("" if not qp else "  <-- DRIFT"))
-    if update:
-        pinned = {
-            name: {k: m[k] for k in (
-                "warm_dispatches", "dispatches_per_batch", "programs",
-                "warm_compiles", "bound", "hbm_util", "mfu_est")}
-            for name, m in measured_all.items()
-        }
-        doc = {
-            "title": registry.get("title", ""),
-            "provenance": {
-                "pinned_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                           time.gmtime()),
-                "device_kind": device_kind,
-                "scale": scale,
-                "parts": n_parts,
-                "batch_rows": batch_rows,
-                # pins were measured with the batch autotuner armed
-                # (the tuned warm path is what the gate protects)
-                "autotune": True,
-            },
-            "tolerance": registry.get("tolerance", 0.25),
-            "queries": pinned,
-        }
-        tmp = f"{registry_path}.tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(doc, f, indent=2)
-            f.write("\n")
-        os.replace(tmp, registry_path)
-        out(f"# perfcheck: re-pinned {len(pinned)} queries to "
-            f"{registry_path} (device {device_kind})")
-    json_doc = {
-        "baselines": registry_path,
-        "tolerance": tolerance,
-        "device_kind": device_kind,
-        "queries": measured_all,
-        "problems": problems,
-        "ok": not problems,
-    }
-    return (1 if problems else 0), json_doc

@@ -86,7 +86,7 @@ EVENT_TYPES = frozenset({
     "worker_lost", "worker_blacklisted", "pool_degraded",
     "worker_telemetry",
     "slo_alert_firing", "slo_alert_resolved",
-    "oom_recovery", "autotune",
+    "oom_recovery",
     "block_corruption", "disk_pressure",
     "mem_watermark", "spill",
     "shuffle_write", "shuffle_fetch", "rss_push",

@@ -609,8 +609,7 @@ def render_json(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         "workers": workers,
         # the whole-query roofline judgment (runtime/perf.py): bytes/
         # flops estimates vs the device peak table -> hbm_util /
-        # mfu_est / bound classification — the measurement ROADMAP
-        # items 3-4 judge batch-size autotuning and bench artifacts by
+        # mfu_est / bound classification
         "perf": qperf,
         # the runtime-stats drift story (runtime/stats.py): worst
         # per-node Q-error, skew findings, stats-store traffic

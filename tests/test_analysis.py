@@ -197,6 +197,28 @@ def test_conf_readme_table_complete():
     assert not missing, f"README conf table missing: {missing}"
 
 
+def test_every_conf_entry_has_a_reader():
+    """A declared key that no module of the package reads is a
+    setting that does nothing: every ``ConfEntry`` constant is named
+    somewhere under ``blaze_tpu/`` outside ``conf.py`` itself."""
+    import re
+
+    pkg = os.path.join(REPO, "blaze_tpu")
+    text = []
+    for d, _, files in os.walk(pkg):
+        for name in files:
+            path = os.path.join(d, name)
+            if name.endswith(".py") and path != os.path.join(pkg, "conf.py"):
+                with open(path) as f:
+                    text.append(f.read())
+    text = "\n".join(text)
+    unread = sorted(
+        name for name, v in vars(conf).items()
+        if isinstance(v, conf.ConfEntry)
+        and not re.search(rf"\b{name}\b", text))
+    assert unread == []
+
+
 # --------------------------------- 3. plan-verifier negative tests
 
 def _scan(n_parts=2, fields=("a", "b")):

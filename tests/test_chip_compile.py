@@ -12,6 +12,7 @@ only one process at a time may load the TPU's library, and every xdist
 worker imports every test file.
 """
 
+import contextlib
 import os
 
 import jax
@@ -101,39 +102,16 @@ def _group_sums(n):
              _shape(n, jnp.float32)))
 
 
-def _sorted_lookup(n):
-    # at the table limit the kernel advertises
-    return (pallas_ops.sorted_lookup,
-            (_shape(pallas_ops.SORTED_LOOKUP_MAX_TABLE, jnp.uint64),
-             _shape(n, jnp.uint64)))
-
-
 @pytest.mark.parametrize("rows", ROWS)
 @pytest.mark.parametrize(
-    "kernel", [_murmur3, _histogram, _group_sums, _sorted_lookup],
-    ids=["murmur3_pids", "pid_histogram", "fused_group_sums",
-         "sorted_lookup_max_table"])
+    "kernel", [_murmur3, _histogram, _group_sums],
+    ids=["murmur3_pids", "pid_histogram", "fused_group_sums"])
 def test_pallas_kernel_compiles_for_the_chip(one_chip, kernel, rows):
     assert not pallas_ops._interpret()
     fn, shapes = kernel(rows)
     compiled = _compile(fn, one_chip, *shapes)
     # the Mosaic kernel itself is in the program, not an interpretation
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_sorted_lookup_over_its_limit_is_a_compiler_refusal(one_chip):
-    """Twice the advertised table does not fit scoped VMEM.  The
-    refusal is RESOURCE_EXHAUSTED by status — and must NOT read as
-    device OOM, or the ladder would spill, halve and fall to the eager
-    rung around a kernel that cannot be built."""
-    from blaze_tpu.runtime.oom import is_resource_exhausted
-
-    with pytest.raises(Exception, match="vmem") as refused:
-        _compile(pallas_ops.sorted_lookup, one_chip,
-                 _shape(2 * pallas_ops.SORTED_LOOKUP_MAX_TABLE, jnp.uint64),
-                 _shape(ROWS[0], jnp.uint64))
-    assert "RESOURCE_EXHAUSTED" in str(refused.value)
-    assert not is_resource_exhausted(refused.value)
 
 
 @pytest.mark.parametrize("key_types", [("int64",), ("int32", "int64", "date32")])
@@ -182,22 +160,11 @@ def test_double_bits_trace_the_chip_branch(one_chip, as_chip, monkeypatch):
     assert taken, "the TPU branch of f64_raw_bits was not traced"
 
 
-@pytest.fixture(scope="module")
-def programs():
-    """Every jitted program q06, q01 and q03 launch through the
-    scheduler path (TaskDefinition bytes per stage) at the CLI's batch
-    capacity, captured at the dispatch seam while the queries run here
-    on the CPU at scale 0.02 — two lineitem batches, so the fused agg
-    UPDATE programs (accumulator + batch) are among them:
-    {query: [(label, fn, args, kwargs)]}.  q01's four groups take the
-    dense update; ``q1_sort_update`` is q01 over a lineitem of 20 line
-    statuses, 60 groups: the sort update every stream of more groups
-    than the dense slots launches, at q01's own shapes."""
-    from blaze_tpu.ops import MemoryScanExec
+@contextlib.contextmanager
+def _launches():
+    """Every jitted program launched inside, captured at the dispatch
+    seam with its arrays as shapes: {key: (label, fn, args, kwargs)}."""
     from blaze_tpu.runtime import dispatch
-    from blaze_tpu.runtime.scheduler import run_stages, split_stages
-    from blaze_tpu.tpch import TPCH_SCHEMAS, build_query
-    from blaze_tpu.tpch.datagen import generate_all, table_to_batches
 
     def spec(x):
         if isinstance(x, (jax.Array, np.ndarray)):
@@ -213,6 +180,29 @@ def programs():
             shapes = jax.tree_util.tree_map(spec, (a, k))
             seen.setdefault((id(fn), str(shapes)), (label, fn) + shapes)
         return out
+
+    dispatch._oom_call = recording
+    try:
+        yield seen
+    finally:
+        dispatch._oom_call = real
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """Every jitted program q06, q01 and q03 launch through the
+    scheduler path (TaskDefinition bytes per stage) at the CLI's batch
+    capacity, captured at the dispatch seam while the queries run here
+    on the CPU at scale 0.02 — two lineitem batches, so the fused agg
+    UPDATE programs (accumulator + batch) are among them:
+    {query: [(label, fn, args, kwargs)]}.  q01's four groups take the
+    dense update; ``q1_sort_update`` is q01 over a lineitem of 20 line
+    statuses, 60 groups: the sort update every stream of more groups
+    than the dense slots launches, at q01's own shapes."""
+    from blaze_tpu.ops import MemoryScanExec
+    from blaze_tpu.runtime.scheduler import run_stages, split_stages
+    from blaze_tpu.tpch import TPCH_SCHEMAS, build_query
+    from blaze_tpu.tpch.datagen import generate_all, table_to_batches
 
     data = generate_all(0.02)
     scans = {
@@ -230,16 +220,13 @@ def programs():
         table_to_batches(many, TPCH_SCHEMAS["lineitem"], 1, batch_rows=CAPACITY),
         TPCH_SCHEMAS["lineitem"]))
     out = {}
-    dispatch._oom_call = recording
-    try:
+    with _launches() as seen:
         for name, q, tables in (("q6", "q6", scans), ("q1", "q1", scans), ("q3", "q3", scans),
                                 ("q1_sort_update", "q1", many_groups)):
             before = set(seen)
             stages, manager = split_stages(build_query(q, tables, 1))
             assert sum(b.num_rows for b in run_stages(stages, manager)) > 0
             out[name] = [seen[k] for k in seen if k not in before]
-    finally:
-        dispatch._oom_call = real
     return out
 
 
@@ -267,3 +254,150 @@ def test_query_programs_compile_for_the_chip(one_chip, programs, as_chip,
             x.shape[0] for x in jax.tree_util.tree_leaves((args, kwargs))
             if isinstance(x, jax.ShapeDtypeStruct) and x.shape])
     assert widest == CAPACITY  # the scan-side program ran at full capacity
+
+
+# ------------------------- operators no benchmark query launches
+
+SMALLEST = 1000  # rows under the smallest capacity bucket (1,024)
+SORTING = 8192   # a sort compiles in time linear in rows x operands
+
+
+def _one_batch(schema, rows):
+    from blaze_tpu.batch import batch_from_pydict
+    from blaze_tpu.ops import MemoryScanExec
+
+    return MemoryScanExec([[batch_from_pydict(rows, schema)]], schema)
+
+
+def _window_plan():
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.sort import SortField
+    from blaze_tpu.ops.window import WindowExec, WindowFunction
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    schema = Schema([Field("g", DataType.int64()), Field("v", DataType.int64())])
+    scan = _one_batch(schema, {"g": sorted(i % 5 for i in range(SORTING)),
+                               "v": [i * 7 % 13 for i in range(SORTING)]})
+    return WindowExec(scan, [WindowFunction("row_number", "rn")],
+                      [col("g")], [SortField(col("v"), True, True)])
+
+
+def _sort_merge_join_plan():
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops import SortExec, SortField
+    from blaze_tpu.ops.joins import SortMergeJoinExec
+    from blaze_tpu.ops.joins.core import JoinType
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    left = Schema([Field("k", DataType.int64()), Field("a", DataType.int32())])
+    right = Schema([Field("sk", DataType.int64()), Field("b", DataType.int32())])
+    n = SORTING
+    l_scan = _one_batch(left, {"k": [i * 3 % n for i in range(n)],
+                               "a": list(range(n))})
+    r_scan = _one_batch(right, {"sk": [i * 5 % n for i in range(n // 2)],
+                                "b": list(range(n // 2))})
+    return SortMergeJoinExec(
+        SortExec(l_scan, [SortField(col("k"))]),
+        SortExec(r_scan, [SortField(col("sk"))]),
+        [col("k")], [col("sk")], JoinType.INNER)
+
+
+def _expand_plan():
+    from blaze_tpu.exprs import col
+    from blaze_tpu.exprs.ir import BinOp, Lit
+    from blaze_tpu.ops.expand import ExpandExec
+    from blaze_tpu.ops.filter import FilterExec
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    i64 = DataType.int64()
+    scan = _one_batch(Schema([Field("k", i64)]), {"k": list(range(SMALLEST))})
+    e = ExpandExec(
+        scan,
+        [[col("k"), Lit(0, i64)],
+         [BinOp("*", col("k"), Lit(2, i64)), Lit(1, i64)]],
+        ["v", "tag"])
+    return FilterExec(e, BinOp(">", col("v"), Lit(10, i64)))
+
+
+def _generate_plan():
+    from blaze_tpu.exprs import col
+    from blaze_tpu.exprs.ir import Alias, BinOp, Lit
+    from blaze_tpu.ops.filter import FilterExec
+    from blaze_tpu.ops.generate import GenerateExec, NativeGenerator
+    from blaze_tpu.ops.project import ProjectExec
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    i64 = DataType.int64()
+    schema = Schema([Field("k", i64), Field("xs", DataType.array(i64, 4))])
+    scan = _one_batch(schema, {
+        "k": list(range(SMALLEST)),
+        "xs": [[i, i + 1, i + 2][: (i % 4)] or None for i in range(SMALLEST)]})
+    g = GenerateExec(scan, NativeGenerator("explode", col("xs")), [col("xs")])
+    f = FilterExec(g, BinOp(">", col("col"), Lit(5, i64)))
+    return ProjectExec(
+        f, [col("k"), Alias(BinOp("+", col("col"), Lit(1, i64)), "c1")],
+        ["k", "c1"])
+
+
+def _launch_plan(plan_fn):
+    from blaze_tpu.ops.fusion import optimize_plan
+    from blaze_tpu.runtime.context import TaskContext
+
+    plan = optimize_plan(plan_fn())
+    with _launches() as seen:
+        assert sum(b.num_rows for b in plan.execute(0, TaskContext(0, 1))) > 0
+    return list(seen.values())
+
+
+Q7_HOT_MAP_ROWS = 27_440  # q7's filtered customer_demographics build side
+
+
+def _launch_q7_hot_probe():
+    """One full-size probe batch (65,536 rows, NULL keys among them)
+    against a map of q7's hot size, through ``Joiner.probe_batch``."""
+    from blaze_tpu.batch import batch_from_pydict
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins.core import Joiner, JoinerState, JoinType
+    from blaze_tpu.schema import DataType, Field, Schema
+
+    rng = np.random.default_rng(7)
+    build = Schema([Field("k", DataType.int64()), Field("b", DataType.int32())])
+    probe = Schema([Field("k", DataType.int64()), Field("p", DataType.int32())])
+    joiner = Joiner(probe, build, [col("k")], [col("k")], JoinType.INNER, True)
+    keys = rng.permutation(1_920_800)[:Q7_HOT_MAP_ROWS]
+    jmap = joiner.build_map(batch_from_pydict(
+        {"k": [int(k) for k in keys], "b": list(range(Q7_HOT_MAP_ROWS))}, build))
+    probes = rng.integers(0, 1_920_800, CAPACITY)
+    batch = batch_from_pydict(
+        {"k": [None if i % 50 == 0 else int(k) for i, k in enumerate(probes)],
+         "p": list(range(CAPACITY))}, probe)
+    with _launches() as seen:
+        assert joiner.probe_batch(jmap, batch, JoinerState()).num_rows > 0
+    return list(seen.values())
+
+
+@pytest.mark.parametrize("launch,labels,widest", [
+    (lambda: _launch_plan(_window_plan), {"window"}, SORTING),
+    (lambda: _launch_plan(_sort_merge_join_plan),
+     {"sort", "join_build_kernel", "join_candidate", "join_probe"}, SORTING),
+    (lambda: _launch_plan(_expand_plan), {"fused_stage"}, 1024),
+    (lambda: _launch_plan(_generate_plan), {"fused_stage"}, 1024),
+    (_launch_q7_hot_probe, {"join_candidate", "join_probe"}, CAPACITY),
+], ids=["window", "sort_merge_join", "expand", "generate", "join_q7_hot_map"])
+def test_operator_programs_compile_for_the_chip(one_chip, as_chip, launch,
+                                                labels, widest):
+    """Window, sort-merge join, expand and generate — operators none of
+    q06/q01/q03 plans — and the Joiner's candidate and probe programs
+    at the shape q7 launches most (a 27,440-row map in its 32,768
+    bucket, 65,536 probe rows): run here on the CPU, each program
+    captured at the dispatch seam and compiled for the chip at the
+    shapes it really launched."""
+    found = launch()
+    assert labels <= {p[0] for p in found}, sorted({p[0] for p in found})
+    seen_widest = 0
+    for label, fn, args, kwargs in found:
+        _compile(fn, one_chip, *args, **kwargs)
+        seen_widest = max([seen_widest] + [
+            x.shape[0] for x in jax.tree_util.tree_leaves((args, kwargs))
+            if isinstance(x, jax.ShapeDtypeStruct) and x.shape])
+    assert seen_widest == widest

@@ -10,7 +10,6 @@ import subprocess
 import sys
 
 import jax
-import numpy as np
 import pytest
 
 from blaze_tpu import conf
@@ -185,30 +184,8 @@ def test_string_keys_still_take_the_xla_hash_quietly(interpret,
     assert not calls
 
 
-def test_failing_pallas_probe_kernel_raises_through_probe_counts(monkeypatch):
-    import jax.numpy as jnp
-
-    from blaze_tpu.ops.joins.core import probe_counts, run_lengths
-
-    def broken(*a, **k):
-        raise RuntimeError("Mosaic failed to compile sorted_lookup")
-
-    table = jnp.asarray(np.arange(16, dtype=np.uint64))
-    probes = jnp.asarray(np.arange(8, dtype=np.uint64))
-    want = [np.asarray(x) for x in probe_counts(table, run_lengths(table), probes)]
-    monkeypatch.setattr(pallas_ops, "sorted_lookup", broken)
-    with pytest.raises(RuntimeError, match="sorted_lookup"):
-        probe_counts(table, run_lengths(table), probes, use_pallas=True)
-    # over the table bound is dispatch on size: the XLA path, no kernel
-    big = jnp.asarray(np.arange(pallas_ops.SORTED_LOOKUP_MAX_TABLE + 1,
-                                dtype=np.uint64))
-    lo, counts = probe_counts(big, run_lengths(big), probes, use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(lo), want[0])
-    np.testing.assert_array_equal(np.asarray(counts), want[1])
-
-
 @pytest.mark.parametrize("message,is_oom", [
-    # what the chip's compiler says of sorted_lookup over its limit
+    # what the chip's compiler said of a Pallas kernel over scoped VMEM
     ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
      "allocating on stack for %sorted_lookup.1 ... Scoped allocation with "
      "size 26.95M and limit 16.00M exceeded scoped vmem limit", False),

@@ -64,6 +64,11 @@ TABLES = {
     "full_no_tail": dict(live_share=1.0, distinct_share=0.3),
     "all_sentinel": dict(live_share=0.0, distinct_share=0.0),
     "one_live_row": dict(live_share=0.0, distinct_share=0.0, live=1),
+    # a table of a fixed size whatever the cap: alone under the small
+    # cap (no tail, not a power of two), in its bucket under the large
+    "over_4096": dict(live=4097, distinct_share=0.2),
+    # q7's filtered customer_demographics: unique keys, NULL probes
+    "q7_hot_map": dict(live=27_440, distinct_share=4.0),
 }
 
 
@@ -72,7 +77,8 @@ TABLES = {
 def test_probe_counts_equals_the_two_sided_search(cap, shape):
     spec = TABLES[shape]
     rng = np.random.default_rng(cap + len(shape))
-    live = spec.get("live", int(cap * spec["live_share"]))
+    live = spec.get("live", int(cap * spec.get("live_share", 0)))
+    cap = max(cap, live)
     table = _table(rng, cap, live, int(live * spec["distinct_share"]))
     probes = _probes(rng, table, 4096)
 

@@ -192,8 +192,9 @@ def test_split_batch_halves_preserve_rows():
     assert oom.split_batch(one) == [one]
 
 
-def _fused_chain_plan(n_rows=600, parts=2):
-    """scan -> filter -> project collapsed into one FusedStageExec."""
+def _fused_chain_plan(n_rows=600, parts=2, batches=1):
+    """scan -> filter -> project collapsed into one FusedStageExec;
+    ``batches`` batches of ``n_rows // parts`` rows a partition."""
     from blaze_tpu.batch import batch_from_pydict
     from blaze_tpu.exprs import col
     from blaze_tpu.exprs.ir import Alias, BinOp, Lit
@@ -207,13 +208,13 @@ def _fused_chain_plan(n_rows=600, parts=2):
                      Field("y", DataType.int64())])
     rng = np.random.RandomState(3)
     per = n_rows // parts
-    batches = [
+    scan = MemoryScanExec([
         [batch_from_pydict(
             {"x": [int(v) for v in rng.randint(0, 100, per)],
-             "y": [int(v) for v in rng.randint(0, 100, per)]}, schema)]
+             "y": [int(v) for v in rng.randint(0, 100, per)]}, schema)
+         for _ in range(batches)]
         for _ in range(parts)
-    ]
-    scan = MemoryScanExec(batches, schema)
+    ], schema)
     f = FilterExec(scan, BinOp(">", col("x"), Lit(20, DataType.int64())))
     p = ProjectExec(f, [col("x"),
                         Alias(BinOp("+", col("y"), Lit(1, DataType.int64())),
@@ -334,6 +335,90 @@ def test_fused_write_oom_falls_back_byte_identical(tmp_path):
         degraded = write("degraded", sabotage=True)
     assert degraded == clean
     assert cap.get("eager_fallbacks") == 1
+
+
+def _partitioning(kind, schema, n_out=3):
+    from blaze_tpu.batch import batch_from_pydict
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.sort import SortField
+    from blaze_tpu.parallel.exchange import _build_range_kernels
+    from blaze_tpu.parallel.shuffle import (
+        HashPartitioning, RangePartitioning, RoundRobinPartitioning)
+
+    if kind == "hash":
+        return HashPartitioning([col("x")], n_out)
+    if kind == "round-robin":
+        return RoundRobinPartitioning(n_out)
+    part = RangePartitioning([SortField(col("x"))], n_out)
+    # x is uniform on [0, 100): the cut points' own key words are the
+    # boundaries (any consistent set is; the driver's pass samples them)
+    cuts = batch_from_pydict({"x": [33, 66], "y1": [0, 0]}, schema)
+    key_words, _, _ = _build_range_kernels(schema, part.fields, n_out)
+    part.boundaries = tuple(
+        np.asarray(w)[:n_out - 1]
+        for w in key_words(tuple(cuts.columns), cuts.num_rows))
+    return part
+
+
+@pytest.mark.parametrize("ooms,recoveries,eager", [
+    (1, 1, 0),  # spill, retry the same program, stay fused
+    (2, 1, 1),  # the retry fails too: per-kernel path for the rest
+], ids=["once", "twice"])
+@pytest.mark.parametrize("kind", ["hash", "round-robin", "range"])
+def test_fused_shuffle_write_oom_ladder_byte_identical(tmp_path, kind, ooms,
+                                                       recoveries, eager):
+    """The fused write's own ladder, by an injected ``@oom`` at the
+    SECOND batch's launch (the round-robin offset has advanced on the
+    device by then): one exhaustion spills and re-runs the program;
+    a second one decomposes to the per-kernel path for the rest of
+    the stream, resyncing the round-robin offset to the host.  Either
+    way the committed bytes and index are the unfused writer's."""
+    from blaze_tpu.ops.fusion import optimize_plan
+    from blaze_tpu.parallel.shuffle import ShuffleWriterExec
+    from blaze_tpu.runtime.context import TaskContext
+
+    def write(tag):
+        data = str(tmp_path / f"{tag}.data")
+        index = str(tmp_path / f"{tag}.index")
+        plan = plans.pop()
+        w = optimize_plan(ShuffleWriterExec(
+            plan, _partitioning(kind, plan.schema), data, index))
+        list(w.execute(0, TaskContext(0, 1)))
+        with open(data, "rb") as f, open(index, "rb") as g:
+            return f.read(), g.read(), w
+
+    # the chain fuses while fusion is on; only the WRITER runs unfused
+    plans = [_fused_chain_plan(n_rows=300, parts=1, batches=4)
+             for _ in range(2)]
+    conf.FUSION_ENABLE.set(False)
+    try:
+        *unfused, wu = write("unfused")
+    finally:
+        conf.FUSION_ENABLE.set(True)
+    assert wu._fused_write is None
+    # the stream's only launches are the fused writes, one a batch:
+    # hit 2 is batch 2's launch and hit 3 its retry after the spill
+    conf.FAULTS_SPEC.set(",".join(
+        f"kernel.dispatch@{2 + i}@oom" for i in range(ooms)))
+    faults.reset()
+    with dispatch.capture() as cap:
+        *degraded, w = write("degraded")
+    assert w._fused_write is not None and w._fused_fns
+    assert degraded == unfused
+    assert cap.get("oom_recoveries", 0) == recoveries, cap
+    assert cap.get("eager_fallbacks", 0) == eager, cap
+    assert not cap.get("batch_downshifts"), cap
+
+
+def test_device_oom_error_not_reabsorbed_as_resource_exhausted():
+    """The OOM ladder's TERMINAL verdict must not re-enter the ladder:
+    DeviceOomError classifies non-absorbable even though its message
+    embeds the cause's RESOURCE_EXHAUSTED text."""
+    err = oom.DeviceOomError(
+        "fused_write: RESOURCE_EXHAUSTED: out of memory")
+    assert not oom.is_resource_exhausted(err)
+    assert oom.is_resource_exhausted(
+        RuntimeError("RESOURCE_EXHAUSTED: out of memory"))
 
 
 def test_injected_oom_absorbed_end_to_end():

@@ -1,6 +1,5 @@
 """Performance introspection layer (tier-1, CPU backend) —
-runtime/perf.py: EXPLAIN ANALYZE, roofline/MFU attribution, and the
-perf-baseline regression gate.
+runtime/perf.py: EXPLAIN ANALYZE and roofline/MFU attribution.
 
 1. **EXPLAIN ANALYZE** (acceptance): a real warm TPC-H q01 run through
    the stage scheduler yields an explain tree that attributes >= 80%
@@ -15,26 +14,22 @@ perf-baseline regression gate.
    reproduced mechanically); collapsing an unfused run's program count
    to the fused run's under the remote chip's per-program floor flips
    dispatch-bound -> memory-or-compute-bound.
-4. **Perf-baseline gate**: --perfcheck machinery passes on HEAD over
-   the TPC-H slice, FIRES on a seeded 2x dispatch inflation, and
-   --perfcheck --update round-trips (re-pin then clean).
-5. **Estimator cost contract**: disarmed, the dispatch choke point
+4. **Estimator cost contract**: disarmed, the dispatch choke point
    never enters the estimator (poisoned — one bool read, the
    trace.enabled pattern); armed, a real program records nonzero
    bytes/flops.
-6. **Monitor endpoint**: /queries/<id>/explain serves the rendered
+5. **Monitor endpoint**: /queries/<id>/explain serves the rendered
    explain for a traced run, a comment for an untraced one, 404 for an
    unknown query.
-7. **Terminal-status rendering**: --report (text + JSON) renders
+6. **Terminal-status rendering**: --report (text + JSON) renders
    cleanly — explicit status banner, no KeyError — over event logs of
    queries that ended failed / cancelled / deadline_exceeded, and over
    a truncated log with no terminal event at all.
-8. **Golden pins**: EXPLAIN_JSON_KEYS / PERFCHECK_JSON_KEYS top-level
-   shapes, and the --report --json ``perf`` section.
+7. **Golden pins**: the EXPLAIN_JSON_KEYS top-level shape, and the
+   --report --json ``perf`` section.
 """
 
 import json
-import shutil
 import urllib.error
 import urllib.request
 
@@ -404,139 +399,7 @@ def test_real_log_carries_device_stamp(q1_events):
     assert perf.device_kind_from_events(q1_events)
 
 
-# --------------------------------------------- 4. perf-baseline gate
-
-@pytest.fixture(scope="module")
-def perfcheck_result():
-    """ONE real measurement sweep shared by the gate tests (tier-1
-    budget: the sweep is 5 warm queries at pinned scale)."""
-    rc, doc = perf.run_perfcheck()
-    return rc, doc
-
-
-def test_perfcheck_clean_on_head(perfcheck_result):
-    """Acceptance: --perfcheck finds no drift on HEAD over the TPC-H
-    slice in what is exact: warm dispatches and programs equal their
-    pins, no warm compile.  A `bound` class flip comes from two host
-    clocks (device_ns against dispatch_ns), flapped under six workers
-    and is not held against HEAD here (PR 27)."""
-    _, doc = perfcheck_result
-    exact = [p for p in doc["problems"] if "bound class flipped" not in p]
-    assert exact == []
-    assert len(doc["queries"]) >= 5
-    pins = perf.load_baselines()["queries"]
-    for name, m in doc["queries"].items():
-        assert m["warm_compiles"] == 0, (name, m)
-        assert (m["warm_dispatches"], m["programs"]) == (
-            pins[name]["warm_dispatches"], pins[name]["programs"]), (name, m)
-
-
-def test_perfcheck_json_golden_keys(perfcheck_result):
-    _, doc = perfcheck_result
-    assert set(perf.PERFCHECK_JSON_KEYS) <= set(doc)
-    for m in doc["queries"].values():
-        assert {"warm_dispatches", "dispatches_per_batch", "programs",
-                "warm_compiles", "bound", "hbm_util", "mfu_est"} <= set(m)
-    json.dumps(doc)
-
-
-def test_perfcheck_fires_on_seeded_dispatch_inflation(perfcheck_result):
-    """Acceptance: a seeded 2x dispatch inflation is DETECTED — drift
-    detection actually fires, it is not a tautology."""
-    _, doc = perfcheck_result
-    registry = perf.load_baselines()
-    # same resolution run_perfcheck uses: conf override when nonzero,
-    # else the registry's pinned tolerance
-    tolerance = (float(conf.PERF_TOLERANCE.get())
-                 or float(registry.get("tolerance", 0.25)))
-    fired = 0
-    for name, base in registry["queries"].items():
-        measured = dict(doc["queries"][name])
-        measured["warm_dispatches"] *= 2
-        measured["programs"] *= 2
-        problems = perf.check_query(name, measured, base, tolerance)
-        assert problems, f"{name}: 2x inflation not detected"
-        fired += len(problems)
-    assert fired >= len(registry["queries"])
-
-
-def test_perfcheck_improvement_also_drifts():
-    """Drift is two-sided: a silent improvement must be re-pinned, not
-    absorbed (the registry stays meaningful)."""
-    base = {"warm_dispatches": 100, "programs": 100, "warm_compiles": 0,
-            "bound": "dispatch-bound"}
-    measured = {"warm_dispatches": 50, "programs": 50, "warm_compiles": 0,
-                "bound": "dispatch-bound", "device_ns": 1,
-                "dispatch_ns": 100}
-    problems = perf.check_query("qx", measured, base, 0.25)
-    assert problems and "improved" in problems[0]
-
-
-def test_perfcheck_bound_flip_borderline_is_noise():
-    """A bound-class flip across a borderline dispatch/device split —
-    within 10x either way, or with neither side past the absolute
-    magnitude floor — is measurement noise, not drift: a loaded CI
-    host swings the CPU backend's split 4-8x and collapses a small
-    query's device reading to near zero (q6 at perfcheck scale:
-    device 0.14 ms vs dispatch 8.8 ms under full-suite load), while a
-    dispatch-floor re-fragmentation moves the ratio over an order of
-    magnitude AND the dispatch wall into the hundreds of ms."""
-    base = {"warm_dispatches": 10, "programs": 10, "warm_compiles": 0,
-            "bound": "dispatch-bound"}
-    ms = 1_000_000
-    for dev, disp in ((100 * ms, 90 * ms), (100 * ms, 11 * ms),
-                      (100 * ms, 950 * ms),
-                      # decisive RATIO but under the magnitude floor —
-                      # the real q6 full-suite-load reading
-                      (138589, 8841526)):
-        noisy = {"warm_dispatches": 10, "programs": 10,
-                 "warm_compiles": 0, "bound": "memory-bound",
-                 "device_ns": dev, "dispatch_ns": disp}
-        assert perf.check_query("qx", noisy, base, 0.25) == [], (dev, disp)
-    decisive = {"warm_dispatches": 10, "programs": 10,
-                "warm_compiles": 0, "bound": "memory-bound",
-                "device_ns": 1000 * ms, "dispatch_ns": 10 * ms}
-    problems = perf.check_query("qx", decisive, base, 0.25)
-    assert problems and "flipped" in problems[0]
-
-
-def test_perfcheck_rejects_update_plus_inflate():
-    """The self-test hook must never be able to pin falsified counts
-    as golden baselines."""
-    with pytest.raises(ValueError, match="self-test"):
-        perf.run_perfcheck(update=True, inflate=2.0)
-
-
-def test_perfcheck_update_roundtrip(tmp_path, monkeypatch):
-    """--perfcheck --update re-pins the registry (with provenance) and
-    a subsequent check against the re-pinned registry is clean — the
-    round-trip, run against canned measurements so it stays fast."""
-    reg_path = tmp_path / "baselines.json"
-    shutil.copy(perf.BASELINES_PATH, reg_path)
-    canned = {"rows": 1, "warm_dispatches": 999, "dispatches_per_batch":
-              9.9, "programs": 999, "warm_compiles": 0,
-              "device_ns": 10, "dispatch_ns": 100,
-              "hbm_bytes_est": 1000, "flops_est": 100,
-              "hbm_util": 0.01, "mfu_est": 0.001,
-              "bound": "dispatch-bound"}
-    monkeypatch.setattr(perf, "measure_query",
-                        lambda *a, **k: dict(canned))
-    rc, _ = perf.run_perfcheck(update=True, registry_path=str(reg_path))
-    assert rc == 0
-    pinned = perf.load_baselines(str(reg_path))
-    assert pinned["queries"]["q1"]["warm_dispatches"] == 999
-    assert pinned["provenance"]["pinned_at"]
-    assert pinned["provenance"]["device_kind"]
-    # the re-pinned registry is immediately clean against the same
-    # measurements...
-    rc, doc = perf.run_perfcheck(registry_path=str(reg_path))
-    assert rc == 0, doc["problems"]
-    # ...and still fires on inflation against the new pins
-    rc, doc = perf.run_perfcheck(registry_path=str(reg_path), inflate=2.0)
-    assert rc == 1 and doc["problems"]
-
-
-# ------------------------------------- 5. estimator cost contract
+# ------------------------------------- 4. estimator cost contract
 
 def test_disarmed_estimator_never_entered(monkeypatch):
     """spark.blaze.perf.estimates=false keeps the traced dispatch path
@@ -606,7 +469,7 @@ def test_chaos_perf_gate_passes():
     assert _check_perf_gate() == 0
 
 
-# --------------------------------------------- 6. monitor endpoint
+# --------------------------------------------- 5. monitor endpoint
 
 def test_monitor_explain_endpoint(data, tmp_path):
     conf.MONITOR_ENABLE.set(True)
@@ -676,7 +539,7 @@ def test_monitor_explain_404_on_unknown(data):
         monitor.reset()
 
 
-# ------------------------------- 7. terminal-status report rendering
+# ------------------------------- 6. terminal-status report rendering
 
 def _terminal_events(data, tmp_path, exc, query_id):
     """A REAL partial event log: stage 0 completes, then the query

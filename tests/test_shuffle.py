@@ -335,3 +335,96 @@ def test_range_partitioning_across_serde_file_shuffle():
         assert ex.partitioning.boundaries is not None
     finally:
         conf.EXCHANGE_IN_PROCESS.set(old)
+
+
+# ------------------------------- the write loop's device staging ring
+
+
+@pytest.fixture(scope="module")
+def tpch_data():
+    from blaze_tpu.tpch.datagen import generate_all
+
+    return generate_all(0.01)
+
+
+def _agg_write(data, tmp, tag):
+    """FINAL(PARTIAL(lineitem by l_returnflag)) under an optimized hash
+    shuffle writer; returns (writer, data_path, index_path)."""
+    import os
+
+    from blaze_tpu.ops.fusion import optimize_plan
+    from blaze_tpu.parallel.shuffle import ShuffleWriterExec
+    from blaze_tpu.tpch import TPCH_SCHEMAS
+    from blaze_tpu.tpch.datagen import table_to_batches
+
+    sch = TPCH_SCHEMAS["lineitem"]
+    scan = MemoryScanExec(
+        table_to_batches(data["lineitem"], sch, 1, batch_rows=2048), sch)
+    groupings = [GroupingExpr(col("l_returnflag"), "l_returnflag")]
+    aggs = [AggFunction("sum", col("l_quantity"), "sum_qty"),
+            AggFunction("count_star", None, "cnt")]
+    partial = AggExec(scan, AggMode.PARTIAL, groupings, aggs)
+    final = AggExec(partial, AggMode.FINAL, groupings, aggs)
+    data_path = os.path.join(tmp, f"{tag}.data")
+    index_path = os.path.join(tmp, f"{tag}.index")
+    writer = optimize_plan(ShuffleWriterExec(
+        final, HashPartitioning([col("l_returnflag")], 3),
+        data_path, index_path))
+    return writer, data_path, index_path
+
+
+def _hash_write(data, tmp, tag):
+    writer, data_path, index_path = _agg_write(data, tmp, tag)
+    list(writer.execute(0, TaskContext(0, 1)))
+    with open(data_path, "rb") as f, open(index_path, "rb") as g:
+        return f.read(), g.read()
+
+
+def test_abort_mid_stream_drops_ring_without_commit(tpch_data, tmp_path):
+    """A task killed mid-stream (injected non-OOM fault — the same
+    seam a ctx cancel rides) drops the device ring and aborts the
+    async writer: nothing commits, and a fresh run afterwards still
+    produces the canonical bytes (no poisoned process state)."""
+    import os
+
+    from blaze_tpu import conf
+    from blaze_tpu.runtime import faults
+
+    tmp = str(tmp_path)
+    plain_blob, plain_idx = _hash_write(tpch_data, tmp, "plain")
+    conf.FAULTS_SPEC.set("kernel.dispatch@4@a0")
+    faults.reset()
+    try:
+        writer, data_path, index_path = _agg_write(tpch_data, tmp, "m")
+        with pytest.raises(faults.InjectedFault):
+            list(writer.execute(0, TaskContext(0, 1)))
+        assert not os.path.exists(data_path), \
+            "aborted task committed a partial .data file"
+        assert not os.path.exists(index_path)
+        conf.FAULTS_SPEC.set("")
+        faults.reset()
+        # the seam leaks nothing into process state: a clean run after
+        # the abort still commits the canonical bytes
+        blob2, idx2 = _hash_write(tpch_data, tmp, "again")
+    finally:
+        conf.FAULTS_SPEC.set("")
+        faults.reset()
+    assert blob2 == plain_blob and idx2 == plain_idx
+
+
+def test_device_ring_fifo_and_overlap_metric():
+    from blaze_tpu.batch import DeviceRing
+    from blaze_tpu.runtime import dispatch
+
+    ring = DeviceRing()
+    with dispatch.capture() as cap:
+        out = []
+        for i in range(5):
+            out.extend(ring.put(i))
+        out.extend(ring.flush())
+    assert out == [0, 1, 2, 3, 4], "ring must preserve FIFO order"
+    assert len(ring) == 0
+    assert cap.get("double_buffer_overlap_ns", 0) > 0
+    ring.put(9)
+    ring.drop()
+    assert len(ring) == 0 and ring.flush() == []

@@ -323,8 +323,6 @@ def _synthetic_events():
                              "stage_id": 1, "task": 0}),
         ("oom_recovery", {"label": "fused_stage", "action": "downshift",
                           "rows": 4096, "depth": 1}),
-        ("autotune", {"action": "grow", "target_rows": 32768,
-                      "device_share": 0.31, "label": "q1"}),
         ("fault_injected", {"site": "shuffle.fetch", "hit": 2,
                             "attempt": 0, "detail": "shuffle_0"}),
         ("straggler_injected", {"site": "shuffle.write", "hit": 1,
