@@ -3,9 +3,10 @@ broadcast_join_exec.rs:76-567, sort_merge_join_exec.rs:58-309).
 
 TPU design (joins/core.py): the "hash map" is a **sorted key table** —
 build keys reduce to 64-bit hashes, sorted on device with their row
-indices and each key's run length; probes binary-search the sorted
-table once a batch (vectorized ``searchsorted``; a match range is the
-found key's run), expand match ranges with the two-phase
+indices, each key's run length and the offsets of its hash-prefix
+buckets; probes binary-search the sorted table once a batch, inside the
+bucket of each probe key's prefix (a match range is the found key's
+run), expand match ranges with the two-phase
 count/cumsum/gather pattern, then **verify** candidate pairs against
 the real key columns (so 64-bit collisions and null keys can never
 produce wrong matches — exactness does not rest on the hash).

@@ -2,10 +2,13 @@
 
 ≙ reference join_hash_map.rs (open-addressing u32 map with raw-bytes
 serialization for broadcast) — rebuilt for XLA: no pointer chasing, no
-data-dependent probe loops; everything is sort, cumulative scans,
-gather and ONE searchsorted of the key table per probe batch (the upper
-bound of a candidate range is its run length, kept in the map).  The
-map itself is a pytree of three device arrays beside the build batch,
+per-row probe loops; everything is sort, cumulative scans, gather and
+ONE binary search of the key table per probe batch.  The keys are
+hashes, uniform in their high bits, so the search starts inside the
+bucket of the probe key's hash prefix (bucket offsets kept in the map)
+and runs as many steps as the map's largest bucket needs; the upper
+bound of a candidate range is its run length, kept in the map too.  The
+map itself is a pytree of five device arrays beside the build batch,
 trivially serializable/broadcastable like the reference's raw-bytes map.
 
 All kernels are per-Joiner jitted closures — Exprs never appear as jit
@@ -50,24 +53,27 @@ class JoinMap:
     """Sorted build-side key table + the build batch it indexes.
 
     Raw-bytes serializable (≙ join_hash_map.rs:290-454): the serialized
-    form carries the sorted table, its run lengths AND the data batch,
-    so a probe-side executor rebuilds it with buffer copies only — no
-    re-sort, no key re-hash, no run-length pass."""
+    form carries the sorted table, its run lengths, its bucket offsets
+    AND the data batch, so a probe-side executor rebuilds it with buffer
+    copies only — no re-sort, no key re-hash, no run-length or bucket
+    pass."""
 
-    sorted_keys: jnp.ndarray   # uint64 (cap,) sorted
-    sorted_rows: jnp.ndarray   # int32 (cap,) original row per key
-    run_lens: jnp.ndarray      # int32 (cap,) positions j >= i holding sorted_keys[i]
-    num_rows: int              # live build rows (static)
-    batch: RecordBatch         # build-side data
+    sorted_keys: jnp.ndarray     # uint64 (cap,) sorted
+    sorted_rows: jnp.ndarray     # int32 (cap,) original row per key
+    run_lens: jnp.ndarray        # int32 (cap,) positions j >= i holding sorted_keys[i]
+    bucket_offsets: jnp.ndarray  # int32 (2**b + 1,) where each hash prefix starts; [-1] = live keys
+    max_bucket: jnp.ndarray      # int32 () live keys in the largest bucket
+    num_rows: int                # live build rows (static)
+    batch: RecordBatch           # build-side data
 
     def tree_flatten(self):
-        return ((self.sorted_keys, self.sorted_rows, self.run_lens, self.batch),
-                (self.num_rows,))
+        return ((self.sorted_keys, self.sorted_rows, self.run_lens,
+                 self.bucket_offsets, self.max_bucket, self.batch), (self.num_rows,))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        sk, sr, rl, batch = children
-        return cls(sk, sr, rl, aux[0], batch)
+        *index, batch = children
+        return cls(*index, aux[0], batch)
 
     def serialize(self) -> bytes:
         import struct
@@ -77,8 +83,10 @@ class JoinMap:
         sk = np.asarray(self.sorted_keys, dtype=np.uint64)
         sr = np.asarray(self.sorted_rows, dtype=np.int32)
         rl = np.asarray(self.run_lens, dtype=np.int32)
-        head = struct.pack("<II", self.num_rows, sk.shape[0])
-        return (head + sk.tobytes() + sr.tobytes() + rl.tobytes()
+        bo = np.asarray(self.bucket_offsets, dtype=np.int32)
+        head = struct.pack("<IIII", self.num_rows, sk.shape[0], bo.shape[0],
+                           int(self.max_bucket))
+        return (head + sk.tobytes() + sr.tobytes() + rl.tobytes() + bo.tobytes()
                 + serialize_batch(self.batch))
 
     @classmethod
@@ -87,21 +95,24 @@ class JoinMap:
 
         from ...io.batch_serde import deserialize_batch
 
-        num_rows, cap = struct.unpack_from("<II", data, 0)
-        off = 8
+        num_rows, cap, n_offsets, max_bucket = struct.unpack_from("<IIII", data, 0)
+        off = 16
         sk = np.frombuffer(data, np.uint64, cap, off).copy()
         off += 8 * cap
         sr = np.frombuffer(data, np.int32, cap, off).copy()
         off += 4 * cap
         rl = np.frombuffer(data, np.int32, cap, off).copy()
         off += 4 * cap
+        bo = np.frombuffer(data, np.int32, n_offsets, off).copy()
+        off += 4 * n_offsets
         # memoryview slice: no second full-payload copy
         batch = (
             deserialize_batch(memoryview(data)[off:], build_schema)
             .with_capacity(cap)
             .to_device()
         )
-        return cls(jnp.asarray(sk), jnp.asarray(sr), jnp.asarray(rl), num_rows, batch)
+        return cls(jnp.asarray(sk), jnp.asarray(sr), jnp.asarray(rl), jnp.asarray(bo),
+                   jnp.int32(max_bucket), num_rows, batch)
 
 
 def make_build_kernel(build_schema: Schema, build_keys: Sequence[Expr]):
@@ -127,7 +138,8 @@ def _make_build_kernel_impl(build_schema: Schema, build_keys):
         keys = jnp.where(live, _key_hash(key_cols), _SENTINEL)
         rows = jnp.arange(cap, dtype=jnp.int32)
         sorted_keys, sorted_rows = jax.lax.sort((keys, rows), num_keys=1)
-        return sorted_keys, sorted_rows, run_lengths(sorted_keys)
+        return (sorted_keys, sorted_rows, run_lengths(sorted_keys),
+                *bucket_offsets(sorted_keys))
 
     return build_kernel
 
@@ -145,9 +157,37 @@ def run_lengths(sorted_keys) -> jnp.ndarray:
     return next_start - pos
 
 
+#: prefix bits beyond log2(cap).  At half a live key a bucket on average
+#: the largest bucket of 2^15–2^19 uniform keys holds 5–7: 3 steps
+#: whatever the keys.  One bit less holds 7–9 (3 or 4 steps, by the
+#: keys: a step is ~0.94 ms of a 65,536-row probe on a v5e), one bit
+#: more still 5 (3 steps) for twice the bytes.
+_EXTRA_PREFIX_BITS = 1
+
+
+def bucket_offsets(sorted_keys) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(offsets, max_bucket) of a sorted key table.  ``offsets`` is
+    int32 (2**b + 1,): for each value of a key's top ``b`` bits the
+    position where that prefix starts, and in the last slot the count
+    of live keys — the sentinel tail (dead rows, NULL keys) lies in no
+    bucket.  ``b`` follows the table's capacity alone.  ``max_bucket``
+    is the largest bucket's live keys, which bounds the probe's search.
+    From the sorted keys alone: every position scatters into its
+    prefix's slot by minimum (the tail into the last), and an empty
+    bucket takes the next start by a reverse cumulative minimum."""
+    cap = sorted_keys.shape[0]
+    b = (cap - 1).bit_length() + _EXTRA_PREFIX_BITS
+    prefix = (sorted_keys >> np.uint64(64 - b)).astype(jnp.int32)
+    slot = jnp.where(sorted_keys != _SENTINEL, prefix, 1 << b)
+    starts = jnp.full((1 << b) + 1, cap, jnp.int32).at[slot].min(
+        jnp.arange(cap, dtype=jnp.int32), indices_are_sorted=True)
+    offsets = jax.lax.cummin(starts, reverse=True)
+    return offsets, jnp.max(offsets[1:] - offsets[:-1])
+
+
 def build_join_map(batch: RecordBatch, build_kernel) -> JoinMap:
-    sk, sr, rl = build_kernel(tuple(batch.columns), batch.num_rows)
-    return JoinMap(sk, sr, rl, batch.num_rows, batch)
+    index = build_kernel(tuple(batch.columns), batch.num_rows)
+    return JoinMap(*index, batch.num_rows, batch)
 
 
 _SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -163,17 +203,39 @@ def _key_hash(cols: Sequence[Column]) -> jnp.ndarray:
     return jnp.where(all_valid, h, _SENTINEL)
 
 
-def probe_counts(jmap_keys, run_lens, probe_keys):
-    """(lo, counts) of candidate ranges per probe row: ONE left
-    searchsorted of the sorted key table; where the key found at ``lo``
-    is the probe's, the range is that key's run (``run_lens``, built
-    with the table), else it is empty.  ``lo == cap`` clips to the last
-    key, which a probe above every key cannot equal."""
+def probe_counts(jmap_keys, run_lens, offsets, max_bucket, probe_keys):
+    """(lo, counts, steps) of candidate ranges per probe row: ONE left
+    binary search of the sorted key table, begun inside the bucket of
+    the probe key's hash prefix.  Every key of a smaller prefix sorts
+    before ``offsets[p]`` and the bucket holds at most ``max_bucket``
+    keys, so the left search of ``[offsets[p], offsets[p] + max_bucket)``
+    (cut at the live keys' end) is the search of the whole table, closed
+    in ``steps`` = bit length of ``max_bucket`` steps: 3 over uniform
+    hashes, log2(cap) + 1 where the table is one run of one key.
+    Sentinel probes (NULL keys, a batch's dead rows) start closed at
+    the live keys' end.  Where the key found at ``lo`` is the probe's,
+    the range is that key's run (``run_lens``, built with the table),
+    else it is empty.  ``lo == cap`` clips to the last key, which a
+    probe above every key cannot equal."""
+    cap = jmap_keys.shape[0]
+    b = (offsets.shape[0] - 1).bit_length() - 1
     is_sent = probe_keys == _SENTINEL
-    lo = jnp.searchsorted(jmap_keys, probe_keys, side="left")
-    at = jnp.clip(lo, 0, jmap_keys.shape[0] - 1)
+    n_live = offsets[-1]
+    prefix = (probe_keys >> np.uint64(64 - b)).astype(jnp.int32)
+    lo = jnp.where(is_sent, n_live, offsets[prefix])
+    hi = jnp.minimum(lo + max_bucket, n_live)
+    steps = 32 - jax.lax.clz(max_bucket)
+
+    def step(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) >> 1
+        right = (lo < hi) & (jmap_keys[jnp.minimum(mid, cap - 1)] < probe_keys)
+        return jnp.where(right, mid + 1, lo), jnp.where(right, hi, jnp.minimum(mid, hi))
+
+    lo, _ = jax.lax.fori_loop(0, steps, step, (lo, hi))
+    at = jnp.minimum(lo, cap - 1)
     found = (jmap_keys[at] == probe_keys) & ~is_sent
-    return lo, jnp.where(found, run_lens[at], 0)
+    return lo, jnp.where(found, run_lens[at], 0), steps
 
 
 def expand_pairs(lo, counts, out_cap: int):
@@ -317,14 +379,15 @@ class Joiner:
         self._build_kernel = make_build_kernel(build_schema, build_keys)
 
         @jax.jit
-        def candidate_kernel(cols, jmap_keys, run_lens, num_rows):
+        def candidate_kernel(cols, jmap_keys, run_lens, offsets, max_bucket, num_rows):
             cap = cols[0].validity.shape[0]
             env = {f.name: c for f, c in zip(probe_schema.fields, cols)}
             key_cols = [lower(e, probe_schema, env, cap) for e in probe_keys]
             live = jnp.arange(cap) < num_rows
             pkeys = jnp.where(live, _key_hash(key_cols), _SENTINEL)
-            lo, counts = probe_counts(jmap_keys, run_lens, pkeys)
-            return jnp.sum(counts), lo, counts
+            lo, counts, steps = probe_counts(jmap_keys, run_lens, offsets, max_bucket, pkeys)
+            # the candidate total and the search's steps, one read
+            return jnp.stack([jnp.sum(counts), steps]), lo, counts
 
         # under the dispatch counters like every cached kernel: the
         # Joiner object is what cached_kernel holds, so its jitted
@@ -394,9 +457,12 @@ class Joiner:
         self, jmap: JoinMap, batch: RecordBatch, state: JoinerState
     ) -> Optional[RecordBatch]:
         jt = self.join_type
-        cand, lo, counts = self._candidate_kernel(
-            tuple(batch.columns), jmap.sorted_keys, jmap.run_lens, batch.num_rows)
-        cand = trace.read_scalar(cand)  # the round trip that picks out_cap
+        head, lo, counts = self._candidate_kernel(
+            tuple(batch.columns), jmap.sorted_keys, jmap.run_lens,
+            jmap.bucket_offsets, jmap.max_bucket, batch.num_rows)
+        with trace.span("device_read"):  # the round trip that picks out_cap
+            cand, steps = np.asarray(head).tolist()
+        dispatch.record("join_search_steps", steps)
         out_cap = bucket_capacity(max(1, cand))
         pair_cols, pair_count, vcounts, matched = self._probe_kernel(
             tuple(batch.columns), jmap, lo, counts, out_cap

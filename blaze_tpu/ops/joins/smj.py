@@ -263,7 +263,8 @@ class SortMergeJoinExec(ExecNode):
         state.matched_build = jnp.asarray(m)
         # finish() reads the batch and its row count only: no key table
         zeros = jnp.zeros(batch.capacity, jnp.int32)
-        fake = JoinMap(zeros.astype(jnp.uint64), zeros, zeros, batch.num_rows, batch)
+        fake = JoinMap(zeros.astype(jnp.uint64), zeros, zeros, zeros, jnp.int32(0),
+                       batch.num_rows, batch)
         return self._joiner.finish(fake, state)
 
     def _empty_build(self) -> RecordBatch:

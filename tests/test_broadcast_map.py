@@ -80,8 +80,10 @@ def test_serialize_deserialize_roundtrip():
     assert rt.num_rows == jmap.num_rows
     np.testing.assert_array_equal(np.asarray(rt.sorted_keys), np.asarray(jmap.sorted_keys))
     np.testing.assert_array_equal(np.asarray(rt.sorted_rows), np.asarray(jmap.sorted_rows))
-    np.testing.assert_array_equal(np.asarray(rt.run_lens), np.asarray(jmap.run_lens))
-    assert rt.run_lens.dtype == jmap.run_lens.dtype
+    for field in ("run_lens", "bucket_offsets", "max_bucket"):
+        got, built = getattr(rt, field), getattr(jmap, field)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(built))
+        assert (got.dtype, got.shape) == (built.dtype, built.shape), field
     assert batch_to_pydict(rt.batch) == batch_to_pydict(jmap.batch)
 
 
@@ -101,6 +103,47 @@ def test_serialized_map_carries_its_run_lengths():
     assert got[4] == cap - 4 and got[-1] == 1
     leaves = jax.tree_util.tree_leaves(rt)
     assert any(leaf is rt.run_lens for leaf in leaves)
+
+
+def test_serialized_map_carries_its_bucket_offsets():
+    """The wire form holds the bucket offsets and the largest bucket as
+    built (4 live keys, 2 of them one key; the NULL key and the dead
+    rows in no bucket), as pytree leaves: the reading side computes
+    nothing, and the candidate program takes them like the built map's."""
+    from blaze_tpu.ops.joins.core import bucket_offsets
+
+    kern = make_build_kernel(BUILD_SCHEMA, [col("k")])
+    jmap = build_join_map(batch_from_pydict(BUILD_DATA, BUILD_SCHEMA), kern)
+    rt = JoinMap.deserialize(jmap.serialize(), BUILD_SCHEMA)
+    offsets, max_bucket = bucket_offsets(rt.sorted_keys)
+    np.testing.assert_array_equal(np.asarray(rt.bucket_offsets), np.asarray(offsets))
+    got = np.asarray(rt.bucket_offsets)
+    assert (got[0], got[-1]) == (0, 4) and (np.diff(got) >= 0).all()
+    assert int(rt.max_bucket) == int(max_bucket) == np.diff(got).max() >= 2
+    leaves = jax.tree_util.tree_leaves(rt)
+    assert any(leaf is rt.bucket_offsets for leaf in leaves)
+    assert any(leaf is rt.max_bucket for leaf in leaves)
+
+
+@pytest.mark.parametrize("jt", [JoinType.INNER, JoinType.LEFT, JoinType.FULL])
+def test_probe_through_a_deserialized_map_equals_the_built_one(jt):
+    from blaze_tpu.ops.joins.core import Joiner, JoinerState
+    from blaze_tpu.runtime import dispatch
+
+    j = Joiner(PROBE_SCHEMA, BUILD_SCHEMA, [col("k")], [col("k")], jt, True)
+    jmap = j.build_map(batch_from_pydict(BUILD_DATA, BUILD_SCHEMA))
+    rt = JoinMap.deserialize(jmap.serialize(), BUILD_SCHEMA)
+    probe = batch_from_pydict(PROBE_DATA, PROBE_SCHEMA)
+
+    def rows(m):
+        state = JoinerState()
+        parts = [j.probe_batch(m, probe, state), j.finish(m, state)]
+        return [batch_to_pydict(b) for b in parts if b is not None]
+
+    built = rows(jmap)
+    with dispatch.capture() as c:
+        assert rows(rt) == built != []
+    assert c.get("xla_compiles", 0) == 0  # the copied map runs the built map's programs
 
 
 def test_per_executor_cache_hit():

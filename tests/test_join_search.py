@@ -1,6 +1,8 @@
-"""The Joiner's candidate search: one left ``searchsorted`` of the
-sorted key table per probe batch, in the candidate program alone; a
-range's length is the run length the build kernel kept in the JoinMap.
+"""The Joiner's candidate search: one left binary search of the sorted
+key table per probe batch, in the candidate program alone, begun inside
+the bucket of the probe key's hash prefix; the bucket offsets and a
+range's length (the key's run length) are what the build kernel kept in
+the JoinMap.
 """
 
 import jax
@@ -14,6 +16,7 @@ from blaze_tpu.ops.joins.core import (
     _SENTINEL,
     JoinType,
     Joiner,
+    bucket_offsets,
     build_join_map,
     expand_pairs,
     make_build_kernel,
@@ -72,6 +75,25 @@ TABLES = {
 }
 
 
+def _search(table: np.ndarray, probes: np.ndarray):
+    """``probe_counts`` through the table's own bucket offsets."""
+    keys = jnp.asarray(table)
+    offsets, max_bucket = bucket_offsets(keys)
+    return probe_counts(keys, run_lengths(keys), offsets, max_bucket, jnp.asarray(probes))
+
+
+def _assert_two_sided(table: np.ndarray, probes: np.ndarray):
+    """``lo``/``counts`` are numpy's left search and right - left."""
+    lo, counts, _ = _search(table, probes)
+    want_lo = np.searchsorted(table, probes, side="left")
+    want = np.searchsorted(table, probes, side="right") - want_lo
+    want[probes == SENT] = 0
+    np.testing.assert_array_equal(np.asarray(lo), want_lo)
+    np.testing.assert_array_equal(np.asarray(counts), want)
+    assert lo.dtype == jnp.int32 and counts.dtype == jnp.int32
+    return want
+
+
 @pytest.mark.parametrize("cap", [1024, 32768])
 @pytest.mark.parametrize("shape", sorted(TABLES))
 def test_probe_counts_equals_the_two_sided_search(cap, shape):
@@ -80,19 +102,86 @@ def test_probe_counts_equals_the_two_sided_search(cap, shape):
     live = spec.get("live", int(cap * spec.get("live_share", 0)))
     cap = max(cap, live)
     table = _table(rng, cap, live, int(live * spec["distinct_share"]))
-    probes = _probes(rng, table, 4096)
-
-    lo, counts = probe_counts(jnp.asarray(table), run_lengths(jnp.asarray(table)),
-                              jnp.asarray(probes))
-
-    want_lo = np.searchsorted(table, probes, side="left")
-    want = np.searchsorted(table, probes, side="right") - want_lo
-    want[probes == SENT] = 0
-    np.testing.assert_array_equal(np.asarray(lo), want_lo)
-    np.testing.assert_array_equal(np.asarray(counts), want)
-    assert lo.dtype == jnp.int32 and counts.dtype == jnp.int32
+    want = _assert_two_sided(table, _probes(rng, table, 4096))
     if live:
         assert want.max() >= 1  # the case does find something
+
+
+def _sorted_with_tail(live_keys, cap: int) -> np.ndarray:
+    table = np.full(cap, SENT, np.uint64)
+    table[: len(live_keys)] = np.sort(np.asarray(live_keys, np.uint64))
+    return table
+
+
+def _crowded_bucket(rng, cap):
+    """300 distinct keys (some twice) under ONE top-b-bit prefix, a few
+    uniform keys around them."""
+    low = rng.choice(2**20, size=300, replace=False).astype(np.uint64)
+    crowd = (np.uint64(0x5A5A) << np.uint64(48)) | low
+    others = rng.integers(1, 2**63, size=200, dtype=np.uint64)
+    table = _sorted_with_tail(np.concatenate([crowd, crowd[:40], others]), cap)
+    probes = np.concatenate([crowd, crowd + np.uint64(1), others,
+                             [crowd.min() - np.uint64(1), crowd.max() + np.uint64(1)]])
+    return table, probes
+
+
+def _all_ones_prefix(rng, cap):
+    """Live keys whose prefix is all ones, right under the sentinel
+    tail: the last bucket ends at the live keys, not at the capacity."""
+    top = SENT - np.arange(1, 40, dtype=np.uint64) * np.uint64(3)
+    others = rng.integers(1, 2**63, size=cap // 2, dtype=np.uint64)
+    table = _sorted_with_tail(np.concatenate([top, top[:5], others]), cap)
+    probes = np.concatenate([top, top + np.uint64(1), top - np.uint64(1),
+                             [SENT, SENT - np.uint64(1)], others[:100]])
+    return table, probes
+
+
+def _empty_buckets_around(rng, cap):
+    """Three far-apart clusters: every probe's prefix has empty buckets
+    on both sides, and most probes fall into an empty bucket."""
+    bases = np.array([1 << 40, 1 << 62, (1 << 63) + (1 << 61)], np.uint64)
+    live = (bases[:, None] + np.arange(0, 50, dtype=np.uint64)[None, :] * np.uint64(7)).ravel()
+    table = _sorted_with_tail(live, cap)
+    absent = rng.integers(1, 2**63, size=500, dtype=np.uint64) * np.uint64(2)
+    probes = np.concatenate([live, live + np.uint64(1), absent, [np.uint64(0)]])
+    return table, probes
+
+
+def _dead_rows_are_sentinels(rng, cap):
+    """A probe batch as the candidate program sees it: 300 live rows,
+    the dead rows behind them all sentinel probes."""
+    table = _table(rng, cap, int(cap * 0.8), cap)
+    probes = np.full(4096, SENT, np.uint64)
+    probes[:300] = rng.choice(table[table != SENT], size=300)
+    return table, probes
+
+
+BUCKET_CASES = {
+    "crowded_bucket": _crowded_bucket,
+    "all_ones_prefix_beside_the_tail": _all_ones_prefix,
+    "empty_buckets_around": _empty_buckets_around,
+    "dead_rows_are_sentinel_probes": _dead_rows_are_sentinels,
+}
+
+
+@pytest.mark.parametrize("cap", [1024, 32768])
+@pytest.mark.parametrize("case", sorted(BUCKET_CASES))
+def test_bucketed_search_equals_the_two_sided_search(cap, case):
+    table, probes = BUCKET_CASES[case](np.random.default_rng(cap + len(case)), cap)
+    assert (table[1:] >= table[:-1]).all() and table[-1] == SENT
+    want = _assert_two_sided(table, probes.astype(np.uint64))
+    assert want.max() >= 1
+
+
+def test_search_at_min_capacity():
+    """The smallest table the engine builds: ``cap`` at MIN_CAPACITY."""
+    from blaze_tpu import conf
+
+    cap = int(conf.MIN_CAPACITY.get())
+    rng = np.random.default_rng(cap)
+    for live, distinct in ((cap, cap), (cap // 2, 5), (3, 3)):
+        table = _table(rng, cap, live, distinct)
+        _assert_two_sided(table, _probes(rng, table, cap))
 
 
 @pytest.mark.parametrize("cap", [1024, 32768])
@@ -104,11 +193,68 @@ def test_run_lengths_against_a_loop(cap):
             np.asarray(run_lengths(jnp.asarray(table))), _np_run_lens(table))
 
 
+def _np_offsets(sorted_keys: np.ndarray, b: int):
+    """Loop reference: where each b-bit prefix starts among the live
+    keys, then the live keys' count; the largest bucket."""
+    live = [int(k) for k in sorted_keys if k != SENT]
+    offsets, at = [], 0
+    for p in range(1 << b):
+        while at < len(live) and (live[at] >> (64 - b)) < p:
+            at += 1
+        offsets.append(at)
+    offsets.append(len(live))
+    return np.array(offsets, np.int32), max(np.diff(offsets))
+
+
+@pytest.mark.parametrize("cap", [1024, 8192])
+def test_bucket_offsets_against_a_loop(cap):
+    rng = np.random.default_rng(cap)
+    for live, distinct in ((cap, cap), (cap // 3, 7), (0, 0), (1, 1)):
+        table = _table(rng, cap, live, distinct)
+        offsets, max_bucket = bucket_offsets(jnp.asarray(table))
+        b = (offsets.shape[0] - 1).bit_length() - 1
+        assert offsets.shape[0] == (1 << b) + 1 and (1 << b) >= cap
+        want, want_max = _np_offsets(table, b)
+        np.testing.assert_array_equal(np.asarray(offsets), want)
+        assert int(max_bucket) == want_max
+        assert offsets.dtype == jnp.int32 and max_bucket.dtype == jnp.int32
+    crowd, _ = _crowded_bucket(rng, cap)
+    offsets, max_bucket = bucket_offsets(jnp.asarray(crowd))
+    want, want_max = _np_offsets(crowd, (offsets.shape[0] - 1).bit_length() - 1)
+    np.testing.assert_array_equal(np.asarray(offsets), want)
+    assert int(max_bucket) == want_max >= 340  # the crowd, its 40 repeats
+
+
+STEP_CAP = 32768
+OLD_STEPS = 16  # the whole-table search this one replaced: log2(cap) + 1
+
+
+@pytest.mark.parametrize("shape,live,distinct,want", [
+    # uniform hashes: the largest bucket's bit length, a handful
+    ("uniform", STEP_CAP, 4 * STEP_CAP, None),
+    # half the table one run of one key: one step under the old search
+    ("one_long_run", STEP_CAP // 2, 1, OLD_STEPS - 1),
+    # the whole table one run: the old search's steps, never more
+    ("one_run_fills_the_table", STEP_CAP, 1, OLD_STEPS),
+    ("all_sentinel", 0, 0, 0),
+])
+def test_search_steps_follow_the_largest_bucket(shape, live, distinct, want):
+    rng = np.random.default_rng(len(shape))
+    table = _table(rng, STEP_CAP, live, distinct)
+    _, _, steps = _search(table, _probes(rng, table, 4096))
+    largest = int(bucket_offsets(jnp.asarray(table))[1])
+    assert int(steps) == largest.bit_length()
+    if want is None:
+        assert int(steps) <= min(int(np.ceil(np.log2(largest + 1))) + 1, 6)
+    else:
+        assert int(steps) == want
+
+
 BUILD = Schema([Field("k", DataType.int64()), Field("b", DataType.int32())])
 PROBE = Schema([Field("k", DataType.int64()), Field("p", DataType.int32())])
 
 
-def test_build_kernel_returns_the_tables_run_lengths():
+def test_build_kernel_returns_the_tables_run_lengths_and_bucket_offsets():
     rng = np.random.default_rng(31)
     n = 700  # capacity 1,024: a sentinel tail of dead rows behind the NULL keys
     keys = [None if rng.random() < 0.1 else int(k) for k in rng.integers(0, 40, n)]
@@ -121,6 +267,11 @@ def test_build_kernel_returns_the_tables_run_lengths():
     # NULL keys and dead rows share the sentinel run at the end
     dead = sk.shape[0] - n + sum(k is None for k in keys)
     assert np.asarray(jmap.run_lens)[sk.shape[0] - dead] == dead
+    # the offsets are the sorted table's, and the tail lies in no bucket
+    offsets, max_bucket = bucket_offsets(jmap.sorted_keys)
+    np.testing.assert_array_equal(np.asarray(jmap.bucket_offsets), np.asarray(offsets))
+    assert int(jmap.max_bucket) == int(max_bucket) >= 1
+    assert int(jmap.bucket_offsets[-1]) == sk.shape[0] - dead
 
 
 def _loops(jaxpr) -> int:
@@ -143,11 +294,13 @@ def test_the_key_table_is_searched_once_a_probe_batch():
     cols = tuple(probe.columns)
 
     candidate = j._candidate_kernel.__wrapped__
-    cand = jax.make_jaxpr(candidate)(cols, jmap.sorted_keys, jmap.run_lens, probe.num_rows)
+    index = (jmap.sorted_keys, jmap.run_lens, jmap.bucket_offsets, jmap.max_bucket)
+    cand = jax.make_jaxpr(candidate)(cols, *index, probe.num_rows)
     assert _loops(cand.jaxpr) == 1
 
-    total, lo, counts = candidate(cols, jmap.sorted_keys, jmap.run_lens, probe.num_rows)
+    (total, steps), lo, counts = candidate(cols, *index, probe.num_rows)
     assert int(total) == 3
+    assert int(steps) == 2  # the largest bucket holds key 4 twice
     expand = jax.make_jaxpr(lambda a, b: expand_pairs(a, b, 1024))(lo, counts)
     assert _loops(expand.jaxpr) == 1
     probe_jaxpr = jax.make_jaxpr(

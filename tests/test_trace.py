@@ -682,6 +682,8 @@ def test_span_counters_with_tracing_off(data, q, monkeypatch):
         assert (c["broadcast_build_n"], c["join_map_builds"]) == (1, 1)
         assert c["join_map_cache_hits"] == n_parts - 1
         assert c["join_probe_n"] > 0 and c["join_probe_rows_in"] >= c["join_rows_out"] > 0
+        # uniform hashes: a handful of steps a probe, not log2(cap) + 1
+        assert 0 < c["join_search_steps"] <= 6 * c["join_probe_n"]
     for k in spans:
         assert c[k] > 0, k
     # tracing stayed off: the span is not an event and not a query span
@@ -774,6 +776,9 @@ def test_joiner_kernels_are_under_the_dispatch_counters():
     assert c["xla_dispatches"] >= 3 and c["launch_n"] == c["xla_dispatches"]
     assert c.get("xla_compiles", 0) == 0
     assert c["device_read_n"] == 3  # candidate total, pair count, unmatched count
+    # the search's steps ride the candidate total's read: build key 4
+    # twice is the largest bucket, two steps
+    assert (c["join_probe_n"], c["join_search_steps"]) == (1, 2)
 
 
 def test_q03_joiner_launches_counted_and_warm_run_compiles_nothing(data):
