@@ -66,7 +66,8 @@ class OrcScanExec(ExecNode):
         self.file_groups = [list(g) for g in file_groups]
         self._schema = schema
         self.predicate = predicate
-        self.batch_rows = batch_rows or int(conf.BATCH_SIZE.get())
+        self.stated_batch_rows = int(batch_rows)  # as ParquetScanExec's
+        self.batch_rows = self.stated_batch_rows or int(conf.BATCH_SIZE.get())
         self._conjuncts = _prune_conjuncts(predicate)
 
     @property

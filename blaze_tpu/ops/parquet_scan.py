@@ -17,10 +17,11 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from .. import conf
-from ..batch import Column, RecordBatch, bucket_capacity
+from ..batch import Column, RecordBatch, _pad_1d, bucket_capacity
 from ..exprs.compile import infer_lit_dtype
 from ..exprs.ir import BinOp, Col, Expr, Lit
 from ..io import parquet as pq
+from ..runtime import dispatch, trace
 from ..runtime.context import TaskContext
 from ..runtime.errors import reraise_control
 from ..schema import DataType, Schema, TypeKind
@@ -117,7 +118,10 @@ class ParquetScanExec(ExecNode):
         self.file_groups = [list(g) for g in file_groups]
         self._schema = schema
         self.predicate = predicate
-        self.batch_rows = batch_rows or int(conf.BATCH_SIZE.get())
+        # what the plan states travels with it (serde); 0 = it states
+        # none, and the executor's spark.blaze.batchSize decides
+        self.stated_batch_rows = int(batch_rows)
+        self.batch_rows = self.stated_batch_rows or int(conf.BATCH_SIZE.get())
         self._conjuncts = _prune_conjuncts(predicate) if bool(
             conf.PARQUET_FILTER_PUSHDOWN.get()
         ) else []
@@ -170,8 +174,13 @@ class ParquetScanExec(ExecNode):
                     if pruned:
                         self.metrics.add("pruned_row_groups", 1)
                         self.metrics.add("pruned_rows", rg.rows)
+                        dispatch.record("scan_row_groups_pruned")
                         continue
-                    with self.metrics.timer("input_io_time"):
+                    # one row group's fetch + decompress + decode and the
+                    # padding to its capacity; once a row group, in the
+                    # producer thread where the scan is pipelined
+                    file_bytes = 0
+                    with self.metrics.timer("input_io_time", trace.span("scan_decode")):
                         cap = bucket_capacity(rg.rows)
                         cols: List[Column] = []
                         for f in self._schema.fields:
@@ -181,8 +190,7 @@ class ParquetScanExec(ExecNode):
                                 cols.append(self._null_column(f.dtype, cap))
                                 continue
                             data, validity, lengths = pq.read_column_chunk(path, ch, f.dtype)
-                            from ..batch import _pad_1d
-
+                            file_bytes += ch.total_comp
                             if f.dtype.is_string:
                                 d = np.zeros((cap, f.dtype.string_width), np.uint8)
                                 d[: rg.rows, : data.shape[1]] = data[:, : f.dtype.string_width]
@@ -197,6 +205,8 @@ class ParquetScanExec(ExecNode):
                                         _pad_1d(validity, cap),
                                     )
                                 )
+                    dispatch.record("scan_file_bytes", file_bytes)
+                    dispatch.record("scan_row_groups")
                     # emit in batch_rows slices to bound device batches
                     full = RecordBatch(self._schema, cols, rg.rows)
                     if rg.rows <= self.batch_rows:
@@ -229,5 +239,3 @@ class ParquetScanExec(ExecNode):
         # file decode overlaps downstream device compute (≙ rt.rs:100-133)
         return maybe_pipelined(self._staged(stream()), ctx, "parquet_scan")
 
-
-from ..batch import _pad_1d  # noqa: E402  (used in stream closures)

@@ -107,7 +107,7 @@ def _narrow(child, needed: Set[str]):
     if isinstance(child, (ParquetScanExec, OrcScanExec)):
         narrowed = Schema([child.schema.field(n) for n in names])
         return type(child)(
-            child.file_groups, narrowed, child.predicate, child.batch_rows
+            child.file_groups, narrowed, child.predicate, child.stated_batch_rows
         )
     return ProjectExec(child, [Col(n) for n in names], names)
 

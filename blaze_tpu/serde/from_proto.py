@@ -187,10 +187,10 @@ def plan_from_proto(n: pb.PhysicalPlanNode):
         if kind == "parquet_scan":
             from ..ops import ParquetScanExec
 
-            return ParquetScanExec(groups, schema_from_proto(s.schema), pred)
+            return ParquetScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows)
         from ..ops.orc_scan import OrcScanExec
 
-        return OrcScanExec(groups, schema_from_proto(s.schema), pred)
+        return OrcScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows)
     if kind == "project":
         p = n.project
         return ProjectExec(plan_from_proto(p.input), [expr_from_proto(e) for e in p.exprs], list(p.names))

@@ -244,6 +244,7 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
             sub.file_groups.append(";".join(g))
         if node.predicate is not None:
             sub.predicate.add().CopyFrom(expr_to_proto(node.predicate))
+        sub.batch_rows = node.stated_batch_rows
     elif isinstance(node, ProjectExec):
         out.project.input.CopyFrom(plan_to_proto(node.children[0]))
         for e in node.exprs:

@@ -176,3 +176,32 @@ def test_decimal_pruning_flba_stats(tmp_path):
     # last row group (unscaled 250..499) survives whole; first three pruned
     assert got["dec"] == list(range(250, 500))
     assert scan.metrics.get("pruned_row_groups") == 3
+
+
+@pytest.mark.parametrize("batch_rows", [64, 200, 1000])
+def test_int64_decimal_row_group_longer_than_a_batch(tmp_path, batch_rows):
+    """Spark's default for a decimal of 18 digits or fewer
+    (writeLegacyFormat=false): INT64 annotated DECIMAL, not FLBA.  A row
+    group longer than ``batch_rows`` comes out in slices of that length,
+    equal to the file's rows and in their order."""
+    unscaled = np.random.RandomState(5).randint(-10**11, 10**11, 700)
+    table = pa.table({
+        "dec": pa.array([decimal.Decimal(int(x)).scaleb(-2) for x in unscaled], pa.decimal128(12, 2)),
+        "d": pa.array(np.arange(700, dtype=np.int32) + 8000, pa.int32()).cast(pa.date32()),
+    })
+    path = tmp_path / "int64_decimal.parquet"
+    papq.write_table(table, path, compression="snappy", use_dictionary=True, data_page_version="1.0",
+                     row_group_size=500, store_decimal_as_integer=True)
+    assert papq.ParquetFile(path).schema.column(0).physical_type == "INT64"
+    schema = Schema([Field("dec", DataType.decimal(12, 2)), Field("d", DataType.date32())])
+    scan = ParquetScanExec([[str(path)]], schema, batch_rows=batch_rows)
+    batches = list(scan.execute(0, TaskContext(0, 1)))
+    # two row groups of 500 and 200 rows, each cut on its own
+    want = [min(batch_rows, g - s) for g in (500, 200) for s in range(0, g, batch_rows)]
+    assert [b.num_rows for b in batches] == want
+    got = {"dec": [], "d": []}
+    for b in batches:
+        for k, v in batch_to_pydict(b).items():
+            got[k].extend(v)
+    assert got["dec"] == [int(x) for x in unscaled]
+    assert got["d"] == list(range(8000, 8700))

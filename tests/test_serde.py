@@ -2,6 +2,7 @@
 tests + the TaskDefinition entry path."""
 
 import numpy as np
+import pytest
 
 from blaze_tpu.batch import batch_from_pydict, batch_to_pydict
 from blaze_tpu.exprs import col, lit
@@ -129,3 +130,56 @@ def test_pickled_generator_gate():
         assert plan_from_proto(proto) is not None
     finally:
         conf.ALLOW_PICKLED_UDFS.set(old)
+
+
+def _scan_file(tmp_path, kind, rows):
+    """One file of ``rows`` int64 keys in the scan's own format."""
+    schema = Schema([Field("k", DataType.int64())])
+    columns = {"k": (np.arange(rows, dtype=np.int64), None, None)}
+    path = str(tmp_path / f"t.{kind}")
+    if kind == "parquet":
+        from blaze_tpu.io.parquet import write_parquet
+        from blaze_tpu.ops import ParquetScanExec as scan_type
+
+        write_parquet(path, schema, columns)
+    else:
+        from blaze_tpu.io.orc import write_orc
+        from blaze_tpu.ops import OrcScanExec as scan_type
+
+        write_orc(path, schema, columns)
+    return scan_type, path, schema
+
+
+@pytest.mark.parametrize("kind", ["parquet", "orc"])
+def test_file_scan_batch_rows_travels_with_the_plan(tmp_path, monkeypatch, kind):
+    """A file scan decoded from TaskDefinition bytes scans in the batch
+    length its plan states; one that states none reads the executor's
+    spark.blaze.batchSize, as bytes written before the field decode."""
+    from blaze_tpu.ops.pruning import prune_columns
+
+    scan_type, path, schema = _scan_file(tmp_path, kind, rows=1000)
+    monkeypatch.setenv("BLAZE_BATCHSIZE", "128")  # the executor's conf, not the plan's
+    stated = plan_from_proto(_parse(plan_to_proto(scan_type([[path]], schema, batch_rows=300)).SerializeToString()))
+    assert type(stated) is scan_type
+    assert (stated.batch_rows, stated.stated_batch_rows) == (300, 300)
+
+    unstated = plan_to_proto(scan_type([[path]], schema))
+    sub = unstated.parquet_scan if kind == "parquet" else unstated.orc_scan
+    before = type(sub)(schema=sub.schema, file_groups=sub.file_groups)  # what the parent wrote
+    assert sub.batch_rows == 0 and sub.SerializeToString() == before.SerializeToString()
+    decoded = plan_from_proto(_parse(unstated.SerializeToString()))
+    assert (decoded.batch_rows, decoded.stated_batch_rows) == (128, 0)
+    assert [b.num_rows for b in decoded.execute(0, TaskContext(0, 1))] == [128] * 7 + [104]
+
+    # column pruning rebuilds a scan: what the plan stated survives, and nothing it did not
+    wide = Schema([Field("k", DataType.int64()), Field("absent", DataType.int64())])
+    for told, want in ((300, 300), (0, 0)):
+        project = ProjectExec(scan_type([[path]], wide, batch_rows=told), [col("k")])
+        narrowed = prune_columns(project).children[0]
+        assert type(narrowed) is scan_type and narrowed.schema.names == ["k"]
+        assert narrowed.stated_batch_rows == want
+
+    td = task_definition(scan_type([[path]], schema, batch_rows=300), task_id="t-0", stage_id=0, partition=0)
+    batches = list(run_task(td))
+    assert [b.num_rows for b in batches] == [300, 300, 300, 100]
+    assert [v for b in batches for v in batch_to_pydict(b)["k"]] == list(range(1000))
