@@ -2,7 +2,8 @@
 
 ≙ the file-format half of the reference's OrcExec (orc_exec.rs:53-285,
 which scans ORC through a forked orc-rust) — implemented from the
-public ORC v1 spec (no pyorc/pyarrow in the image):
+public ORC v1 spec (no pyorc; snappy chunks go through
+parquet.snappy_decompress, the one place that chooses a codec library):
 
 - file layout: "ORC" header, stripes (data streams + protobuf
   StripeFooter), protobuf Metadata (stripe-level column statistics),
@@ -92,9 +93,9 @@ def orc_decompress(buf: bytes, kind: int) -> bytes:
         elif kind == C_ZLIB:
             out += zlib.decompress(chunk, -15)  # raw deflate
         elif kind == C_SNAPPY:
-            from .parquet import _snappy_decompress
+            from .parquet import snappy_decompress
 
-            out += _snappy_decompress(chunk)
+            out += snappy_decompress(chunk)
         elif kind == C_LZ4:
             from .parquet import _lz4_block_decompress
 

@@ -2,15 +2,19 @@
 
 ≙ the file-format half of the reference's ParquetExec/ParquetSinkExec
 (parquet_exec.rs:65-418, parquet_sink_exec.rs) — implemented from the
-public parquet-format spec (no pyarrow in the image):
+public parquet-format spec.  The file format is read and written here;
+of pyarrow (in the image, optional) only the snappy codec is borrowed
+(snappy_decompress), as ZSTD pages go to ``zstandard``:
 
 - written files: PAR1 magic, one DATA_PAGE v1 per column chunk per row
   group, PLAIN encoding, RLE/bit-packed definition levels for OPTIONAL
   columns, UNCOMPRESSED / GZIP / SNAPPY (Spark's default, pure-python
   LZ77) / ZSTD / LZ4_RAW pages, thrift-compact FileMetaData with
   min/max statistics per chunk.
-- reader: decodes that subset (plus dictionary-free files other writers
-  produce with the same encodings) and prunes row groups with the
+- reader: decodes that subset and what parquet-mr / pyarrow write with
+  the same encodings (dictionary pages, RLE/bit-packed indices, v1 and
+  v2 data pages) by array operations over a whole page — no numpy call
+  a run, no Python step a bit or byte — and prunes row groups with the
   pushed-down predicate over chunk statistics — the row-group
   granularity of the reference's page filtering
   (spark.blaze.parquet.enable.pageFiltering).
@@ -22,6 +26,8 @@ FLOAT/DOUBLE; BYTE_ARRAY(UTF8) <- string.
 
 from __future__ import annotations
 
+import collections
+import functools
 import gzip
 import os
 import struct
@@ -50,19 +56,47 @@ PAGE_DATA, PAGE_INDEX, PAGE_DICT, PAGE_DATA_V2 = 0, 1, 2, 3
 ENC_PLAIN, ENC_PLAIN_DICT, ENC_RLE, ENC_RLE_DICT = 0, 2, 3, 8
 
 
-def _snappy_decompress(src: bytes) -> bytes:
-    """Pure-python snappy raw-block decode (no external lib in image)."""
-    # uvarint: uncompressed length
-    pos = 0
-    total = 0
+@functools.lru_cache(maxsize=None)
+def _snappy_library():
+    """pyarrow's snappy codec where pyarrow imports, else None: the one
+    place that chooses (Parquet pages and ORC chunks both come here)."""
+    try:
+        import pyarrow
+    except ImportError:
+        return None
+    return pyarrow.Codec("snappy") if pyarrow.Codec.is_available("snappy") else None
+
+
+def snappy_decompress(src: bytes) -> bytes:
+    """Snappy raw-block decode: the codec library's where one imports,
+    the pure-python _snappy_decompress where none does."""
+    codec = _snappy_library()
+    if codec is None:
+        return _snappy_decompress(src)
+    total, _ = _uvarint(src, 0)  # the preamble: the length the library asks for
+    return codec.decompress(src, total, asbytes=True)
+
+
+def _uvarint(buf: bytes, pos: int) -> Tuple[int, int]:
+    """(value, position after it) of the LEB128 varint at ``pos``."""
+    value = 0
     shift = 0
     while True:
-        b = src[pos]
+        b = buf[pos]
         pos += 1
-        total |= (b & 0x7F) << shift
-        if not (b & 0x80):
-            break
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, pos
         shift += 7
+
+
+def _snappy_decompress(src: bytes) -> bytes:
+    """Pure-python snappy raw-block decode, snappy_decompress's fallback:
+    one Python step a tag, never one a byte.  (Appending to the output
+    is the fastest form CPython has for the short copies real pages are
+    made of: a presized output written through memoryview slices
+    measured a third slower.)"""
+    total, pos = _uvarint(src, 0)  # the uncompressed length
     out = bytearray()
     n = len(src)
     while pos < n:
@@ -84,18 +118,19 @@ def _snappy_decompress(src: bytes) -> bytes:
             pos += 1
         elif t == 2:
             ln = (tag >> 2) + 1
-            off = int.from_bytes(src[pos : pos + 2], "little")
+            off = src[pos] | (src[pos + 1] << 8)
             pos += 2
         else:
             ln = (tag >> 2) + 1
             off = int.from_bytes(src[pos : pos + 4], "little")
             pos += 4
         start = len(out) - off
+        if start < 0 or off == 0:
+            raise ValueError(f"snappy: copy offset {off} at output byte {len(out)}")
         if off >= ln:
             out += out[start : start + ln]
-        else:  # overlapping copy
-            for i in range(ln):
-                out.append(out[start + i])
+        else:  # overlapping copy: the last `off` bytes, repeated
+            out += (out[start:] * (ln // off + 1))[:ln]
     if len(out) != total:
         raise ValueError(f"snappy: decoded {len(out)} bytes, expected {total}")
     return bytes(out)
@@ -194,7 +229,7 @@ def _decompress(payload: bytes, codec: int, uncompressed_size: int) -> bytes:
     if codec == CODEC_GZIP:
         return gzip.decompress(payload)
     if codec == CODEC_SNAPPY:
-        return _snappy_decompress(payload)
+        return snappy_decompress(payload)
     if codec == CODEC_ZSTD:
         import zstandard
 
@@ -223,45 +258,78 @@ def _decompress(payload: bytes, codec: int, uncompressed_size: int) -> bytes:
 
 
 def _rle_bp_decode(data: bytes, bit_width: int, num_values: int) -> np.ndarray:
-    """General RLE / bit-packed hybrid decode -> int32 values."""
-    out = np.zeros(num_values, np.int32)
+    """General RLE / bit-packed hybrid decode -> int32 values.
+
+    One pass over the run headers records where each run lies; the
+    bit-packed payloads of the whole buffer are then unpacked together
+    and the RLE runs filled by one ``np.repeat``: no numpy call a run."""
     if bit_width == 0:
-        return out
-    pos = 0
-    filled = 0
+        return np.zeros(num_values, np.int32)
     mask = (1 << bit_width) - 1
     byte_w = (bit_width + 7) // 8
+    counts: List[int] = []  # values a run holds, in run order
+    values: List[int] = []  # an RLE run's value; -1 for a bit-packed run
+    payloads = []           # the bit-packed runs' bytes, in run order
+    pos = 0
+    filled = 0
     n = len(data)
     while filled < num_values and pos < n:
-        hdr = 0
-        shift = 0
-        while True:
-            b = data[pos]
-            pos += 1
-            hdr |= (b & 0x7F) << shift
-            if not (b & 0x80):
-                break
-            shift += 7
+        hdr = data[pos]
+        pos += 1
+        if hdr & 0x80:  # a varint of more than one byte
+            hdr &= 0x7F
+            shift = 7
+            while True:
+                b = data[pos]
+                pos += 1
+                hdr |= (b & 0x7F) << shift
+                if b < 0x80:
+                    break
+                shift += 7
+        count = hdr >> 1
         if hdr & 1:  # bit-packed groups of 8
-            groups = hdr >> 1
-            nbytes = groups * bit_width
-            chunk = data[pos : pos + nbytes]
+            nbytes = count * bit_width
+            payloads.append(data[pos : pos + nbytes])
             pos += nbytes
-            bits = np.unpackbits(
-                np.frombuffer(chunk, np.uint8), bitorder="little"
-            ).reshape(-1, bit_width)
-            vals = (bits.astype(np.int64) << np.arange(bit_width)).sum(axis=1)
-            take = min(len(vals), num_values - filled)
-            out[filled : filled + take] = vals[:take]
-            filled += take
+            count *= 8
+            values.append(-1)
         else:
-            run = hdr >> 1
-            v = int.from_bytes(data[pos : pos + byte_w], "little") & mask
+            values.append(int.from_bytes(data[pos : pos + byte_w], "little") & mask)
             pos += byte_w
-            take = min(run, num_values - filled)
-            out[filled : filled + take] = v
-            filled += take
-    return out
+            count = min(count, num_values - filled)  # a corrupt header sizes nothing
+        counts.append(count)
+        filled += count
+    if len(payloads) == len(counts):  # no RLE run: the payloads are the values
+        out = _unpack_groups(payloads, bit_width)
+    else:
+        runs = np.array(values, np.int64)
+        out = np.repeat(runs.astype(np.int32), counts)
+        if payloads:
+            out[np.repeat(runs < 0, counts)] = _unpack_groups(payloads, bit_width)
+    if filled < num_values:  # the buffer ended early: zeros, as for rows never written
+        out = np.concatenate([out, np.zeros(num_values - filled, np.int32)])
+    return out[:num_values]
+
+
+def _unpack_groups(payloads: list, bit_width: int) -> np.ndarray:
+    """Bit-packed runs' bytes (groups of 8 values, ``bit_width`` bytes a
+    group, LSB first) -> int32, eight values a group, the runs in order.
+    Value ``j`` of every group starts ``j * bit_width`` bits into it: one
+    little-endian word read at that byte of each group, shifted and
+    masked — eight strided array expressions however many groups."""
+    word = np.dtype("<u8" if bit_width > 24 else "<u4")
+    # the zero tail: a last group cut short reads zeros, and the last
+    # group's last word stays inside the buffer
+    tail = bit_width + word.itemsize
+    buf = b"".join([*payloads, bytes(tail)])
+    groups = -(-(len(buf) - tail) // bit_width)
+    out = np.empty((groups, 8), np.int32)
+    mask = word.type((1 << bit_width) - 1)
+    for j in range(8):
+        byte, shift = divmod(j * bit_width, 8)
+        words = np.ndarray((groups,), word, buf, offset=byte, strides=(bit_width,))
+        out[:, j] = (words >> word.type(shift)) & mask
+    return out.reshape(-1)
 
 
 def _physical(dtype: DataType) -> int:
@@ -307,39 +375,9 @@ def _rle_encode_defs(validity: np.ndarray) -> bytes:
     return bytes(out)
 
 
-def _rle_decode_defs(data: bytes, num_values: int) -> Tuple[np.ndarray, int]:
-    """Decode 1-bit RLE/bit-packed hybrid definition levels."""
-    out = np.zeros(num_values, np.bool_)
-    pos = 0
-    filled = 0
-    while filled < num_values:
-        hdr = 0
-        shift = 0
-        while True:
-            b = data[pos]
-            pos += 1
-            hdr |= (b & 0x7F) << shift
-            if not (b & 0x80):
-                break
-            shift += 7
-        if hdr & 1:
-            # bit-packed: groups of 8 values, 1 bit each
-            groups = hdr >> 1
-            nvals = groups * 8
-            for g in range(groups):
-                byte = data[pos]
-                pos += 1
-                for bit in range(8):
-                    if filled < num_values:
-                        out[filled] = (byte >> bit) & 1
-                        filled += 1
-        else:
-            run = hdr >> 1
-            v = data[pos]
-            pos += 1
-            out[filled : filled + run] = bool(v)
-            filled += run
-    return out, pos
+def _rle_decode_defs(data: bytes, num_values: int) -> np.ndarray:
+    """1-bit definition levels -> validity: the hybrid decode at width 1."""
+    return _rle_bp_decode(data, 1, num_values).astype(np.bool_)
 
 
 def _plain_encode(dtype: DataType, data: np.ndarray, validity: np.ndarray,
@@ -378,42 +416,30 @@ def _flba_to_int64(raw: bytes, count: int, type_length: int) -> np.ndarray:
     return out
 
 
-def _plain_decode_phys(phys: int, raw: bytes, validity: np.ndarray, width: int,
-                       type_length: int = 0):
-    """PLAIN decode by the FILE's physical type; caller converts to the
-    requested logical dtype (schema adaption)."""
-    n = len(validity)
-    nn = int(validity.sum())
+def _plain_decode(phys: int, raw: bytes, count: int, width: int, type_length: int = 0):
+    """PLAIN decode of ``count`` values by the FILE's physical type — a
+    dictionary page's entries or a data page's non-null values, densely;
+    the caller adapts to the requested logical dtype (schema adaption).
+    Byte arrays come back as (data (count, width), lengths)."""
     if phys == T_BOOLEAN:
-        bits = np.unpackbits(np.frombuffer(raw, np.uint8), bitorder="little")[:nn].astype(np.bool_)
-        out = np.zeros(n, np.bool_)
-        out[validity] = bits
-        return out, None
+        return np.unpackbits(np.frombuffer(raw, np.uint8), bitorder="little")[:count].astype(np.bool_)
     np_map = {T_INT32: "<i4", T_INT64: "<i8", T_FLOAT: "<f4", T_DOUBLE: "<f8"}
     if phys in np_map:
-        vals = np.frombuffer(raw, np_map[phys], count=nn)
-        out = np.zeros(n, vals.dtype)
-        out[validity] = vals
-        return out, None
+        return np.frombuffer(raw, np_map[phys], count=count)
     if phys == T_FLBA:
-        vals = _flba_to_int64(raw, nn, type_length)
-        out = np.zeros(n, np.int64)
-        out[validity] = vals
-        return out, None
+        return _flba_to_int64(raw, count, type_length)
     if phys == T_INT96:
         # legacy Spark timestamps: 8B nanos-of-day LE + 4B julian day
-        out = np.zeros(n, np.int64)
-        idx = np.nonzero(validity)[0]
-        for j, i in enumerate(idx):
-            nanos = int.from_bytes(raw[j * 12 : j * 12 + 8], "little")
-            julian = int.from_bytes(raw[j * 12 + 8 : j * 12 + 12], "little")
+        out = np.zeros(count, np.int64)
+        for i in range(count):
+            nanos = int.from_bytes(raw[i * 12 : i * 12 + 8], "little")
+            julian = int.from_bytes(raw[i * 12 + 8 : i * 12 + 12], "little")
             out[i] = (julian - 2440588) * 86_400_000_000 + nanos // 1000
-        return out, None
-    # byte array
-    data = np.zeros((n, width), np.uint8)
-    lengths = np.zeros(n, np.int32)
+        return out
+    data = np.zeros((count, width), np.uint8)
+    lengths = np.zeros(count, np.int32)
     pos = 0
-    for i in np.nonzero(validity)[0]:
+    for i in range(count):
         (ln,) = struct.unpack_from("<I", raw, pos)
         pos += 4
         lengths[i] = min(ln, width)
@@ -702,39 +728,23 @@ def read_metadata(path: str) -> ParquetFileMeta:
     return ParquetFileMeta(num_rows=fm.get(3, 0), schema_elements=schema_elems, row_groups=rgs)
 
 
-def _plain_decode_dict_values(phys: int, raw: bytes, count: int, width: int,
-                              type_length: int = 0):
-    """Decode a PLAIN dictionary page into a lookup table."""
-    if phys == T_FLBA:
-        return _flba_to_int64(raw, count, type_length)
-    if phys == T_INT32:
-        return np.frombuffer(raw, "<i4", count=count)
-    if phys == T_INT64:
-        return np.frombuffer(raw, "<i8", count=count)
-    if phys == T_FLOAT:
-        return np.frombuffer(raw, "<f4", count=count)
-    if phys == T_DOUBLE:
-        return np.frombuffer(raw, "<f8", count=count)
-    if phys == T_BOOLEAN:
-        return np.unpackbits(np.frombuffer(raw, np.uint8), bitorder="little")[:count].astype(bool)
-    # byte arrays: (data (count, width), lengths)
-    data = np.zeros((count, width), np.uint8)
-    lengths = np.zeros(count, np.int32)
-    pos = 0
-    for i in range(count):
-        (ln,) = struct.unpack_from("<I", raw, pos)
-        pos += 4
-        lengths[i] = min(ln, width)
-        data[i, : lengths[i]] = np.frombuffer(raw, np.uint8, count=lengths[i], offset=pos)
-        pos += ln
-    return data, lengths
+def _python_codec(codec: int) -> bool:
+    """Whether this process decompresses ``codec``'s pages in pure python."""
+    return codec in (CODEC_LZ4, CODEC_LZ4_RAW) or (
+        codec == CODEC_SNAPPY and _snappy_library() is None)
 
 
-def read_column_chunk(path: str, chunk: ChunkMeta, dtype: DataType):
+def read_column_chunk(path: str, chunk: ChunkMeta, dtype: DataType,
+                      capacity: Optional[int] = None,
+                      tally: Optional[collections.Counter] = None):
     """Decode a full column chunk: every page (v1/v2), PLAIN or
     dictionary encodings, all supported codecs.  Returns
-    (data, validity, lengths|None) numpy arrays of chunk.num_values
-    rows.  ≙ the arrow-rs page machinery behind parquet_exec.rs:65-418."""
+    (data, validity, lengths|None) numpy arrays of ``capacity`` rows
+    (chunk.num_values where none is given); rows past chunk.num_values
+    are zero and invalid.  ``tally`` counts what was decoded: ``pages``
+    (data and dictionary pages) and ``pages_python_codec`` (those a
+    pure-python decoder decompressed).  ≙ the arrow-rs page machinery
+    behind parquet_exec.rs:65-418."""
     from .fs import get_fs
 
     with get_fs(path).open(path) as f:
@@ -742,53 +752,60 @@ def read_column_chunk(path: str, chunk: ChunkMeta, dtype: DataType):
         blob = f.read(chunk.total_comp if chunk.total_comp else None)
 
     n_total = chunk.num_values
+    cap = n_total if capacity is None else capacity
+    if cap < n_total:
+        raise ValueError(f"capacity {cap} under the chunk's {n_total} values")
     width = dtype.string_width if dtype.is_string else 0
-    validity = np.zeros(n_total, np.bool_)
+    validity = np.zeros(cap, np.bool_)
     if dtype.is_string:
-        data = np.zeros((n_total, width), np.uint8)
-        lengths = np.zeros(n_total, np.int32)
+        data = np.zeros((cap, width), np.uint8)
+        lengths = np.zeros(cap, np.int32)
     else:
-        data = np.zeros(n_total, dtype.np_dtype)
+        data = np.zeros(cap, dtype.np_dtype)
         lengths = None
     dict_table = None  # (values[, lengths]) from the dictionary page
+    python_codec = _python_codec(chunk.codec)
 
-    def emit_values(encoding: int, values: bytes, page_valid: np.ndarray, row0: int):
-        nv = page_valid.shape[0]
-        nn = int(page_valid.sum())
+    def decompress(raw, uncompressed_size: int):
+        if tally is not None:
+            tally["pages_python_codec"] += python_codec
+        return _decompress(raw, chunk.codec, uncompressed_size)
+
+    def emit_values(encoding: int, values, page_valid: Optional[np.ndarray], nv: int, row0: int):
+        """One data page's values into rows row0..row0+nv; ``page_valid``
+        None = every row valid (the values go straight to their rows)."""
         sl = slice(row0, row0 + nv)
-        validity[sl] = page_valid
-        if nn == 0:
-            return
+        if page_valid is None:
+            validity[sl] = True
+            nn = nv
+            where = ...
+        else:
+            validity[sl] = page_valid
+            nn = int(page_valid.sum())
+            where = page_valid
+            if nn == 0:
+                return
         if encoding in (ENC_PLAIN_DICT, ENC_RLE_DICT):
-            bit_width = values[0]
-            idx = _rle_bp_decode(values[1:], bit_width, nn)
+            idx = _rle_bp_decode(values[1:], values[0], nn)
             if dtype.is_string:
                 dvals, dlens = dict_table
-                rows = row0 + np.nonzero(page_valid)[0]
-                data[rows] = dvals[idx]
-                lengths[rows] = dlens[idx]
+                data[sl][where] = dvals[idx]
+                lengths[sl][where] = dlens[idx]
             else:
-                out = np.zeros(nv, dtype.np_dtype)
-                out[page_valid] = dict_table[idx].astype(dtype.np_dtype, copy=False)
-                data[sl] = out
+                data[sl][where] = dict_table[idx]
         elif encoding == ENC_RLE and chunk.phys == T_BOOLEAN:
             # v2 booleans: u32 length + RLE/bit-packed hybrid, width 1
             (rl,) = struct.unpack_from("<I", values, 0)
-            bits = _rle_bp_decode(values[4 : 4 + rl], 1, nn).astype(bool)
-            out = np.zeros(nv, np.bool_)
-            out[page_valid] = bits
-            data[sl] = out
+            data[sl][where] = _rle_bp_decode(values[4 : 4 + rl], 1, nn)
         elif encoding != ENC_PLAIN:
             # gated, not silently wrong: DELTA_* / BYTE_STREAM_SPLIT
             raise NotImplementedError(f"parquet page encoding {encoding}")
-        else:  # PLAIN — decode by the file's physical type, then adapt
-            d, l = _plain_decode_phys(chunk.phys, values, page_valid, width,
-                                      chunk.type_length)
-            if dtype.is_string:
-                data[sl, : d.shape[1]] = d[:, :width]
-                lengths[sl] = l
-            else:
-                data[sl] = d.astype(dtype.np_dtype, copy=False)
+        elif dtype.is_string:
+            d, l = _plain_decode(chunk.phys, values, nn, width)
+            data[sl][where] = d
+            lengths[sl][where] = l
+        else:  # by the file's physical type; the assignment adapts to dtype
+            data[sl][where] = _plain_decode(chunk.phys, values, nn, width, chunk.type_length)
 
     pos = 0
     decoded = 0
@@ -801,50 +818,43 @@ def read_column_chunk(path: str, chunk: ChunkMeta, dtype: DataType):
         ptype = ph.get(1, PAGE_DATA)
         uncomp_size = ph.get(2, 0)
         comp_size = ph.get(3, uncomp_size)
-        page_raw = blob[pos + header_len : pos + header_len + comp_size]
+        page_raw = view[pos + header_len : pos + header_len + comp_size]
         pos += header_len + comp_size
+        if ptype not in (PAGE_DICT, PAGE_DATA, PAGE_DATA_V2):
+            continue  # index or unknown page: skip
+        if tally is not None:
+            tally["pages"] += 1
         if ptype == PAGE_DICT:
             dh = ph.get(7, {})
             count = dh.get(1, 0)
-            payload = _decompress(page_raw, chunk.codec, uncomp_size)
-            dict_table = _plain_decode_dict_values(
-                chunk.phys, payload, count, width or 64, chunk.type_length
-            )
+            payload = decompress(page_raw, uncomp_size)
+            dict_table = _plain_decode(chunk.phys, payload, count, width or 64, chunk.type_length)
             continue
+        page_valid = None
         if ptype == PAGE_DATA:
             dph = ph.get(5, {})
             nv = dph.get(1, 0)
             encoding = dph.get(2, ENC_PLAIN)
-            payload = _decompress(page_raw, chunk.codec, uncomp_size)
+            values = decompress(page_raw, uncomp_size)
             if chunk.max_def > 0:
-                (def_len,) = struct.unpack_from("<I", payload, 0)
-                page_valid, _ = _rle_decode_defs(payload[4 : 4 + def_len], nv)
-                values = payload[4 + def_len :]
-            else:
-                page_valid = np.ones(nv, np.bool_)
-                values = payload
-            emit_values(encoding, values, page_valid, decoded)
-            decoded += nv
-            continue
-        if ptype == PAGE_DATA_V2:
+                (def_len,) = struct.unpack_from("<I", values, 0)
+                page_valid = _rle_decode_defs(values[4 : 4 + def_len], nv)
+                values = values[4 + def_len :]
+        else:
             dph = ph.get(8, {})
             nv = dph.get(1, 0)
-            num_nulls = dph.get(2, 0)
             encoding = dph.get(4, ENC_PLAIN)
             def_len = dph.get(5, 0)
             rep_len = dph.get(6, 0)
-            is_compressed = dph.get(7, True)
             levels = page_raw[: rep_len + def_len]  # NEVER compressed
-            rest = page_raw[rep_len + def_len :]
-            if is_compressed:
-                rest = _decompress(rest, chunk.codec, max(uncomp_size - rep_len - def_len, 1))
+            values = page_raw[rep_len + def_len :]
+            if dph.get(7, True):  # is_compressed
+                values = decompress(values, max(uncomp_size - rep_len - def_len, 1))
             if chunk.max_def > 0 and def_len:
                 # v2 def levels: RLE hybrid WITHOUT the u32 length prefix
-                page_valid = _rle_bp_decode(levels[rep_len:], 1, nv).astype(bool)
-            else:
-                page_valid = np.ones(nv, np.bool_)
-            emit_values(encoding, rest, page_valid, decoded)
-            decoded += nv
-            continue
-        # index or unknown page: skip
+                page_valid = _rle_decode_defs(levels[rep_len:], nv)
+        if page_valid is not None and page_valid.all():
+            page_valid = None
+        emit_values(encoding, values, page_valid, nv, decoded)
+        decoded += nv
     return data, validity, lengths

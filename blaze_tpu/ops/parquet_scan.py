@@ -10,6 +10,7 @@ Spark-compatible schema adaption (scan/mod.rs:28-187).
 
 from __future__ import annotations
 
+import collections
 import datetime
 import struct
 from typing import List, Optional, Sequence
@@ -176,10 +177,12 @@ class ParquetScanExec(ExecNode):
                         self.metrics.add("pruned_rows", rg.rows)
                         dispatch.record("scan_row_groups_pruned")
                         continue
-                    # one row group's fetch + decompress + decode and the
-                    # padding to its capacity; once a row group, in the
-                    # producer thread where the scan is pipelined
+                    # one row group's fetch + decompress + decode, every
+                    # column decoded straight into arrays of the row
+                    # group's capacity; once a row group, in the producer
+                    # thread where the scan is pipelined
                     file_bytes = 0
+                    pages = collections.Counter()
                     with self.metrics.timer("input_io_time", trace.span("scan_decode")):
                         cap = bucket_capacity(rg.rows)
                         cols: List[Column] = []
@@ -189,24 +192,14 @@ class ParquetScanExec(ExecNode):
                                 # schema adaption: missing column -> null
                                 cols.append(self._null_column(f.dtype, cap))
                                 continue
-                            data, validity, lengths = pq.read_column_chunk(path, ch, f.dtype)
+                            data, validity, lengths = pq.read_column_chunk(
+                                path, ch, f.dtype, capacity=cap, tally=pages)
                             file_bytes += ch.total_comp
-                            if f.dtype.is_string:
-                                d = np.zeros((cap, f.dtype.string_width), np.uint8)
-                                d[: rg.rows, : data.shape[1]] = data[:, : f.dtype.string_width]
-                                cols.append(
-                                    Column(f.dtype, d, _pad_1d(validity, cap), _pad_1d(lengths, cap))
-                                )
-                            else:
-                                cols.append(
-                                    Column(
-                                        f.dtype,
-                                        _pad_1d(data.astype(f.dtype.np_dtype, copy=False), cap),
-                                        _pad_1d(validity, cap),
-                                    )
-                                )
+                            cols.append(Column(f.dtype, data, validity, lengths))
                     dispatch.record("scan_file_bytes", file_bytes)
                     dispatch.record("scan_row_groups")
+                    dispatch.record("scan_pages", pages["pages"])
+                    dispatch.record("scan_pages_python_codec", pages["pages_python_codec"])
                     # emit in batch_rows slices to bound device batches
                     full = RecordBatch(self._schema, cols, rg.rows)
                     if rg.rows <= self.batch_rows:

@@ -11,7 +11,7 @@ import os
 import numpy as np
 import pytest
 
-from blaze_tpu.batch import batch_from_pydict, batch_to_pydict
+from blaze_tpu.batch import batch_from_pydict, batch_to_pydict, concat_batches
 from blaze_tpu.exprs import col, lit
 from blaze_tpu.io import parquet as pq
 from blaze_tpu.ops import MemoryScanExec, ParquetScanExec, ParquetSinkExec
@@ -150,3 +150,325 @@ def test_writer_codecs_roundtrip(tmp_path, codec):
     assert got_i == [None if not vmask[i] else int(data[i]) for i in range(n)]
     assert t.column("s").to_pylist() == [f"row-{i}" for i in range(n)]
     assert t.column("b").to_pylist() == [bool(i % 2 == 0) for i in range(n)]
+
+
+# --------------------------------------------------------------------
+# The reader's whole-array decode (PR 35): the hybrid RLE / bit-packed
+# decode, definition levels, the snappy codec choice, capacity, and
+# pyarrow-written files held to pyarrow's own read.
+
+def _varint(v):
+    out = bytearray()
+    while v >= 0x80:
+        out.append((v & 0x7F) | 0x80)
+        v >>= 7
+    out.append(v)
+    return bytes(out)
+
+
+def _hybrid_encode(runs, bit_width):
+    """runs: ("rle", value, count) | ("bp", values), values padded with
+    zeros to whole groups of 8 — the parquet-format hybrid encoding."""
+    out = bytearray()
+    for run in runs:
+        if run[0] == "rle":
+            _, value, count = run
+            out += _varint(count << 1) + int(value).to_bytes((bit_width + 7) // 8, "little")
+        else:
+            values = list(run[1]) + [0] * (-len(run[1]) % 8)
+            out += _varint((len(values) // 8) << 1 | 1)
+            bits = 0
+            for i, v in enumerate(values):
+                bits |= int(v) << (i * bit_width)
+            out += bits.to_bytes(len(values) * bit_width // 8, "little")
+    return bytes(out)
+
+
+def _hybrid_decode_scalar(data, bit_width, num_values):
+    """The reference decoder: one Python step a value, bignum bit picks."""
+    out = []
+    pos = 0
+    mask = (1 << bit_width) - 1
+    while len(out) < num_values and pos < len(data):
+        hdr = shift = 0
+        while True:
+            b = data[pos]
+            pos += 1
+            hdr |= (b & 0x7F) << shift
+            shift += 7
+            if not b & 0x80:
+                break
+        if hdr & 1:
+            nbytes = (hdr >> 1) * bit_width
+            bits = int.from_bytes(data[pos : pos + nbytes], "little")
+            pos += nbytes
+            out += [(bits >> (i * bit_width)) & mask for i in range((hdr >> 1) * 8)]
+        else:
+            nbytes = (bit_width + 7) // 8
+            out += [int.from_bytes(data[pos : pos + nbytes], "little") & mask] * (hdr >> 1)
+            pos += nbytes
+    out = (out + [0] * num_values)[:num_values]
+    # the decoder's result is int32: width 32 wraps as two's complement
+    return np.array(out, np.int64).astype(np.int32)
+
+
+def _hybrid_runs(kind, bit_width, rng):
+    top = 1 << bit_width
+    values = lambda n: [int(v) for v in rng.randint(0, top, n, dtype=np.int64)]
+    if kind == "bit_packed":  # parquet-mr's shape: 504 a run, the last one short
+        return [("bp", values(504)), ("bp", values(504)), ("bp", values(203))]
+    if kind == "rle":
+        return [("rle", top - 1, 300), ("rle", 0, 1), ("rle", values(1)[0], 77)]
+    return [("rle", values(1)[0], 9), ("bp", values(504)), ("rle", top - 1, 130),
+            ("bp", values(16)), ("bp", values(40)), ("rle", 0, 5), ("bp", values(3))]
+
+
+@pytest.mark.parametrize("kind", ["bit_packed", "rle", "mixed"])
+@pytest.mark.parametrize("bit_width", range(1, 33))
+def test_hybrid_decode_matches_the_scalar_decoder(bit_width, kind):
+    runs = _hybrid_runs(kind, bit_width, np.random.RandomState(1000 * bit_width + len(kind)))
+    data = _hybrid_encode(runs, bit_width)
+    total = sum(r[2] if r[0] == "rle" else len(r[1]) for r in runs)
+    assert total % 8  # the last group is cut short by num_values
+    for n in (total, total - 1, 8, 1, 0):
+        got = pq._rle_bp_decode(data, bit_width, n)
+        assert got.dtype == np.int32 and got.shape == (n,)
+        assert (got == _hybrid_decode_scalar(data, bit_width, n)).all(), n
+
+
+def test_hybrid_decode_edges():
+    # width 0 holds no bytes a value; a buffer that ends early reads zeros
+    assert (pq._rle_bp_decode(b"", 0, 5) == 0).all()
+    data = _hybrid_encode([("bp", [5, 6, 7, 1, 2, 3, 4, 5]), ("rle", 3, 4)], 3)
+    assert pq._rle_bp_decode(data, 3, 20).tolist() == [5, 6, 7, 1, 2, 3, 4, 5, 3, 3, 3, 3] + [0] * 8
+    # a last bit-packed group whose bytes were cut short (old writers)
+    assert pq._rle_bp_decode(data[:3], 3, 8).tolist() == _hybrid_decode_scalar(data[:3], 3, 8).tolist()
+    # a run header of more than one varint byte
+    long_run = _hybrid_encode([("rle", 1, 100_000), ("bp", [1, 0, 1])], 1)
+    assert pq._rle_bp_decode(long_run, 1, 100_003).sum() == 100_002
+
+
+def _chunk_pages(path, chunk):
+    """(header dict, decompressed v1 payload) of every page of a chunk."""
+    from blaze_tpu.io.thrift_compact import CompactReader
+
+    with open(path, "rb") as f:
+        f.seek(chunk.offset)
+        blob = f.read(chunk.total_comp)
+    pos = 0
+    while pos < len(blob):
+        r = CompactReader(memoryview(blob)[pos:])
+        ph = r.read_struct()
+        raw = blob[pos + r.pos : pos + r.pos + ph[3]]
+        pos += r.pos + ph[3]
+        yield ph, raw
+
+
+def _dictionary_file(tmp_path, n=150_000, **kw):
+    pa = pytest.importorskip("pyarrow")
+    import pyarrow.parquet as papq
+
+    rng = np.random.RandomState(5)
+    path = str(tmp_path / "dict.parquet")
+    # ~78,000 distinct values: the dictionary stays under its 1 MB page
+    # and the indices reach 17 bits, the cell's l_extendedprice shape
+    values = rng.randint(0, 100_000, n) * 1_000_003
+    papq.write_table(pa.table({"v": pa.array(values, pa.int64())}), path,
+                     compression="snappy", use_dictionary=True, data_page_size=64 << 10, **kw)
+    return path, values
+
+
+def test_pyarrow_index_pages_are_504_value_runs(tmp_path):
+    """A pyarrow (as a parquet-mr) dictionary-index page is bit-packed
+    runs of 504 values: the decode must not pay one numpy call a run."""
+    path, values = _dictionary_file(tmp_path)
+    chunk = pq.read_metadata(path).row_groups[0].chunks["v"]
+    widths = []
+    for ph, raw in _chunk_pages(path, chunk):
+        if ph.get(1) != pq.PAGE_DATA:
+            continue
+        payload = pq._snappy_decompress(raw)
+        (def_len,) = np.frombuffer(payload[:4], "<u4")
+        body = payload[4 + int(def_len):]
+        bit_width, nv = body[0], ph[5][1]
+        widths.append(bit_width)  # grows with the dictionary, page by page
+        assert body[1] == 63 << 1 | 1  # the first run: 63 groups of 8, 504 values
+        got = pq._rle_bp_decode(body[1:], bit_width, nv)
+        assert (got == _hybrid_decode_scalar(body[1:], bit_width, nv)).all()
+    assert len(widths) > 1 and max(widths) == 17
+    data, validity, _ = pq.read_column_chunk(path, chunk, DataType.int64())
+    assert validity.all() and (data == values).all()
+
+
+@pytest.mark.parametrize("page_version", ["1.0", "2.0"])
+def test_definition_levels_with_nulls(tmp_path, page_version):
+    """Nulls scattered (bit-packed levels) and in long stretches (RLE
+    levels), v1 (u32-prefixed, compressed) and v2 (bare, uncompressed)."""
+    pa = pytest.importorskip("pyarrow")
+    import pyarrow.parquet as papq
+
+    n = 30_000
+    rng = np.random.RandomState(9)
+    valid = rng.rand(n) > 0.045          # tpcds's 4.5%-NULL foreign keys
+    valid[5_000:9_000] = False
+    valid[20_000:26_000] = True
+    keys = rng.randint(0, 2_000, n)
+    path = str(tmp_path / f"nulls{page_version}.parquet")
+    papq.write_table(pa.table({"k": pa.array(keys, pa.int64(), mask=~valid)}), path,
+                     compression="snappy", data_page_version=page_version, data_page_size=16 << 10)
+    chunk = pq.read_metadata(path).row_groups[0].chunks["k"]
+    data, validity, _ = pq.read_column_chunk(path, chunk, DataType.int64())
+    assert (validity == valid).all()
+    assert (data[valid] == keys[valid]).all() and (data[~valid] == 0).all()
+    assert papq.read_table(path).column("k").to_pylist() == [
+        int(k) if v else None for k, v in zip(keys, valid)]
+
+
+def test_snappy_library_and_pure_python_agree(tmp_path, monkeypatch):
+    pa = pytest.importorskip("pyarrow")
+    path, values = _dictionary_file(tmp_path)
+    chunk = pq.read_metadata(path).row_groups[0].chunks["v"]
+    assert pq._snappy_library() is not None
+    blocks = [raw for _, raw in _chunk_pages(path, chunk)]
+    # this module's own encoder, with overlapping copies (offset < length)
+    rng = np.random.RandomState(3)
+    for src in (b"a" * 5000, b"abc" * 700 + bytes(rng.randint(0, 4, 3000).astype(np.uint8)),
+                bytes(rng.randint(0, 256, 4000).astype(np.uint8)), b"", b"xyz"):
+        blocks.append(pq._snappy_compress(src))
+        assert pq._snappy_decompress(blocks[-1]) == src
+    for block in blocks:
+        want = pa.Codec("snappy").decompress(block, len(pq._snappy_decompress(block)), asbytes=True)
+        assert pq.snappy_decompress(block) == pq._snappy_decompress(block) == want
+    # where no library imports, the same pages decode through the fallback
+    whole = pq.read_column_chunk(path, chunk, DataType.int64())
+    monkeypatch.setattr(pq, "_snappy_library", lambda: None)
+    fallback = pq.read_column_chunk(path, chunk, DataType.int64())
+    assert (whole[0] == fallback[0]).all() and (fallback[0] == values).all()
+    with pytest.raises(ValueError, match="copy offset"):
+        pq._snappy_decompress(bytes([8, 0x01 | (4 << 2), 9]))  # copies from before the start
+
+
+def _mixed_file(tmp_path, n=40_000):
+    """The shapes a Spark table holds, as pyarrow writes them: dictionary
+    int64 decimal, date, dictionary string, a column whose dictionary
+    outgrows its page (PLAIN fallback), a column with nulls."""
+    pa = pytest.importorskip("pyarrow")
+    import pyarrow.parquet as papq
+    from bench.entries import catalyst_parquet
+
+    rng = np.random.RandomState(21)
+    schema = Schema([
+        Field("price", DataType.decimal(12, 2)),
+        Field("day", DataType.date32()),
+        Field("flag", DataType.string(8)),
+        Field("wide", DataType.int64()),
+        Field("maybe", DataType.int32()),
+    ])
+    price = rng.randint(0, 5_000, n).astype(np.int64) * 2_099 + 90_001  # 40 KB of dictionary
+    day = rng.randint(8_000, 10_600, n).astype(np.int32)
+    words = [b"A", b"N", b"R", b"RETURN", b""]
+    pick = rng.randint(0, len(words), n)
+    flag = np.zeros((n, 8), np.uint8)
+    flag_len = np.array([len(words[i]) for i in pick], np.int32)
+    for i, w in enumerate(words):
+        flag[pick == i, : len(w)] = np.frombuffer(w, np.uint8)
+    wide = rng.randint(0, 1 << 62, n).astype(np.int64)   # all distinct: PLAIN
+    maybe = rng.randint(-50, 50, n).astype(np.int32)
+    maybe_valid = rng.rand(n) > 0.3
+    ones = np.ones(n, bool)
+    cols = {"price": (price, ones, None), "day": (day, ones, None), "flag": (flag, ones, flag_len),
+            "wide": (wide, ones, None), "maybe": (maybe, maybe_valid, None)}
+    arrays = [catalyst_parquet.arrow_array(f.dtype, *cols[f.name]) for f in schema.fields]
+    path = str(tmp_path / "mixed.parquet")
+    papq.write_table(pa.Table.from_arrays(arrays, names=schema.names), path,
+                     **dict(catalyst_parquet.WRITER, data_page_size=32 << 10, row_group_size=25_000,
+                            dictionary_pagesize_limit=64 << 10))
+    return path, schema, cols
+
+
+def _as_stored(value):
+    """A pyarrow python value as the engine stores it."""
+    if isinstance(value, datetime.date):
+        return (value - datetime.date(1970, 1, 1)).days
+    if hasattr(value, "scaleb"):  # decimal(12, 2): the unscaled integer
+        return int(value.scaleb(2))
+    return value
+
+
+@pytest.mark.parametrize("column", ["price", "day", "flag", "wide", "maybe"])
+def test_pyarrow_file_reads_as_pyarrow_reads_it(tmp_path, column):
+    import pyarrow.parquet as papq
+
+    path, schema, cols = _mixed_file(tmp_path)
+    dtype = next(f.dtype for f in schema.fields if f.name == column)
+    page_encodings = {ph[5][2] for ph, _ in _chunk_pages(path, pq.read_metadata(path).row_groups[0].chunks[column])
+                      if ph.get(1) == pq.PAGE_DATA}
+    # every chunk starts dictionary-encoded; only `wide` outgrows its dictionary page
+    assert page_encodings == ({pq.ENC_RLE_DICT, pq.ENC_PLAIN} if column == "wide" else {pq.ENC_RLE_DICT})
+    theirs = [_as_stored(v) for v in papq.read_table(path, columns=[column]).column(column).to_pylist()]
+    ours = []
+    for rg in pq.read_metadata(path).row_groups:
+        data, validity, lengths = pq.read_column_chunk(path, rg.chunks[column], dtype)
+        assert not data[~validity].any()  # a null row holds zeros
+        if dtype.is_string:
+            ours += [bytes(data[i, : lengths[i]]).decode() if validity[i] else None for i in range(rg.rows)]
+        else:
+            ours += [v if ok else None for v, ok in zip(data.tolist(), validity.tolist())]
+    assert ours == theirs
+    source, valid, _ = cols[column]
+    if not dtype.is_string:
+        assert ours == [v if ok else None for v, ok in zip(source.tolist(), valid.tolist())]
+
+
+def test_capacity_pads_what_the_chunk_holds(tmp_path):
+    path, schema, _ = _mixed_file(tmp_path)
+    rg = pq.read_metadata(path).row_groups[1]
+    assert rg.rows == 15_000
+    for f in schema.fields:
+        plain = pq.read_column_chunk(path, rg.chunks[f.name], f.dtype)
+        padded = pq.read_column_chunk(path, rg.chunks[f.name], f.dtype, capacity=16_384)
+        for a, b in zip(plain, padded):
+            if a is None:
+                assert b is None
+                continue
+            assert b.dtype == a.dtype and b.shape == (16_384,) + a.shape[1:]
+            assert (b[: rg.rows] == a).all() and not b[rg.rows :].any()
+    with pytest.raises(ValueError, match="capacity"):
+        pq.read_column_chunk(path, rg.chunks["day"], DataType.date32(), capacity=rg.rows - 1)
+
+
+@pytest.mark.parametrize("library", [True, False])
+def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, library):
+    """One scan_decode span a row group, every page of every chunk in
+    scan_pages, and scan_pages_python_codec only where no library is."""
+    import pyarrow.parquet as papq
+
+    from blaze_tpu.runtime import dispatch
+
+    path, schema, cols = _mixed_file(tmp_path)
+    if not library:
+        monkeypatch.setattr(pq, "_snappy_library", lambda: None)
+    md = papq.ParquetFile(path).metadata
+    scan = ParquetScanExec([[path]], schema, batch_rows=8192)
+    with dispatch.capture() as c:
+        rows = sum(b.num_rows for b in scan.execute(0, TaskContext(0, 1)))
+    assert rows == md.num_rows == 40_000
+    assert c["scan_decode_n"] == c["scan_row_groups"] == md.num_row_groups == 2
+    assert c["scan_file_bytes"] == sum(
+        md.row_group(r).column(i).total_compressed_size
+        for r in range(2) for i in range(len(schema.fields)))
+    pages = sum(1 for rg in pq.read_metadata(path).row_groups
+                for ch in rg.chunks.values() for _ in _chunk_pages(path, ch))
+    assert c["scan_pages"] == pages > 20
+    assert c.get("scan_pages_python_codec", 0) == (0 if library else pages)
+
+
+def test_scan_batches_equal_the_decoded_row_group(tmp_path):
+    """Decoding at the row group's capacity changes no batch: the scan's
+    slices hold the file's rows, padding zero and invalid."""
+    path, schema, cols = _mixed_file(tmp_path)
+    scan = ParquetScanExec([[path]], schema, batch_rows=8192)
+    got = batch_to_pydict(concat_batches(list(scan.execute(0, TaskContext(0, 1)))))
+    assert got["maybe"] == [int(v) if ok else None for v, ok in zip(cols["maybe"][0], cols["maybe"][1])]
+    assert got["wide"] == cols["wide"][0].tolist()
+    assert got["day"] == cols["day"][0].tolist()
