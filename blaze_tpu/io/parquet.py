@@ -659,7 +659,16 @@ class ChunkMeta:
 @dataclass
 class RowGroupMeta:
     rows: int
-    chunks: Dict[str, ChunkMeta]
+    chunks: Dict[str, ChunkMeta]      # in the file's column order
+    total_comp: int = 0               # compressed bytes of all its chunks
+
+    @property
+    def midpoint(self) -> int:
+        """The byte by which parquet-mr gives the row group to one
+        split of its file (``filterFileMetaDataByMidpoint``): the first
+        chunk's first page plus half the compressed size."""
+        first = next(iter(self.chunks.values())).offset if self.chunks else 0
+        return first + self.total_comp // 2
 
 
 @dataclass
@@ -724,7 +733,10 @@ def read_metadata(path: str) -> ParquetFileMeta:
                 max_def=0 if repetition.get(name) == 0 else 1,
                 type_length=type_lengths.get(name, 0),
             )
-        rgs.append(RowGroupMeta(rows=rg.get(3, 0), chunks=chunks))
+        total_comp = rg.get(6)  # total_compressed_size: optional in the format
+        if total_comp is None:
+            total_comp = sum(c.total_comp for c in chunks.values())
+        rgs.append(RowGroupMeta(rows=rg.get(3, 0), chunks=chunks, total_comp=total_comp))
     return ParquetFileMeta(num_rows=fm.get(3, 0), schema_elements=schema_elems, row_groups=rgs)
 
 

@@ -25,7 +25,7 @@ from .generate import GenerateExec
 from .object_agg import ObjectAggExec, Udaf
 from .udafs import approx_count_distinct, approx_percentile
 from .orc_scan import OrcScanExec
-from .parquet_scan import ParquetScanExec
+from .parquet_scan import FileSplit, ParquetScanExec
 from .parquet_sink import ParquetSinkExec
 
 __all__ = [
@@ -36,4 +36,5 @@ __all__ = [
     "SortMergeJoinExec", "WindowExec", "WindowFunction", "ExpandExec",
     "ObjectAggExec", "Udaf", "approx_count_distinct", "approx_percentile",
     "GenerateExec", "OrcScanExec", "ParquetScanExec", "ParquetSinkExec",
+    "FileSplit",
 ]

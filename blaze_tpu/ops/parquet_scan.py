@@ -13,7 +13,7 @@ from __future__ import annotations
 import collections
 import datetime
 import struct
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
 
@@ -27,6 +27,37 @@ from ..runtime.context import TaskContext
 from ..runtime.errors import reraise_control
 from ..schema import DataType, Schema, TypeKind
 from .base import BatchStream, ExecNode
+
+
+class FileSplit(NamedTuple):
+    """``length`` bytes of ``path`` from ``start``: one piece of a file
+    as Spark hands it to a task (a ``PartitionedFile``).  The pieces of
+    a file tile it, and a row group belongs to the piece that holds its
+    midpoint, so each is read once.  ≙ the ``range {start, end}`` the
+    reference's ``NativeParquetScanBase`` puts on each file."""
+
+    path: str
+    start: int
+    length: int
+
+
+#: an entry of a scan's file group: a path is the whole file
+FileEntry = Union[str, FileSplit]
+
+
+def entry_path(entry: FileEntry) -> str:
+    return entry.path if isinstance(entry, FileSplit) else entry
+
+
+def split_row_groups(entry: FileEntry,
+                     row_groups: Sequence[pq.RowGroupMeta]) -> List[pq.RowGroupMeta]:
+    """Those of a file's row groups that ``entry`` reads: all of them
+    for a path, for a split those whose midpoint lies in its range
+    (parquet-mr's ``RangeMetadataFilter``)."""
+    if not isinstance(entry, FileSplit):
+        return list(row_groups)
+    end = entry.start + entry.length
+    return [rg for rg in row_groups if entry.start <= rg.midpoint < end]
 
 
 def _lit_physical(value, dtype: DataType):
@@ -110,12 +141,14 @@ def _maybe_match(chunk: pq.ChunkMeta, dtype: DataType, op: str, lit_v) -> bool:
 class ParquetScanExec(ExecNode):
     def __init__(
         self,
-        file_groups: Sequence[Sequence[str]],
+        file_groups: Sequence[Sequence[FileEntry]],
         schema: Schema,
         predicate: Optional[Expr] = None,
         batch_rows: int = 0,
     ):
         super().__init__([])
+        # one group a task; an entry is a path (the whole file) or a
+        # FileSplit (the row groups whose midpoint lies in its range)
         self.file_groups = [list(g) for g in file_groups]
         self._schema = schema
         self.predicate = predicate
@@ -148,15 +181,22 @@ class ParquetScanExec(ExecNode):
         files = self.file_groups[partition] if partition < len(self.file_groups) else []
 
         def stream():
-            for path in files:
-                try:
-                    meta = pq.read_metadata(path)
-                except Exception:
-                    if bool(conf.IGNORE_CORRUPT_FILES.get()):
-                        self.metrics.add("skipped_corrupt_files", 1)
-                        continue
-                    raise
-                for rg in meta.row_groups:
+            for entry in files:
+                path = entry_path(entry)
+                # one task's open of one file: its footer, and which of
+                # its row groups are this entry's
+                with trace.span("scan_open"):
+                    try:
+                        row_groups = pq.read_metadata(path).row_groups
+                    except Exception:
+                        if bool(conf.IGNORE_CORRUPT_FILES.get()):
+                            self.metrics.add("skipped_corrupt_files", 1)
+                            continue
+                        raise
+                    mine = split_row_groups(entry, row_groups)
+                dispatch.record("scan_splits")
+                dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
+                for rg in mine:
                     if rg.rows == 0:
                         continue
                     pruned = False

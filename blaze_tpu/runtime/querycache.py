@@ -103,9 +103,12 @@ def _node_part(node, slots: list, sources: list, exact: list):
 
         from ..exprs.compile import expr_key
 
+        from ..ops.parquet_scan import entry_path
+
+        # an entry is a path or a FileSplit: a range is part of the key
         paths = tuple(tuple(g) for g in node.file_groups)
         for g in node.file_groups:
-            for p in g:
+            for p in map(entry_path, g):
                 try:
                     st = os.stat(p)
                 except OSError:

@@ -339,15 +339,21 @@ _JOINS = frozenset({"BroadcastJoinExec", "HashJoinExec",
 _AGGS = frozenset({"AggExec", "ObjectAggExec", "BloomFilterAggExec"})
 
 
-def _footer(path: str) -> Optional[Tuple[int, int]]:
-    """(rows, bytes) for one parquet/ORC file from its footer, cached
-    by (path, mtime_ns, size) so per-task optimize_plan never re-reads
-    a footer it has already paid for."""
+def _footer(entry) -> Optional[Tuple[int, int]]:
+    """(rows, bytes) for one entry of a scan's file group from the
+    file's footer — a parquet/ORC file, or a byte range of a parquet
+    file, which has the rows and compressed bytes of the row groups the
+    range reads (a file in four ranges counts once) — cached by
+    (entry, mtime_ns, size) so per-task optimize_plan never re-reads a
+    footer it has already paid for."""
+    from ..ops.parquet_scan import FileSplit, entry_path, split_row_groups
+
+    path = entry_path(entry)
     try:
         st = os.stat(path)
     except OSError:
         return None
-    key = (path, st.st_mtime_ns, st.st_size)
+    key = (entry, st.st_mtime_ns, st.st_size)
     with _lock:
         lockset.check(_LOG, "_footer_cache")
         if key in _footer_cache:
@@ -357,7 +363,12 @@ def _footer(path: str) -> Optional[Tuple[int, int]]:
             from ..io.orc import read_metadata
         else:
             from ..io.parquet import read_metadata
-        rows = int(read_metadata(path).num_rows)
+        meta = read_metadata(path)
+        if isinstance(entry, FileSplit):
+            mine = split_row_groups(entry, meta.row_groups)
+            val = (sum(rg.rows for rg in mine), sum(rg.total_comp for rg in mine))
+        else:
+            val = (int(meta.num_rows), int(st.st_size))
     except Exception as e:  # noqa: BLE001 — an unreadable footer only
         # degrades the ESTIMATE; the scan itself will surface the real
         # typed error when it reads the file
@@ -365,7 +376,6 @@ def _footer(path: str) -> Optional[Tuple[int, int]]:
 
         errors.reraise_control(e)
         return None
-    val = (rows, int(st.st_size))
     with _lock:
         lockset.check(_LOG, "_footer_cache")
         if len(_footer_cache) >= _FOOTER_CAP:

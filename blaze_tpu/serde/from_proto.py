@@ -185,8 +185,12 @@ def plan_from_proto(n: pb.PhysicalPlanNode):
             pred = sub if pred is None else (pred & sub)
         groups = [g.split(";") if g else [] for g in s.file_groups]
         if kind == "parquet_scan":
-            from ..ops import ParquetScanExec
+            from ..ops import FileSplit, ParquetScanExec
 
+            if s.file_ranges:  # none: a scan of whole files, or bytes from before the ranges
+                groups = [[path if length < 0 else FileSplit(path, start, length)
+                           for path, start, length in zip(g, r.start, r.length, strict=True)]
+                          for g, r in zip(groups, s.file_ranges, strict=True)]
             return ParquetScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows)
         from ..ops.orc_scan import OrcScanExec
 

@@ -210,6 +210,7 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
         HashJoinExec,
         SortMergeJoinExec,
     )
+    from ..ops.parquet_scan import FileSplit, entry_path
     from ..parallel.broadcast import IpcWriterExec
     from ..parallel.shuffle import IpcReaderExec, ShuffleWriterExec
     from ..runtime.context import RESOURCES
@@ -241,7 +242,15 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
         sub = out.parquet_scan if isinstance(node, ParquetScanExec) else out.orc_scan
         sub.schema.CopyFrom(schema_to_proto(node.schema))
         for g in node.file_groups:
-            sub.file_groups.append(";".join(g))
+            sub.file_groups.append(";".join(entry_path(e) for e in g))
+        if any(isinstance(e, FileSplit) for g in node.file_groups for e in g):
+            # OrcScanExec admits no split, so this is a Parquet scan's
+            for g in node.file_groups:
+                ranges = sub.file_ranges.add()
+                for e in g:
+                    ranged = isinstance(e, FileSplit)
+                    ranges.start.append(e.start if ranged else 0)
+                    ranges.length.append(e.length if ranged else -1)
         if node.predicate is not None:
             sub.predicate.add().CopyFrom(expr_to_proto(node.predicate))
         sub.batch_rows = node.stated_batch_rows
