@@ -2,9 +2,11 @@
 
 ≙ the file-format half of the reference's ParquetExec/ParquetSinkExec
 (parquet_exec.rs:65-418, parquet_sink_exec.rs) — implemented from the
-public parquet-format spec.  The file format is read and written here;
-of pyarrow (in the image, optional) only the snappy codec is borrowed
-(snappy_decompress), as ZSTD pages go to ``zstandard``:
+public parquet-format spec.  The file format is read and written here,
+and pyarrow (in the image, optional) is used where it imports: its
+snappy codec for the page decoder (snappy_decompress; ZSTD pages go to
+``zstandard``), and its C++ column reader for a scan's row groups
+(read_row_group), held to the page decoder's arrays bit for bit:
 
 - written files: PAR1 magic, one DATA_PAGE v1 per column chunk per row
   group, PLAIN encoding, RLE/bit-packed definition levels for OPTIONAL
@@ -18,6 +20,13 @@ of pyarrow (in the image, optional) only the snappy codec is borrowed
   pushed-down predicate over chunk statistics — the row-group
   granularity of the reference's page filtering
   (spark.blaze.parquet.enable.pageFiltering).
+- read_row_group: one row group's chunks fetched, decompressed and
+  decoded by Arrow's reader in ONE call that releases the GIL, each
+  column then converted whole to read_column_chunk's arrays; a chunk
+  Arrow's reader does not take (INT96, FIXED_LEN_BYTE_ARRAY, a file
+  type other than the requested one), and every chunk where pyarrow
+  does not import, goes through read_column_chunk — ≙ the reference's
+  ParquetExec decoding through the arrow-rs ``parquet`` crate.
 
 Physical mapping: BOOLEAN (bit-packed) <- bool; INT32 <- int8/16/32 +
 DATE; INT64 <- int64/timestamp/decimal(<=18) [ConvertedType DECIMAL];
@@ -41,6 +50,15 @@ from .thrift_compact import (
     CT_BINARY, CT_I32, CT_I64, CT_STRUCT, CompactReader, CompactWriter,
 )
 
+try:
+    # with this module, on the thread that imports it, never first on a
+    # scan's producer thread: a thread that imports pyarrow and ends with
+    # its task takes Arrow's default memory pool with it, and the next
+    # thread to read a file through Arrow segfaults (pyarrow 25.0.0)
+    import pyarrow.parquet as _pyarrow_parquet
+except ImportError:
+    _pyarrow_parquet = None
+
 MAGIC = b"PAR1"
 
 # parquet physical types
@@ -60,10 +78,10 @@ ENC_PLAIN, ENC_PLAIN_DICT, ENC_RLE, ENC_RLE_DICT = 0, 2, 3, 8
 def _snappy_library():
     """pyarrow's snappy codec where pyarrow imports, else None: the one
     place that chooses (Parquet pages and ORC chunks both come here)."""
-    try:
-        import pyarrow
-    except ImportError:
+    if _pyarrow_parquet is None:
         return None
+    import pyarrow
+
     return pyarrow.Codec("snappy") if pyarrow.Codec.is_available("snappy") else None
 
 
@@ -661,6 +679,7 @@ class RowGroupMeta:
     rows: int
     chunks: Dict[str, ChunkMeta]      # in the file's column order
     total_comp: int = 0               # compressed bytes of all its chunks
+    index: int = 0                    # its place among the file's row groups
 
     @property
     def midpoint(self) -> int:
@@ -736,7 +755,8 @@ def read_metadata(path: str) -> ParquetFileMeta:
         total_comp = rg.get(6)  # total_compressed_size: optional in the format
         if total_comp is None:
             total_comp = sum(c.total_comp for c in chunks.values())
-        rgs.append(RowGroupMeta(rows=rg.get(3, 0), chunks=chunks, total_comp=total_comp))
+        rgs.append(RowGroupMeta(rows=rg.get(3, 0), chunks=chunks, total_comp=total_comp,
+                                index=len(rgs)))
     return ParquetFileMeta(num_rows=fm.get(3, 0), schema_elements=schema_elems, row_groups=rgs)
 
 
@@ -869,4 +889,217 @@ def read_column_chunk(path: str, chunk: ChunkMeta, dtype: DataType,
             page_valid = None
         emit_values(encoding, values, page_valid, nv, decoded)
         decoded += nv
+    return data, validity, lengths
+
+
+# ------------------------------------------------- Arrow's column reader
+
+def _arrow_reader():
+    """``pyarrow.parquet`` where it imports, else None: the one place
+    that chooses whether Arrow's C++ reader decodes a scan's row groups
+    or the page decoder above decodes every chunk."""
+    return _pyarrow_parquet
+
+
+def open_arrow_file(path: str, fields: Sequence[Field], row_groups: Sequence[RowGroupMeta]):
+    """One task's open of one file for read_row_group: Arrow's
+    ``ParquetFile`` over ``get_fs(path).open(path)``, the file's string
+    columns among ``fields`` left as indices + dictionary; None where
+    pyarrow does not import or the file holds no row group.  The caller
+    closes it (``close(force=True)`` closes the file under it too)."""
+    lib = _arrow_reader()
+    if lib is None or not row_groups:
+        return None
+    from .fs import get_fs
+
+    chunks = row_groups[0].chunks
+    as_dictionary = [f.name for f in fields if f.dtype.is_string
+                     and f.name in chunks and chunks[f.name].phys == T_BYTE_ARRAY]
+    f = get_fs(path).open(path)
+    try:
+        return lib.ParquetFile(f, read_dictionary=as_dictionary)
+    except BaseException:
+        f.close()
+        raise
+
+
+def read_row_group(path: str, row_group: RowGroupMeta, fields: Sequence[Field], capacity: int,
+                   arrow_file=None, tally: Optional[collections.Counter] = None):
+    """Decode one row group's chunks of ``fields``: for each field, in
+    order, read_column_chunk's ``(data, validity, lengths|None)`` at
+    ``capacity`` rows, or None for a field the file does not hold.
+
+    With ``arrow_file`` (open_arrow_file's), Arrow's reader fetches,
+    decompresses and decodes the chunks in one call, outside the GIL,
+    and each column is converted whole; a chunk it is not asked for
+    (INT96, FIXED_LEN_BYTE_ARRAY), one whose Arrow type is not the
+    requested type's, and all of them where that call fails, are
+    decoded by read_column_chunk — which is also what says what is
+    wrong with a corrupt chunk.  ``tally`` counts ``chunks`` and
+    ``chunks_native`` besides read_column_chunk's pages."""
+    if capacity < row_group.rows:
+        raise ValueError(f"capacity {capacity} under the row group's {row_group.rows} rows")
+    held = [(i, f, row_group.chunks[f.name]) for i, f in enumerate(fields)
+            if f.name in row_group.chunks]
+    table = None
+    asked = [f.name for _, f, chunk in held if chunk.phys not in (T_INT96, T_FLBA)]
+    if arrow_file is not None and asked:
+        import pyarrow
+
+        try:
+            # use_threads: the columns decode side by side on Arrow's pool; on
+            # the chip machine every window read better with it than any
+            # without (PERF.md §6, PR 37)
+            table = arrow_file.read_row_group(row_group.index, columns=asked, use_threads=True)
+        except (pyarrow.ArrowException, OSError):
+            pass  # the page decoder reads these chunks, or says what is wrong with them
+    out: List[Optional[tuple]] = [None] * len(fields)
+    for i, f, chunk in held:
+        arrays = None
+        if table is not None and f.name in asked:
+            arrays = _from_arrow(table.column(f.name), f.dtype, capacity)
+        if arrays is None:
+            arrays = read_column_chunk(path, chunk, f.dtype, capacity=capacity, tally=tally)
+        elif tally is not None:
+            tally["chunks_native"] += 1
+        if tally is not None:
+            tally["chunks"] += 1
+        out[i] = arrays
+    return out
+
+
+_ARROW_FIXED = {  # requested kind -> the pyarrow.types test of the one Arrow type it is read from
+    TypeKind.INT8: "is_int8", TypeKind.INT16: "is_int16", TypeKind.INT32: "is_int32",
+    TypeKind.INT64: "is_int64", TypeKind.FLOAT32: "is_float32", TypeKind.FLOAT64: "is_float64",
+    TypeKind.DATE32: "is_date32",
+}
+
+
+def _arrow_layout(t, dtype: DataType) -> Optional[str]:
+    """How _from_arrow reads a column of Arrow type ``t`` into the
+    requested ``dtype``, or None where the two do not pair (a file type
+    that differs from the requested one: the page decoder adapts it)."""
+    from pyarrow import types
+
+    k = dtype.kind
+    if k in _ARROW_FIXED:
+        return "fixed" if getattr(types, _ARROW_FIXED[k])(t) else None
+    if k == TypeKind.TIMESTAMP:
+        return "fixed" if types.is_timestamp(t) and t.unit == "us" else None
+    if k == TypeKind.BOOL:
+        return "bits" if types.is_boolean(t) else None
+    if k == TypeKind.DECIMAL:
+        taken = (types.is_decimal128(t) and t.precision <= 18
+                 and (t.precision, t.scale) == (dtype.precision, dtype.scale))
+        return "decimal128" if taken else None
+    if dtype.is_string:
+        if types.is_dictionary(t):
+            return "dictionary" if _is_bytes(t.value_type) else None
+        return "bytes" if _is_bytes(t) else None
+    return None
+
+
+def _is_bytes(t) -> bool:
+    from pyarrow import types
+
+    return (types.is_string(t) or types.is_binary(t)
+            or types.is_large_string(t) or types.is_large_binary(t))
+
+
+def _arrow_bits(buf, offset: int, n: int) -> np.ndarray:
+    """``n`` bits of an Arrow bitmap from bit ``offset`` -> bool."""
+    first, bit = divmod(offset, 8)
+    bits = np.unpackbits(np.frombuffer(buf, np.uint8, offset=first), count=bit + n,
+                         bitorder="little")
+    return bits[bit:].view(np.bool_)
+
+
+def _arrow_bytes(arr, width: int, data: np.ndarray, lengths: np.ndarray) -> None:
+    """An Arrow string / binary array's values into ``data`` (len(arr),
+    width), zero-padded, each cut to its first ``width`` bytes, and
+    ``lengths``: offsets and chars by one mask, no Python step a row."""
+    from pyarrow import types
+
+    n = len(arr)
+    large = types.is_large_string(arr.type) or types.is_large_binary(arr.type)
+    odt = np.dtype(np.int64 if large else np.int32)
+    _, offsets_buf, chars_buf = arr.buffers()
+    offsets = np.frombuffer(offsets_buf, odt, count=n + 1, offset=arr.offset * odt.itemsize)
+    full = np.diff(offsets)
+    start, total = int(offsets[0]), int(offsets[-1] - offsets[0])
+    chars = (np.frombuffer(chars_buf, np.uint8, count=total, offset=start) if total
+             else np.zeros(0, np.uint8))
+    np.minimum(full, width, out=lengths, casting="unsafe")
+    if total and int(full.max()) > width:  # a value longer than the width keeps its head
+        within = np.arange(total) - np.repeat(offsets[:-1] - start, full)
+        chars = chars[within < width]
+    data[np.arange(width) < lengths[:, None]] = chars
+
+
+def _arrow_dictionary(arr, valid: Optional[np.ndarray], width: int,
+                      data: np.ndarray, lengths: np.ndarray) -> None:
+    """An Arrow dictionary<string> array's rows into ``data`` (len(arr),
+    width) and ``lengths``: the (small) dictionary padded once, one zero
+    entry after it for the null rows, then ONE gather of whole rows and
+    one of lengths."""
+    n = len(arr)
+    entries = arr.dictionary
+    k = len(entries)
+    table = np.zeros((k + 1, width), np.uint8)
+    table_lengths = np.zeros(k + 1, np.int32)
+    if k:
+        _arrow_bytes(entries, width, table[:k], table_lengths[:k])
+    idt = np.dtype(arr.type.index_type.to_pandas_dtype())
+    idx = np.frombuffer(arr.buffers()[1], idt, count=n, offset=arr.offset * idt.itemsize)
+    if valid is not None:
+        idx = np.where(valid, idx, k)
+    # a row is one word where the width is a word's, else `width` opaque bytes
+    word = np.dtype(f"u{width}" if width in (1, 2, 4, 8) else (np.void, width))
+    np.take(table.view(word)[:, 0], idx, out=data.view(word)[:, 0], mode="clip")
+    np.take(table_lengths, idx, out=lengths, mode="clip")
+
+
+def _from_arrow(column, dtype: DataType, capacity: int):
+    """A row group's column as Arrow decoded it (a ChunkedArray) ->
+    read_column_chunk's (data, validity, lengths|None) at ``capacity``
+    rows, by array operations over whole chunks; None where its Arrow
+    type and ``dtype`` do not pair (_arrow_layout)."""
+    layout = _arrow_layout(column.type, dtype)
+    if layout is None:
+        return None
+    width = dtype.string_width if dtype.is_string else 0
+    validity = np.zeros(capacity, np.bool_)
+    if dtype.is_string:
+        data = np.zeros((capacity, width), np.uint8)
+        lengths = np.zeros(capacity, np.int32)
+    else:
+        data = np.zeros(capacity, dtype.np_dtype)
+        lengths = None
+    row = 0
+    for arr in column.chunks:
+        n = len(arr)
+        if n == 0:
+            continue
+        sl = slice(row, row + n)
+        row += n
+        buffers = arr.buffers()
+        valid = None if arr.null_count == 0 else _arrow_bits(buffers[0], arr.offset, n)
+        validity[sl] = True if valid is None else valid
+        if layout == "dictionary":
+            _arrow_dictionary(arr, valid, width, data[sl], lengths[sl])
+            continue
+        if layout == "bytes":
+            _arrow_bytes(arr, width, data[sl], lengths[sl])
+            if valid is not None:
+                lengths[sl][~valid] = 0
+        elif layout == "bits":
+            data[sl] = _arrow_bits(buffers[1], arr.offset, n)
+        elif layout == "decimal128":  # little-endian int128: the low word is the value
+            words = np.frombuffer(buffers[1], np.int64, count=2 * n, offset=16 * arr.offset)
+            data[sl] = words.reshape(n, 2)[:, 0]
+        else:
+            item = data.dtype.itemsize
+            data[sl] = np.frombuffer(buffers[1], data.dtype, count=n, offset=arr.offset * item)
+        if valid is not None:
+            data[sl][~valid] = 0
     return data, validity, lengths

@@ -1,10 +1,11 @@
 """Minimal Thrift Compact Protocol reader/writer.
 
 Parquet metadata (FileMetaData, PageHeader, ...) is thrift-compact
-encoded; this is the self-contained codec for blaze_tpu.io.parquet
-(the image carries no pyarrow/thrift).  Implements the subset the
-parquet structures use: structs, i16/i32/i64 (zigzag varints), binary,
-bool, double, lists.
+encoded; this is the self-contained codec for blaze_tpu.io.parquet,
+which parses every footer and, where its own page decoder runs, every
+page header with it (no thrift library; pyarrow is optional there).
+Implements the subset the parquet structures use: structs, i16/i32/i64
+(zigzag varints), binary, bool, double, lists.
 """
 
 from __future__ import annotations

@@ -183,11 +183,14 @@ class ParquetScanExec(ExecNode):
         def stream():
             for entry in files:
                 path = entry_path(entry)
-                # one task's open of one file: its footer, and which of
-                # its row groups are this entry's
+                # one task's open of one file: its footer, which of its
+                # row groups are this entry's, and Arrow's reader over it
+                # where pyarrow imports (closed with the entry: no handle,
+                # footer or decoded array outlives its task)
                 with trace.span("scan_open"):
                     try:
                         row_groups = pq.read_metadata(path).row_groups
+                        arrow_file = pq.open_arrow_file(path, self._schema.fields, row_groups)
                     except Exception:
                         if bool(conf.IGNORE_CORRUPT_FILES.get()):
                             self.metrics.add("skipped_corrupt_files", 1)
@@ -196,79 +199,88 @@ class ParquetScanExec(ExecNode):
                     mine = split_row_groups(entry, row_groups)
                 dispatch.record("scan_splits")
                 dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
-                for rg in mine:
-                    if rg.rows == 0:
-                        continue
-                    pruned = False
-                    for name, op, lit_v in self._conjuncts:
-                        ch = rg.chunks.get(name)
-                        if ch is None:
-                            continue
-                        fld = next((f for f in self._schema.fields if f.name == name), None)
-                        if fld is None:
-                            # predicate column pruned from the read
-                            # schema: stats pruning just skips it
-                            continue
-                        if not _maybe_match(ch, fld.dtype, op, lit_v):
-                            pruned = True
-                            break
-                    if pruned:
-                        self.metrics.add("pruned_row_groups", 1)
-                        self.metrics.add("pruned_rows", rg.rows)
-                        dispatch.record("scan_row_groups_pruned")
-                        continue
-                    # one row group's fetch + decompress + decode, every
-                    # column decoded straight into arrays of the row
-                    # group's capacity; once a row group, in the producer
-                    # thread where the scan is pipelined
-                    file_bytes = 0
-                    pages = collections.Counter()
-                    with self.metrics.timer("input_io_time", trace.span("scan_decode")):
-                        cap = bucket_capacity(rg.rows)
-                        cols: List[Column] = []
-                        for f in self._schema.fields:
-                            ch = rg.chunks.get(f.name)
-                            if ch is None:
-                                # schema adaption: missing column -> null
-                                cols.append(self._null_column(f.dtype, cap))
-                                continue
-                            data, validity, lengths = pq.read_column_chunk(
-                                path, ch, f.dtype, capacity=cap, tally=pages)
-                            file_bytes += ch.total_comp
-                            cols.append(Column(f.dtype, data, validity, lengths))
-                    dispatch.record("scan_file_bytes", file_bytes)
-                    dispatch.record("scan_row_groups")
-                    dispatch.record("scan_pages", pages["pages"])
-                    dispatch.record("scan_pages_python_codec", pages["pages_python_codec"])
-                    # emit in batch_rows slices to bound device batches
-                    full = RecordBatch(self._schema, cols, rg.rows)
-                    if rg.rows <= self.batch_rows:
-                        self.metrics.add("output_rows", rg.rows)
-                        yield full
-                    else:
-                        host = full
-                        for s in range(0, rg.rows, self.batch_rows):
-                            e = min(s + self.batch_rows, rg.rows)
-                            scap = bucket_capacity(e - s)
-                            sl: List[Column] = []
-                            for c in host.columns:
-                                d = np.asarray(c.data)[s:e]
-                                sl.append(
-                                    Column(
-                                        c.dtype,
-                                        _pad_1d(np.ascontiguousarray(d), scap),
-                                        _pad_1d(np.asarray(c.validity)[s:e], scap),
-                                        None
-                                        if c.lengths is None
-                                        else _pad_1d(np.asarray(c.lengths)[s:e], scap),
-                                    )
-                                )
-                            b = RecordBatch(self._schema, sl, e - s)
-                            self._record_batch(b)
-                            yield b
+                try:
+                    yield from self._row_group_batches(path, mine, arrow_file)
+                finally:
+                    if arrow_file is not None:
+                        arrow_file.close(force=True)
 
         from ..runtime.pipeline import maybe_pipelined
 
         # file decode overlaps downstream device compute (≙ rt.rs:100-133)
         return maybe_pipelined(self._staged(stream()), ctx, "parquet_scan")
 
+    def _row_group_batches(self, path: str, row_groups: Sequence[pq.RowGroupMeta], arrow_file):
+        """The batches of one entry's row groups, each decoded once."""
+        for rg in row_groups:
+            if rg.rows == 0:
+                continue
+            pruned = False
+            for name, op, lit_v in self._conjuncts:
+                ch = rg.chunks.get(name)
+                if ch is None:
+                    continue
+                fld = next((f for f in self._schema.fields if f.name == name), None)
+                if fld is None:
+                    # predicate column pruned from the read
+                    # schema: stats pruning just skips it
+                    continue
+                if not _maybe_match(ch, fld.dtype, op, lit_v):
+                    pruned = True
+                    break
+            if pruned:
+                self.metrics.add("pruned_row_groups", 1)
+                self.metrics.add("pruned_rows", rg.rows)
+                dispatch.record("scan_row_groups_pruned")
+                continue
+            # one row group's fetch + decompress + decode, every
+            # column straight into arrays of the row group's capacity:
+            # through Arrow's reader in one call outside the GIL, then
+            # converted whole, where ``arrow_file`` is there and takes
+            # the chunk, page by page in this module's decoder else;
+            # once a row group, in the producer thread where the scan
+            # is pipelined
+            decoded = collections.Counter()
+            with self.metrics.timer("input_io_time", trace.span("scan_decode")):
+                cap = bucket_capacity(rg.rows)
+                fields = self._schema.fields
+                chunks = pq.read_row_group(path, rg, fields, cap,
+                                           arrow_file=arrow_file, tally=decoded)
+                # schema adaption: missing column -> null
+                cols: List[Column] = [
+                    self._null_column(f.dtype, cap) if arrays is None else Column(f.dtype, *arrays)
+                    for f, arrays in zip(fields, chunks)]
+            dispatch.record("scan_file_bytes", sum(
+                rg.chunks[f.name].total_comp for f in fields if f.name in rg.chunks))
+            dispatch.record("scan_row_groups")
+            dispatch.record("scan_chunks", decoded["chunks"])
+            dispatch.record("scan_chunks_native", decoded["chunks_native"])
+            # pages the page decoder walked: none where Arrow took every chunk
+            dispatch.record("scan_pages", decoded["pages"])
+            dispatch.record("scan_pages_python_codec", decoded["pages_python_codec"])
+            # emit in batch_rows slices to bound device batches
+            full = RecordBatch(self._schema, cols, rg.rows)
+            if rg.rows <= self.batch_rows:
+                self.metrics.add("output_rows", rg.rows)
+                yield full
+            else:
+                host = full
+                for s in range(0, rg.rows, self.batch_rows):
+                    e = min(s + self.batch_rows, rg.rows)
+                    scap = bucket_capacity(e - s)
+                    sl: List[Column] = []
+                    for c in host.columns:
+                        d = np.asarray(c.data)[s:e]
+                        sl.append(
+                            Column(
+                                c.dtype,
+                                _pad_1d(np.ascontiguousarray(d), scap),
+                                _pad_1d(np.asarray(c.validity)[s:e], scap),
+                                None
+                                if c.lengths is None
+                                else _pad_1d(np.asarray(c.lengths)[s:e], scap),
+                            )
+                        )
+                    b = RecordBatch(self._schema, sl, e - s)
+                    self._record_batch(b)
+                    yield b

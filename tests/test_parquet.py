@@ -4,6 +4,7 @@ pruning, sink with dynamic partitioning.
 ≙ the reference's parquet path (parquet_exec.rs scan + page filtering,
 parquet_sink_exec.rs incl. hive dynamic partitions)."""
 
+import collections
 import datetime
 import glob
 import os
@@ -11,10 +12,10 @@ import os
 import numpy as np
 import pytest
 
-from blaze_tpu.batch import batch_from_pydict, batch_to_pydict, concat_batches
+from blaze_tpu.batch import batch_from_pydict, batch_to_pydict, bucket_capacity, concat_batches
 from blaze_tpu.exprs import col, lit
 from blaze_tpu.io import parquet as pq
-from blaze_tpu.ops import MemoryScanExec, ParquetScanExec, ParquetSinkExec
+from blaze_tpu.ops import FileSplit, MemoryScanExec, ParquetScanExec, ParquetSinkExec
 from blaze_tpu.runtime.context import TaskContext
 from blaze_tpu.schema import DataType, Field, Schema
 
@@ -300,14 +301,12 @@ def test_pyarrow_index_pages_are_504_value_runs(tmp_path):
     assert validity.all() and (data == values).all()
 
 
-@pytest.mark.parametrize("page_version", ["1.0", "2.0"])
-def test_definition_levels_with_nulls(tmp_path, page_version):
+def _nulls_file(tmp_path, page_version, n=30_000):
     """Nulls scattered (bit-packed levels) and in long stretches (RLE
     levels), v1 (u32-prefixed, compressed) and v2 (bare, uncompressed)."""
     pa = pytest.importorskip("pyarrow")
     import pyarrow.parquet as papq
 
-    n = 30_000
     rng = np.random.RandomState(9)
     valid = rng.rand(n) > 0.045          # tpcds's 4.5%-NULL foreign keys
     valid[5_000:9_000] = False
@@ -316,6 +315,14 @@ def test_definition_levels_with_nulls(tmp_path, page_version):
     path = str(tmp_path / f"nulls{page_version}.parquet")
     papq.write_table(pa.table({"k": pa.array(keys, pa.int64(), mask=~valid)}), path,
                      compression="snappy", data_page_version=page_version, data_page_size=16 << 10)
+    return path, keys, valid
+
+
+@pytest.mark.parametrize("page_version", ["1.0", "2.0"])
+def test_definition_levels_with_nulls(tmp_path, page_version):
+    import pyarrow.parquet as papq
+
+    path, keys, valid = _nulls_file(tmp_path, page_version)
     chunk = pq.read_metadata(path).row_groups[0].chunks["k"]
     data, validity, _ = pq.read_column_chunk(path, chunk, DataType.int64())
     assert (validity == valid).all()
@@ -437,16 +444,21 @@ def test_capacity_pads_what_the_chunk_holds(tmp_path):
         pq.read_column_chunk(path, rg.chunks["day"], DataType.date32(), capacity=rg.rows - 1)
 
 
-@pytest.mark.parametrize("library", [True, False])
-def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, library):
-    """One scan_decode span a row group, every page of every chunk in
-    scan_pages, and scan_pages_python_codec only where no library is."""
+@pytest.mark.parametrize("decoder", ["arrow_reader", "page_decoder_snappy_library",
+                                     "page_decoder_pure_python"])
+def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, decoder):
+    """One scan_decode span a row group and every chunk in scan_chunks;
+    scan_chunks_native those Arrow's reader decoded, scan_pages the
+    pages the page decoder walked (none where Arrow took every chunk),
+    scan_pages_python_codec those only where no snappy library is."""
     import pyarrow.parquet as papq
 
     from blaze_tpu.runtime import dispatch
 
     path, schema, cols = _mixed_file(tmp_path)
-    if not library:
+    if decoder != "arrow_reader":
+        monkeypatch.setattr(pq, "_arrow_reader", lambda: None)
+    if decoder == "page_decoder_pure_python":
         monkeypatch.setattr(pq, "_snappy_library", lambda: None)
     md = papq.ParquetFile(path).metadata
     scan = ParquetScanExec([[path]], schema, batch_rows=8192)
@@ -459,8 +471,11 @@ def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, library
         for r in range(2) for i in range(len(schema.fields)))
     pages = sum(1 for rg in pq.read_metadata(path).row_groups
                 for ch in rg.chunks.values() for _ in _chunk_pages(path, ch))
-    assert c["scan_pages"] == pages > 20
-    assert c.get("scan_pages_python_codec", 0) == (0 if library else pages)
+    assert pages > 20
+    assert c["scan_chunks"] == 2 * len(schema.fields) == 10
+    assert c["scan_chunks_native"] == (10 if decoder == "arrow_reader" else 0)
+    assert c["scan_pages"] == (0 if decoder == "arrow_reader" else pages)
+    assert c["scan_pages_python_codec"] == (pages if decoder == "page_decoder_pure_python" else 0)
 
 
 def test_scan_batches_equal_the_decoded_row_group(tmp_path):
@@ -472,3 +487,259 @@ def test_scan_batches_equal_the_decoded_row_group(tmp_path):
     assert got["maybe"] == [int(v) if ok else None for v, ok in zip(cols["maybe"][0], cols["maybe"][1])]
     assert got["wide"] == cols["wide"][0].tolist()
     assert got["day"] == cols["day"][0].tolist()
+
+
+# --------------------------------------------------------------------
+# Arrow's column reader behind read_row_group (PR 37): whatever it
+# decodes equals the page decoder's arrays bit for bit, and whatever it
+# does not take goes through the page decoder, chunk by chunk, counted.
+
+def decoders_agree(path, schema, capacity=None):
+    """Every row group of ``path`` through read_row_group twice — the
+    page decoder alone, then with Arrow's reader — equal in dtype, shape
+    and every byte, padding included.  Returns the second run's tally."""
+    meta = pq.read_metadata(path)
+    arrow_file = pq.open_arrow_file(path, schema.fields, meta.row_groups)
+    assert arrow_file is not None
+    tally = collections.Counter()
+    try:
+        for rg in meta.row_groups:
+            cap = capacity or bucket_capacity(rg.rows)
+            ours = pq.read_row_group(path, rg, schema.fields, cap)
+            theirs = pq.read_row_group(path, rg, schema.fields, cap, arrow_file=arrow_file, tally=tally)
+            for f, mine, arrows in zip(schema.fields, ours, theirs):
+                assert (mine is None) == (arrows is None) == (f.name not in rg.chunks), f.name
+                for a, b in zip(mine or (), arrows or ()):
+                    assert (a is None) == (b is None), f.name
+                    if a is not None:
+                        assert a.dtype == b.dtype and a.shape == b.shape == (cap,) + a.shape[1:], f.name
+                        assert a.tobytes() == b.tobytes(), f.name
+    finally:
+        arrow_file.close(force=True)
+    return tally
+
+
+def _typed_file(tmp_path, **writer):
+    """Every type the reader maps, with NULLs in each, in two row groups."""
+    pa = pytest.importorskip("pyarrow")
+    import decimal
+
+    import pyarrow.parquet as papq
+
+    n = 3_000
+    rng = np.random.RandomState(37)
+    ints = rng.randint(-100, 100, n)
+    mask = rng.rand(n) < 0.2
+    words = ["", "a", "bb", "delivered", "x" * 8, "ÿ"]
+    arrays = {
+        "b": pa.array(ints % 3 == 0, pa.bool_(), mask=mask),
+        "i8": pa.array(ints, pa.int8(), mask=mask),
+        "i16": pa.array(ints * 300, pa.int16(), mask=mask),
+        "i32": pa.array(ints * 70_000, pa.int32(), mask=mask),
+        "i64": pa.array(ints.astype(np.int64) << 40, pa.int64(), mask=mask),
+        "f32": pa.array(ints / 7, pa.float32(), mask=mask),
+        "f64": pa.array(np.where(ints == 5, np.nan, ints / 3), pa.float64(), mask=mask),
+        "day": pa.array(ints + 9_000, pa.int32(), mask=mask).cast(pa.date32()),
+        "ts": pa.array(ints.astype(np.int64) * 10**9, pa.timestamp("us"), mask=mask),
+        "ts_utc": pa.array(ints.astype(np.int64) * 10**9, pa.timestamp("us", tz="UTC"), mask=mask),
+        "dec": pa.array([None if m else decimal.Decimal(int(v)).scaleb(-2) for v, m in zip(ints * 10**9, mask)],
+                        pa.decimal128(12, 2)),
+        "s": pa.array([None if m else words[v % len(words)] for v, m in zip(ints, mask)], pa.string()),
+        "bin": pa.array([None if m else words[v % len(words)].encode() for v, m in zip(ints, mask)],
+                        pa.binary()),
+        "big_s": pa.array([None if m else words[v % len(words)] for v, m in zip(ints, mask)],
+                          pa.large_string()),
+    }
+    schema = Schema([
+        Field("b", DataType.bool_()), Field("i8", DataType.int8()), Field("i16", DataType.int16()),
+        Field("i32", DataType.int32()), Field("i64", DataType.int64()), Field("f32", DataType.float32()),
+        Field("f64", DataType.float64()), Field("day", DataType.date32()),
+        Field("ts", DataType.timestamp()), Field("ts_utc", DataType.timestamp()),
+        Field("dec", DataType.decimal(12, 2)), Field("s", DataType.string(16)),
+        Field("bin", DataType.binary(8)), Field("big_s", DataType.string(8)),
+    ])
+    path = str(tmp_path / "typed.parquet")
+    papq.write_table(pa.table(arrays), path, row_group_size=2_000, data_page_size=4 << 10,
+                     store_decimal_as_integer=True, **writer)
+    return path, schema
+
+
+def _long_strings_file(tmp_path, use_dictionary):
+    """Strings up to 40 bytes, some NULL, one row group: wider than any
+    width they are read at, PLAIN or dictionary-encoded."""
+    pa = pytest.importorskip("pyarrow")
+    import pyarrow.parquet as papq
+
+    rng = np.random.RandomState(8)
+    values = [None if i % 11 == 0 else "k%d-" % (i % 50) + "z" * int(rng.randint(0, 36)) for i in range(5_000)]
+    path = str(tmp_path / f"long{use_dictionary}.parquet")
+    papq.write_table(pa.table({"s": pa.array(values, pa.string())}), path,
+                     use_dictionary=use_dictionary, data_page_size=8 << 10)
+    return path, values
+
+
+def _own_writer_file(tmp_path, codec):
+    path = str(tmp_path / f"own{codec}.parquet")
+    pq.write_parquet(path, SCHEMA, _cols(500), row_group_rows=200, codec=codec)
+    return path, SCHEMA
+
+
+CORPUS = {
+    # the cell's own shapes; the second row group holds 15,000 rows at capacity 16,384
+    "mixed": lambda tmp: _mixed_file(tmp)[:2],
+    "dictionary_int64": lambda tmp: (_dictionary_file(tmp)[0], Schema([Field("v", DataType.int64())])),
+    "nulls_v1": lambda tmp: (_nulls_file(tmp, "1.0")[0], Schema([Field("k", DataType.int64())])),
+    "nulls_v2": lambda tmp: (_nulls_file(tmp, "2.0")[0], Schema([Field("k", DataType.int64())])),
+    "typed_dictionary": lambda tmp: _typed_file(tmp, use_dictionary=True, compression="snappy"),
+    "typed_plain_v2_zstd": lambda tmp: _typed_file(tmp, use_dictionary=False, compression="zstd",
+                                                   data_page_version="2.0"),
+    "typed_gzip": lambda tmp: _typed_file(tmp, use_dictionary=True, compression="gzip"),
+    "typed_uncompressed": lambda tmp: _typed_file(tmp, use_dictionary=False, compression="NONE"),
+    "own_writer_snappy": lambda tmp: _own_writer_file(tmp, pq.CODEC_SNAPPY),
+    "own_writer_gzip": lambda tmp: _own_writer_file(tmp, pq.CODEC_GZIP),
+    "own_writer_zstd": lambda tmp: _own_writer_file(tmp, pq.CODEC_ZSTD),
+    "own_writer_lz4_raw": lambda tmp: _own_writer_file(tmp, pq.CODEC_LZ4_RAW),
+    "own_writer_uncompressed": lambda tmp: _own_writer_file(tmp, pq.CODEC_UNCOMPRESSED),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CORPUS))
+def test_arrow_reader_equals_the_page_decoder(tmp_path, case):
+    path, schema = CORPUS[case](tmp_path)
+    tally = decoders_agree(path, schema)
+    chunks = sum(len(rg.chunks) for rg in pq.read_metadata(path).row_groups)
+    # every chunk of these files pairs with its requested type: none is left to the page decoder
+    assert tally["chunks_native"] == tally["chunks"] == chunks > 0
+    assert tally["pages"] == 0
+
+
+@pytest.mark.parametrize("use_dictionary", [True, False])
+@pytest.mark.parametrize("width", [4, 8, 12, 64])
+def test_strings_longer_than_the_width_are_cut_the_same(tmp_path, width, use_dictionary):
+    """A word a row where the width is a word's (4, 8), opaque rows
+    where it is not (12), and a width no value reaches (64)."""
+    path, values = _long_strings_file(tmp_path, use_dictionary)
+    schema = Schema([Field("s", DataType.string(width))])
+    tally = decoders_agree(path, schema, capacity=8_192)  # the row group holds 5,000
+    assert tally["chunks_native"] == tally["chunks"] == 1
+    rg = pq.read_metadata(path).row_groups[0]
+    arrow_file = pq.open_arrow_file(path, schema.fields, [rg])
+    try:
+        ((data, validity, lengths),) = pq.read_row_group(path, rg, schema.fields, 8_192, arrow_file=arrow_file)
+    finally:
+        arrow_file.close(force=True)
+    assert data.shape == (8_192, width) and not data[5_000:].any() and not validity[5_000:].any()
+    got = [bytes(data[i, : lengths[i]]) if validity[i] else None for i in range(5_000)]
+    assert got == [None if v is None else v.encode()[:width] for v in values]
+
+
+def test_a_capacity_under_the_row_group_is_refused(tmp_path):
+    path, schema, _ = _mixed_file(tmp_path)
+    rg = pq.read_metadata(path).row_groups[1]
+    with pytest.raises(ValueError, match="capacity"):
+        pq.read_row_group(path, rg, schema.fields, rg.rows - 1)
+
+
+def test_a_failed_arrow_read_leaves_the_row_group_to_the_page_decoder(tmp_path):
+    """What Arrow's reader refuses, the page decoder reads — or says
+    what is wrong with."""
+    pa = pytest.importorskip("pyarrow")
+
+    class Refusing:
+        def read_row_group(self, *args, **kwargs):
+            raise pa.ArrowNotImplementedError("not this encoding")
+
+    path, schema, _ = _mixed_file(tmp_path)
+    tally = collections.Counter()
+    for rg in pq.read_metadata(path).row_groups:
+        cap = bucket_capacity(rg.rows)
+        ours = pq.read_row_group(path, rg, schema.fields, cap)
+        fell_back = pq.read_row_group(path, rg, schema.fields, cap, arrow_file=Refusing(), tally=tally)
+        assert all(a.tobytes() == b.tobytes() for x, y in zip(ours, fell_back) for a, b in zip(x, y)
+                   if a is not None)
+    assert tally["chunks"] == 10 and tally["chunks_native"] == 0 and tally["pages"] > 20
+
+
+def _scan_arrays(scan):
+    """Every batch of every partition: (rows, each column's buffers as bytes)."""
+    out = []
+    for p in range(scan.num_partitions()):
+        for b in scan.execute(p, TaskContext(p, scan.num_partitions())):
+            out.append((b.num_rows, [None if a is None else np.asarray(a).tobytes()
+                                     for c in b.columns for a in (c.data, c.validity, c.lengths)]))
+    return out
+
+
+@pytest.mark.parametrize("entries", ["whole_file", "byte_ranges"])
+def test_a_scan_without_the_library_gives_the_same_batches(tmp_path, monkeypatch, entries):
+    """The chooser returning None is today's scan: the same batches, byte
+    for byte, from a path and from the FileSplit ranges of one file."""
+    from blaze_tpu.runtime import dispatch
+
+    path, schema, _ = _mixed_file(tmp_path)
+    wider = Schema(list(schema.fields) + [Field("absent", DataType.string(8))])
+    if entries == "whole_file":
+        groups = [[path]]
+    else:  # one row group a range: the second starts at the second one's midpoint
+        cut = pq.read_metadata(path).row_groups[1].midpoint
+        groups = [[FileSplit(path, 0, cut)], [FileSplit(path, cut, os.path.getsize(path) - cut)]]
+    with dispatch.capture() as c:
+        native = _scan_arrays(ParquetScanExec(groups, wider, batch_rows=8192))
+    assert c["scan_chunks_native"] == c["scan_chunks"] == 10 and c["scan_pages"] == 0
+    monkeypatch.setattr(pq, "_arrow_reader", lambda: None)
+    with dispatch.capture() as c:
+        plain = _scan_arrays(ParquetScanExec(groups, wider, batch_rows=8192))
+    assert c["scan_chunks"] == 10 and c["scan_chunks_native"] == 0 and c["scan_pages"] > 20
+    assert native == plain and sum(rows for rows, _ in native) == 40_000
+
+
+def test_a_scan_closes_what_it_opened(tmp_path, monkeypatch):
+    """Arrow's reader and the file under it go with the entry, also where
+    the consumer stops early: nothing of a file outlives its task."""
+    path, schema, _ = _mixed_file(tmp_path)
+    opened = []
+    open_arrow_file = pq.open_arrow_file
+    monkeypatch.setattr(pq, "open_arrow_file", lambda *a: opened.append(open_arrow_file(*a)) or opened[-1])
+    monkeypatch.setattr("blaze_tpu.conf.PIPELINE_DEPTH.get", lambda: 0)  # the stream itself, no producer thread
+    scan = ParquetScanExec([[path], [path]], schema, batch_rows=8192)
+    assert sum(b.num_rows for b in scan.execute(0, TaskContext(0, 2))) == 40_000
+    stream = scan.execute(1, TaskContext(1, 2))
+    next(stream)
+    stream.close()
+    assert len(opened) == 2 and all(f.closed for f in opened)
+
+
+def test_arrow_outlives_the_producer_threads_of_a_process_first_scans(tmp_path):
+    """A process whose first use of pyarrow is a scan: each scan's
+    producer thread ends with its task, and Arrow must still read on the
+    next one (pyarrow is imported with io/parquet, on the importing
+    thread: imported first by a thread that ends, it segfaults the next
+    thread that reads a file through it)."""
+    import subprocess
+    import sys
+
+    script = f"""
+import sys
+import numpy as np
+from blaze_tpu.io import parquet as pq
+from blaze_tpu.ops import ParquetScanExec
+from blaze_tpu.runtime.context import TaskContext
+from blaze_tpu.schema import DataType, Field, Schema
+
+schema = Schema([Field("k", DataType.int64()), Field("s", DataType.string(8))])
+words = np.zeros((300, 8), np.uint8)
+words[:, 0] = 97 + np.arange(300) % 3
+rows = 0
+for i in range(3):
+    path = {str(tmp_path)!r} + "/f%d.parquet" % i
+    pq.write_parquet(path, schema, {{"k": (np.arange(300), None, None),
+                                    "s": (words, None, np.ones(300, np.int32))}}, row_group_rows=100)
+    scan = ParquetScanExec([[path]], schema)  # pipelined: a producer thread a task
+    rows += sum(b.num_rows for b in scan.execute(0, TaskContext(0, 1)))
+import pyarrow.parquet
+rows += pyarrow.parquet.read_table(path).num_rows
+print("rows", rows)
+"""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    done = subprocess.run([sys.executable, "-c", script], env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0 and done.stdout.split()[-2:] == ["rows", "1200"], done.stderr[-2000:]

@@ -17,9 +17,12 @@ import pytest
 
 from blaze_tpu.batch import batch_to_pydict, concat_batches
 from blaze_tpu.exprs import col, lit
+from blaze_tpu.io import parquet as pq
 from blaze_tpu.ops import MemoryScanExec, ParquetScanExec
+from blaze_tpu.runtime import dispatch
 from blaze_tpu.runtime.context import TaskContext
 from blaze_tpu.schema import DataType, Field, Schema
+from test_parquet import decoders_agree
 
 N = 500
 
@@ -92,20 +95,19 @@ def _assert_equal(got, exp):
             assert g == want, f"column {k}"
 
 
-@pytest.mark.parametrize(
-    "codec,dictionary,page_version",
-    [
-        ("snappy", True, "1.0"),
-        ("snappy", False, "1.0"),
-        ("zstd", True, "1.0"),
-        ("gzip", True, "1.0"),
-        ("none", True, "1.0"),
-        ("snappy", True, "2.0"),
-        ("zstd", False, "2.0"),
-        ("lz4", True, "1.0"),
-    ],
-)
-def test_pyarrow_roundtrip(tmp_path, codec, dictionary, page_version):
+WRITERS = [
+    ("snappy", True, "1.0"),
+    ("snappy", False, "1.0"),
+    ("zstd", True, "1.0"),
+    ("gzip", True, "1.0"),
+    ("none", True, "1.0"),
+    ("snappy", True, "2.0"),
+    ("zstd", False, "2.0"),
+    ("lz4", True, "1.0"),
+]
+
+
+def _write(tmp_path, codec, dictionary, page_version):
     table = _table()
     path = tmp_path / f"t_{codec}_{dictionary}_{page_version}.parquet"
     papq.write_table(
@@ -117,8 +119,65 @@ def test_pyarrow_roundtrip(tmp_path, codec, dictionary, page_version):
         data_page_size=1024,           # many small pages per chunk
         write_statistics=True,
     )
+    return table, path
+
+
+@pytest.mark.parametrize("codec,dictionary,page_version", WRITERS)
+def test_pyarrow_roundtrip(tmp_path, codec, dictionary, page_version):
+    table, path = _write(tmp_path, codec, dictionary, page_version)
     got, _ = _read_ours(path)
     _assert_equal(got, _expected(table))
+
+
+@pytest.mark.parametrize("codec,dictionary,page_version", WRITERS)
+def test_arrow_reader_equals_the_page_decoder(tmp_path, codec, dictionary, page_version):
+    """Six of the seven columns come through Arrow's reader, bit for bit
+    what the page decoder gives; ``dec`` is FIXED_LEN_BYTE_ARRAY here
+    (pyarrow's default for a decimal) and stays the page decoder's."""
+    _, path = _write(tmp_path, codec, dictionary, page_version)
+    tally = decoders_agree(str(path), SCHEMA)
+    assert (tally["chunks"], tally["chunks_native"]) == (3 * 7, 3 * 6)
+    assert tally["pages"] >= 3  # the three dec chunks' pages
+
+
+def _left_to_the_page_decoder(case):
+    """(table as pyarrow writes it, writer options, the schema asked for,
+    the values expected) for a chunk Arrow's reader is not given or
+    whose Arrow type is not the requested one."""
+    micros = [1_600_000_000_000_000 + 1_000_003 * i for i in range(300)]
+    if case == "int96_timestamp":
+        return (pa.table({"c": pa.array(micros, pa.timestamp("us"))}),
+                dict(use_deprecated_int96_timestamps=True), DataType.timestamp(), micros)
+    if case == "flba_decimal":
+        unscaled = [i * 12_345 - 900_000 for i in range(300)]
+        return (pa.table({"c": pa.array([decimal.Decimal(v).scaleb(-2) for v in unscaled],
+                                        pa.decimal128(12, 2))}), {}, DataType.decimal(12, 2), unscaled)
+    if case == "int32_read_as_int64":  # schema adaption: the file's type widens to the requested one
+        values = list(range(-150, 150))
+        return pa.table({"c": pa.array(values, pa.int32())}), {}, DataType.int64(), values
+    if case == "decimal_of_another_scale":  # the unscaled integer, whatever the file calls its scale
+        unscaled = [i * 7 for i in range(300)]
+        return (pa.table({"c": pa.array([decimal.Decimal(v).scaleb(-4) for v in unscaled],
+                                        pa.decimal128(12, 4))}),
+                dict(store_decimal_as_integer=True), DataType.decimal(12, 2), unscaled)
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case", ["int96_timestamp", "flba_decimal", "int32_read_as_int64",
+                                  "decimal_of_another_scale"])
+def test_what_arrow_does_not_take_goes_through_the_page_decoder(tmp_path, case):
+    table, writer, dtype, want = _left_to_the_page_decoder(case)
+    table = table.append_column("k", pa.array(range(300), pa.int64()))  # a chunk Arrow does take, beside it
+    path = str(tmp_path / f"{case}.parquet")
+    papq.write_table(table, path, row_group_size=100, **writer)
+    schema = Schema([Field("c", dtype), Field("k", DataType.int64())])
+    tally = decoders_agree(path, schema)
+    assert (tally["chunks"], tally["chunks_native"]) == (6, 3) and tally["pages"] >= 3
+    with dispatch.capture() as c:
+        got, _ = _read_ours_with_schema(path, schema)
+    assert got == {"c": want, "k": list(range(300))}
+    assert (c["scan_chunks"], c["scan_chunks_native"]) == (6, 3)
+    assert c["scan_pages"] == tally["pages"] and c["scan_row_groups"] == 3
 
 
 def test_required_columns(tmp_path):
@@ -129,10 +188,14 @@ def test_required_columns(tmp_path):
     )
     path = tmp_path / "req.parquet"
     papq.write_table(table, path, compression="snappy")
-    scan = ParquetScanExec([[str(path)]], Schema([Field("r", DataType.int64())]))
+    schema = Schema([Field("r", DataType.int64())])
+    scan = ParquetScanExec([[str(path)]], schema)
     out = list(scan.execute(0, TaskContext(0, 1)))
     d = batch_to_pydict(concat_batches(out))
     assert d["r"] == list(range(50))
+    tally = decoders_agree(str(path), schema)  # no definition levels for either decoder
+    assert tally["chunks_native"] == tally["chunks"] == 1
+    assert pq.read_metadata(str(path)).row_groups[0].chunks["r"].max_def == 0
 
 
 def test_row_group_pruning_on_real_file(tmp_path):
