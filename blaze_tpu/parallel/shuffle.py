@@ -638,11 +638,14 @@ def _insert_host(rep: "ShuffleRepartitioner", schema: Schema, item) -> None:
     num_rows None means "resolve from counts" (the fused write path:
     the live row count after the fused chain IS the counts total)."""
     cols, counts, n = item
-    with trace.span("device_read"):
+    with trace.span("device_read") as read:
         counts = np.asarray(counts)
         if n is None:
             n = int(counts.sum())
         host = RecordBatch(schema, list(cols), n).to_host()
+    # this read's part of the enclosing exchange_write: the wait for the
+    # map body's program plus the transfer
+    dispatch.record("exchange_d2h_ns", read.ns)
     rep.insert_sorted(host, counts)
 
 
@@ -655,7 +658,16 @@ class _AsyncInserter:
     capped; staging errors surface on the producer at the next put()
     or at close().  The repartitioner's own lock makes insert_sorted
     safe against concurrent memmgr spills, so commit-by-rename
-    semantics in write_output are untouched."""
+    semantics in write_output are untouched.
+
+    The caller's thread says when it waited for the stager: a
+    ``trace.span("inserter_full")`` around a ``put()`` that found the
+    queue full, ``trace.span("inserter_drain")`` around ``close()``'s
+    flush and join, and ``inserter_items`` (batches put, one record at
+    ``close()``, whether or not a put blocked).  The stager's own
+    ``exchange_write`` spans lie inside a ``blaze:exchange_stager``
+    annotation that carries the ``ids`` (the task's ``stage`` /
+    ``partition``) of the writer that created it."""
 
     _DONE = object()
 
@@ -671,10 +683,12 @@ class _AsyncInserter:
     }
 
     def __init__(self, rep: "ShuffleRepartitioner", schema: Schema,
-                 depth: int, metrics):
+                 depth: int, metrics, **ids):
         self._rep = rep
         self._schema = schema
         self._metrics = metrics
+        self._ids = ids
+        self._items = 0  # the producer's alone
         self._q: "queue.Queue" = queue.Queue(max(1, depth))
         self._errs: List[BaseException] = []
         self._aborted = False
@@ -693,29 +707,37 @@ class _AsyncInserter:
         self._thread.start()
 
     def _drain(self) -> None:
-        while True:
-            item = self._q.get()
-            if item is _AsyncInserter._DONE:
-                return
-            if self._errs or self._aborted:
-                continue  # task failing/cancelled: discard, don't stage
-            try:
-                with self._metrics.timer("shuffle_host_stage_time",
-                                         trace.span("exchange_write")):
-                    _insert_host(self._rep, self._schema, item)
-            except BaseException as e:  # noqa: BLE001 — surfaced to producer
-                self._errs.append(e)
+        with trace.annotation("exchange_stager", **self._ids):
+            while True:
+                item = self._q.get()
+                if item is _AsyncInserter._DONE:
+                    return
+                if self._errs or self._aborted:
+                    continue  # task failing/cancelled: discard, don't stage
+                try:
+                    with self._metrics.timer("shuffle_host_stage_time",
+                                             trace.span("exchange_write")):
+                        _insert_host(self._rep, self._schema, item)
+                except BaseException as e:  # noqa: BLE001 — surfaced to producer
+                    self._errs.append(e)
 
     def put(self, item) -> None:
         if self._errs:
             raise self._errs[0]
-        self._q.put(item)
+        self._items += 1
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:
+            with trace.span("inserter_full"):
+                self._q.put(item)
 
     def close(self) -> None:
         """Flush and join; re-raises any staging error — MUST happen
         before write_output so every inserted batch reaches the file."""
-        self._q.put(self._DONE)
-        self._thread.join()
+        with trace.span("inserter_drain"):
+            self._q.put(self._DONE)
+            self._thread.join()
+        dispatch.record("inserter_items", self._items)
         if self._errs:
             raise self._errs[0]
 
@@ -970,6 +992,7 @@ class ShuffleWriterExec(ExecNode):
                     inserter = _AsyncInserter(
                         rep, out_schema,
                         int(conf.SHUFFLE_ASYNC_QUEUE_DEPTH.get()), self.metrics,
+                        stage=ctx.stage_id, partition=ctx.partition,
                     )
                     # two-slot device staging ring: batch N's pid-sorted
                     # output stays device-resident while batch N+1's

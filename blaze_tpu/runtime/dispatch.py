@@ -18,7 +18,18 @@ tree said so.  Every jitted operator kernel (they all register through
                          kernel capture's ``compile_ns`` instead),
 - ``<span>_ns``/``<span>_n`` — host time and openings of every
                          ``trace.span`` (:func:`record_span`), with the
-                         bytes ``h2d_bytes``/``shuffle_bytes_written``,
+                         bytes ``h2d_bytes``/``shuffle_bytes_written``.
+                         The wait spans of the two hand-off queues are
+                         among them: ``pipeline_wait``/``pipeline_full``
+                         (runtime/pipeline.py) and ``inserter_full``/
+                         ``inserter_drain`` (parallel/shuffle.py),
+- ``pipeline_items``/``pipeline_producer_ns`` — items a pipelined
+                         stream handed over and its producer thread's
+                         life, one record a stream each;
+                         ``inserter_items`` — batches a map task put to
+                         its exchange stager, one record a task;
+                         ``exchange_d2h_ns`` — the exchange writer's
+                         ``device_read``, a part of ``exchange_write_ns``,
 - ``fused_stage_len``  — LONGEST fused segment built (a max-gauge via
                          :func:`record_max`, recorded by ``ops.fusion``
                          — plans are rebuilt per task/iteration, so a
@@ -26,9 +37,9 @@ tree said so.  Every jitted operator kernel (they all register through
 
 accumulate into (a) a process-global tally and (b) every active
 :func:`capture` scope.  The scheduler opens a capture per stage and
-mirrors the counters into its MetricNode; bench.py opens one per
-measured query; the dispatch-budget regression test opens one around
-a warm q01 run and asserts the collapse holds.
+mirrors the counters into its MetricNode; ``bench/run.py`` opens one
+around its measured window; the dispatch-budget regression test opens
+one around a warm q01 run and asserts the collapse holds.
 
 Compiles-in-trace caveat: a jitted kernel called INSIDE another trace
 (the agg update program inlines the reduce + merge kernels) does not

@@ -7,15 +7,29 @@ task drives the plan stream into a ``sync_channel(1)`` while the
 consumer pulls — same bounded-channel shape, with the same error and
 cancellation contract (producer errors surface at the consumer;
 consumer teardown or task cancellation stops the producer promptly).
+
+Both sides say when they waited for the other, with a ``trace.span``
+opened only where a hand-over was about to block: ``pipeline_wait`` on
+the consumer's thread (the queue was empty: the producer sets the
+pace), ``pipeline_full`` on the producer's (the queue was full: the
+consumer does).  Whether or not anything blocked, every stream records
+``pipeline_items`` (items handed to the consumer) and
+``pipeline_producer_ns`` (the producer thread's life, first
+``next(stream)`` to last ``put``) once, and its producer thread lives
+inside a ``blaze:<name>_producer`` annotation (``name`` is the stream's:
+``blaze:parquet_scan_producer``) that carries the task's ``stage`` /
+``partition``.
 """
 
 from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Iterable, Iterator
 
 from .. import conf
+from . import dispatch, trace
 
 _DONE = object()
 
@@ -28,46 +42,72 @@ def pipelined(stream: Iterable, ctx, depth: int = 2, name: str = "pipeline") -> 
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
 
+    def halted() -> bool:
+        return stop.is_set() or not ctx.is_task_running()
+
     def put(item) -> bool:
-        while True:
-            if stop.is_set() or not ctx.is_task_running():
-                return False
-            try:
-                q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
+        if halted():
+            return False
+        try:
+            q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with trace.span("pipeline_full", stream=name):
+            while not halted():
+                try:
+                    q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def produce():
-        try:
-            for item in stream:
-                if not put(item):
-                    return
-            put(_DONE)
-        except BaseException as e:  # noqa: BLE001 — forwarded, not swallowed
-            put(e)
+        t0 = time.perf_counter_ns()
+        with trace.annotation(name + "_producer", stage=ctx.stage_id, partition=ctx.partition):
+            try:
+                for item in stream:
+                    if not put(item):
+                        return
+                put(_DONE)
+            except BaseException as e:  # noqa: BLE001 — forwarded, not swallowed
+                put(e)
+            finally:
+                dispatch.record("pipeline_producer_ns", time.perf_counter_ns() - t0)
 
     t = threading.Thread(target=produce, name=f"blaze-{name}", daemon=True)
+
+    def get():
+        """The next item; ``_DONE`` where the task was cancelled."""
+        try:
+            return q.get_nowait()
+        except queue.Empty:
+            pass
+        with trace.span("pipeline_wait", stream=name):
+            while True:
+                try:
+                    return q.get(timeout=0.05)
+                except queue.Empty:
+                    if not ctx.is_task_running():
+                        return _DONE
 
     def consume():
         # start lazily: a stream that is never iterated must not leak a
         # producer thread (its finally below would never run)
         t.start()
+        items = 0
         try:
             while True:
-                try:
-                    item = q.get(timeout=0.05)
-                except queue.Empty:
-                    if not ctx.is_task_running():
-                        return
-                    continue
+                item = get()  # its wait span is closed before the yield
                 if item is _DONE:
                     return
                 if isinstance(item, BaseException):
                     raise item
+                items += 1
                 yield item
         finally:
             stop.set()
+            dispatch.record("pipeline_items", items)
 
     return consume()
 

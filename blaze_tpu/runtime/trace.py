@@ -34,8 +34,16 @@ Host spans (:class:`span`) are a different thing from events and are
 always on: a ``jax.profiler.TraceAnnotation`` on the profiler's clock
 plus ``<name>_ns``/``<name>_n`` in the dispatch tally, so a
 ``dispatch.capture()`` reads where a query's host time went with this
-log disarmed.  ``spark.blaze.trace.sampleRate=0`` arms the log and the
-per-label attribution without any block-until-ready.
+log disarmed.  ``_ns`` is wall time, and wall time cannot tell a thread
+that computes from one that waits, so the program's two hand-off
+queues say who waited for whom with spans of their own, opened only
+where a thread was about to block: ``pipeline_wait`` /
+``pipeline_full`` (runtime/pipeline.py: the task thread waiting for its
+scan's producer, the producer for the task thread) and
+``inserter_full`` / ``inserter_drain`` (parallel/shuffle.py: the map
+task's thread waiting for the exchange stager).
+``spark.blaze.trace.sampleRate=0`` arms the log and the per-label
+attribution without any block-until-ready.
 
 Consumers: the stage scheduler emits lifecycle events
 (stage submit/complete, task attempt start/end/retry/timeout,
@@ -530,10 +538,6 @@ def kernel_capture() -> Iterator[Dict[str, Dict[str, int]]]:
             # sinks) would evict the outer scope's dict instead
             _remove_by_identity(_KERNEL_SINKS, sink)
             _KERNEL_TIMING = bool(_KERNEL_SINKS)
-
-
-#: bench.py alias: profile one run's kernel split without an event log
-profile_kernels = kernel_capture
 
 
 def sample_kernel() -> bool:

@@ -478,6 +478,27 @@ def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, decoder
     assert c["scan_pages_python_codec"] == (pages if decoder == "page_decoder_pure_python" else 0)
 
 
+@pytest.mark.parametrize("batch_rows,sliced", [(8192, True), (1 << 20, False)])
+def test_scan_slices_under_a_span_and_hands_every_batch_over(tmp_path, batch_rows, sliced):
+    """One scan_slice span a batch cut from a row group, none where a row
+    group is yielded whole; the pipelined scan hands over, and stages,
+    exactly the batches it made."""
+    from blaze_tpu.runtime import dispatch
+
+    path, schema, _ = _mixed_file(tmp_path)
+    rows = [rg.rows for rg in pq.read_metadata(path).row_groups]
+    scan = ParquetScanExec([[path]], schema, batch_rows=batch_rows)
+    with dispatch.capture() as c:
+        batches = list(scan.execute(0, TaskContext(0, 1)))
+    want = sum(-(-r // batch_rows) for r in rows) if sliced else len(rows)
+    assert len(batches) == want == c["pipeline_items"] == c["scan_stage_n"]
+    assert c.get("scan_slice_n", 0) == (want if sliced else 0)
+    if sliced:
+        assert want > len(rows) and c["scan_slice_ns"] > 0
+    else:
+        assert "scan_slice_ns" not in c
+
+
 def test_scan_batches_equal_the_decoded_row_group(tmp_path):
     """Decoding at the row group's capacity changes no batch: the scan's
     slices hold the file's rows, padding zero and invalid."""

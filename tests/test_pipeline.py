@@ -168,3 +168,132 @@ def test_scan_through_pipeline(tmp_path):
     finally:
         conf.PIPELINE_DEPTH.set(old)
     assert piped == sync == list(range(5000))
+
+
+# --------------------------------------------------------------------
+# Who waits for whom (PR 38): a wait span on either side of the queue,
+# opened only where the hand-over was about to block, and the stream's
+# items and its producer's life whether or not anything blocked.
+
+def _join_producers(name):
+    """The producer records its life as its thread ends: wait for it."""
+    for t in threading.enumerate():
+        if t.name == f"blaze-{name}":
+            t.join(10)
+            assert not t.is_alive()
+
+
+def _drive(gen, name, depth, consumer_sleep=0.0, take=None):
+    from blaze_tpu.runtime import dispatch
+
+    got = []
+    with dispatch.capture() as c:
+        it = pipelined(gen, TaskContext(0, 1), depth=depth, name=name)
+        try:
+            for item in it:
+                got.append(item)
+                if take is not None and len(got) == take:
+                    break
+                time.sleep(consumer_sleep)
+        finally:
+            it.close()
+            _join_producers(name)
+    return got, c
+
+
+def test_a_slow_producer_makes_the_consumer_wait_and_never_blocks_itself():
+    def gen():
+        for i in range(8):
+            time.sleep(0.02)
+            yield i
+
+    got, c = _drive(gen(), "slowprod", depth=2)
+    assert got == list(range(8))
+    # one wait an item, less what a loaded host lets the producer run ahead
+    assert c["pipeline_wait_n"] >= 3
+    assert c["pipeline_wait_ns"] >= 3 * 0.015e9
+    assert "pipeline_full_n" not in c and "pipeline_full_ns" not in c
+
+
+def test_a_slow_consumer_blocks_the_producer_and_hardly_waits_itself():
+    got, c = _drive(iter(range(12)), "slowcons", depth=1, consumer_sleep=0.02)
+    assert got == list(range(12))
+    assert c["pipeline_full_n"] >= 4
+    assert c["pipeline_full_ns"] >= 4 * 0.015e9
+    # the first hand-over, while the producer thread starts, and what a
+    # loaded host adds
+    assert c.get("pipeline_wait_n", 0) <= 3
+    assert c.get("pipeline_wait_ns", 0) < c["pipeline_full_ns"] / 2
+    # the producer's life holds its waits: one thread, one clock
+    assert c["pipeline_full_ns"] <= c["pipeline_producer_ns"]
+
+
+@pytest.mark.parametrize("how,want", [("to_the_end", 6), ("consumer_closes_early", 3),
+                                      ("producer_raises", 4)])
+def test_a_stream_records_its_items_and_its_producers_life_once(how, want, monkeypatch):
+    from blaze_tpu.runtime import dispatch
+
+    records = []
+    real = dispatch.record
+
+    def spy(name, v=1):
+        if name.startswith("pipeline_"):
+            records.append((name, v))
+        real(name, v)
+
+    def gen():
+        for i in range(6):
+            if how == "producer_raises" and i == 4:
+                raise ValueError("boom in producer")
+            yield i
+
+    monkeypatch.setattr(dispatch, "record", spy)
+    if how == "producer_raises":
+        with pytest.raises(ValueError, match="boom in producer"):
+            _drive(gen(), "once", depth=2)
+    else:
+        got, _ = _drive(gen(), "once", depth=2,
+                        take=want if how == "consumer_closes_early" else None)
+        assert got == list(range(want))
+    assert sorted(n for n, _ in records) == ["pipeline_items", "pipeline_producer_ns"]
+    assert dict(records)["pipeline_items"] == want
+    assert dict(records)["pipeline_producer_ns"] > 0
+
+
+def test_no_wait_span_is_open_across_a_yield():
+    """The consumer's own time between two items is not a wait: the
+    producer is long done, the consumer sleeps, and pipeline_wait holds
+    none of it."""
+    got, c = _drive(iter(range(4)), "acrossyield", depth=8, consumer_sleep=0.05)
+    assert got == list(range(4))
+    assert c["pipeline_items"] == 4
+    assert c.get("pipeline_wait_n", 0) <= 1
+    assert c.get("pipeline_wait_ns", 0) < 0.04e9 < 4 * 0.05e9
+    assert "pipeline_full_n" not in c
+
+
+def test_the_producer_thread_lives_inside_an_annotation_named_for_its_stream(monkeypatch):
+    """blaze:<name>_producer with the task's stage and partition, opened
+    on the producer's thread, and its wait span inside it."""
+    from blaze_tpu.runtime import trace
+
+    opened = []
+    real = trace.annotation
+
+    def spy(name, **ids):
+        opened.append((name, ids, threading.current_thread().name))
+        return real(name, **ids)
+
+    monkeypatch.setattr(trace, "annotation", spy)
+    it = pipelined(iter(range(6)), TaskContext(2, 4, stage_id=7), depth=1, name="some_scan")
+    try:
+        for _ in it:
+            time.sleep(0.01)
+    finally:
+        it.close()
+        _join_producers("some_scan")
+    lives = [o for o in opened if o[0].endswith("_producer")]
+    assert lives == [("some_scan_producer", {"stage": 7, "partition": 2}, "blaze-some_scan")]
+    full = [o for o in opened if o[0] == "pipeline_full"]
+    assert full and all(o[1:] == ({"stream": "some_scan"}, "blaze-some_scan") for o in full)
+    assert opened.index(lives[0]) < opened.index(full[0])

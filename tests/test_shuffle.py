@@ -428,3 +428,106 @@ def test_device_ring_fifo_and_overlap_metric():
     ring.put(9)
     ring.drop()
     assert len(ring) == 0 and ring.flush() == []
+
+
+# ------------------- the map task's thread waiting for its stager (PR 38)
+
+
+def test_a_stalled_stager_shows_as_the_tasks_wait():
+    """depth 1 and a stager that takes 30 ms a batch: the third put finds
+    the queue full (inserter_full), close() waits out the rest
+    (inserter_drain), inserter_items counts the puts, and the stager's
+    D2H is the exchange_d2h_ns share of its exchange_write."""
+    import time
+
+    from blaze_tpu.parallel.shuffle import ShuffleRepartitioner, _AsyncInserter
+    from blaze_tpu.runtime import dispatch
+    from blaze_tpu.runtime.metrics import MetricsSet
+
+    schema = Schema([Field("x", DataType.int64())])
+    rep = ShuffleRepartitioner(schema, 1, MetricsSet())
+    real_insert = rep.insert_sorted
+
+    def slow_insert(host, counts):
+        time.sleep(0.03)
+        real_insert(host, counts)
+
+    rep.insert_sorted = slow_insert
+    b = batch_from_pydict({"x": [1, 2, 3, 4]}, schema)
+    with dispatch.capture() as c:
+        ins = _AsyncInserter(rep, schema, depth=1, metrics=MetricsSet(), stage=7, partition=2)
+        for _ in range(5):
+            ins.put((list(b.columns), np.array([4]), 4))
+        ins.close()
+    assert not ins._thread.is_alive()
+    assert c["inserter_items"] == 5 == c["exchange_write_n"] == c["device_read_n"]
+    # three of the five puts find the queue full, less what a loaded host
+    # lets the stager catch up
+    assert c["inserter_full_n"] >= 2 and c["inserter_drain_n"] == 1
+    assert c["inserter_full_ns"] >= 0.025e9 and c["inserter_drain_ns"] >= 0.025e9
+    assert 0 < c["exchange_d2h_ns"] == c["device_read_ns"] < c["exchange_write_ns"]
+    # what the task waited for is inside what the stager did
+    waited = c["inserter_full_ns"] + c["inserter_drain_ns"]
+    assert waited <= c["exchange_write_ns"] * 1.5
+
+
+@pytest.mark.parametrize("async_write", [True, False])
+def test_a_map_task_tallies_its_stager_only_where_it_has_one(tpch_data, tmp_path, async_write):
+    from blaze_tpu import conf
+    from blaze_tpu.runtime import dispatch
+
+    old = conf.SHUFFLE_ASYNC_WRITE.get()
+    conf.SHUFFLE_ASYNC_WRITE.set(async_write)
+    try:
+        writer, _, _ = _agg_write(tpch_data, str(tmp_path), f"w{int(async_write)}")
+        with dispatch.capture() as c:
+            list(writer.execute(0, TaskContext(0, 1)))
+    finally:
+        conf.SHUFFLE_ASYNC_WRITE.set(old)
+    inserter = {k for k in c if k.startswith("inserter_")}
+    if async_write:
+        # every batch staged plus the commit is an exchange_write
+        assert c["inserter_items"] == c["exchange_write_n"] - 1 > 0
+        assert c["inserter_drain_n"] == 1
+        assert inserter >= {"inserter_items", "inserter_drain_ns", "inserter_drain_n"}
+    else:
+        assert not inserter
+    assert 0 < c["exchange_d2h_ns"] <= c["device_read_ns"]
+    assert c["exchange_d2h_ns"] < c["exchange_write_ns"]
+
+
+def test_the_stager_thread_lives_inside_an_annotation_with_its_tasks_ids(monkeypatch):
+    """blaze:exchange_stager carries what the writer handed the inserter;
+    the stager's exchange_write spans open inside it, on its thread, and
+    the task's drain on the task's."""
+    import threading
+
+    from blaze_tpu.parallel.shuffle import ShuffleRepartitioner, _AsyncInserter
+    from blaze_tpu.runtime import trace
+    from blaze_tpu.runtime.metrics import MetricsSet
+
+    opened = []
+    real = trace.annotation
+
+    def spy(name, **ids):
+        opened.append((name, ids, threading.current_thread().name))
+        return real(name, **ids)
+
+    monkeypatch.setattr(trace, "annotation", spy)
+    schema = Schema([Field("x", DataType.int64())])
+    rep = ShuffleRepartitioner(schema, 1, MetricsSet())
+    b = batch_from_pydict({"x": [1, 2, 3, 4]}, schema)
+    ins = _AsyncInserter(rep, schema, depth=2, metrics=MetricsSet(), stage=7, partition=2)
+    for _ in range(3):
+        ins.put((list(b.columns), np.array([4]), 4))
+    ins.close()
+    me = threading.current_thread().name
+    by_name = {}
+    for name, ids, thread in opened:
+        by_name.setdefault(name, []).append((ids, thread))
+    assert by_name["exchange_stager"] == [({"stage": 7, "partition": 2}, "shuffle-async-insert")]
+    assert by_name["exchange_write"] == [({}, "shuffle-async-insert")] * 3
+    assert by_name["inserter_drain"] == [({}, me)]
+    assert {t for _, t in by_name.get("inserter_full", [])} <= {me}
+    names = [o[0] for o in opened]
+    assert names.index("exchange_stager") < names.index("exchange_write")
