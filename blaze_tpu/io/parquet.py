@@ -27,6 +27,12 @@ snappy codec for the page decoder (snappy_decompress; ZSTD pages go to
   type other than the requested one), and every chunk where pyarrow
   does not import, goes through read_column_chunk — ≙ the reference's
   ParquetExec decoding through the arrow-rs ``parquet`` crate.
+- read_row_group_pieces: the same row group as a stream — where Arrow
+  takes every chunk, its reader decodes page by page as each piece of a
+  batch's rows is pulled and the piece is converted by the same code
+  into arrays of its own capacity, so a scan hands a batch on after a
+  sixteenth of the decode; any other row group is read_row_group's, as
+  one piece.
 
 Physical mapping: BOOLEAN (bit-packed) <- bool; INT32 <- int8/16/32 +
 DATE; INT64 <- int64/timestamp/decimal(<=18) [ConvertedType DECIMAL];
@@ -966,6 +972,96 @@ def read_row_group(path: str, row_group: RowGroupMeta, fields: Sequence[Field], 
             tally["chunks"] += 1
         out[i] = arrays
     return out
+
+
+def read_row_group_pieces(path: str, row_group: RowGroupMeta, fields: Sequence[Field],
+                          piece_rows: int, capacity, arrow_file=None,
+                          tally: Optional[collections.Counter] = None):
+    """Decode one row group as a stream of pieces: yields ``(chunks, lo,
+    hi)``, ``chunks`` as read_row_group returns them and rows ``[lo,
+    hi)`` of them the piece's own, the pieces tiling the row group in
+    order.
+
+    Where ``arrow_file`` takes every chunk of ``fields`` the row group
+    holds (none INT96 or FIXED_LEN_BYTE_ARRAY, each Arrow type the
+    requested type's: what the footer and Arrow's schema say before
+    anything is read), Arrow's reader decodes page by page as it is
+    pulled and a piece is the next ``piece_rows`` rows (the last: what
+    is left) converted straight into arrays of ``capacity(rows)``:
+    ``(chunks, 0, rows)``, and nothing of the row group's size is ever
+    allocated.  Any other row group, and the rest of one from the piece
+    on at which Arrow's call fails, is ONE piece: read_row_group's whole
+    row group at ``capacity(row_group.rows)`` — ``(chunks, rows already
+    handed on, row_group.rows)``.  ``tally`` as read_row_group's, and
+    ``pieces`` and ``streamed`` (a row group streamed to its end)."""
+    held = [f for f in fields if f.name in row_group.chunks]
+    handed_on = 0
+    if held and _streams(arrow_file, row_group, held):
+        import pyarrow
+
+        # use_threads: the columns of a piece decode side by side on Arrow's pool;
+        # on the chip machine every window read better with it, in both file
+        # cells, though a piece is a sixteenth of a row group (PERF.md §6, PR 39)
+        pieces = _recut(arrow_file.iter_batches(
+            batch_size=piece_rows, row_groups=[row_group.index],
+            columns=[f.name for f in held], use_threads=True), piece_rows)
+        while True:
+            try:
+                batches = next(pieces, None)
+            except (pyarrow.ArrowException, OSError):
+                break  # the page decoder reads the rest, or says what is wrong with it
+            if batches is None:
+                if tally is not None:
+                    tally["chunks"] += len(held)
+                    tally["chunks_native"] += len(held)
+                    tally["streamed"] += 1
+                return
+            table = pyarrow.Table.from_batches(batches)
+            rows = table.num_rows
+            cap = capacity(rows)
+            chunks = [_from_arrow(table.column(f.name), f.dtype, cap) if f in held else None
+                      for f in fields]
+            if tally is not None:
+                tally["pieces"] += 1
+            yield chunks, 0, rows
+            handed_on += rows
+    chunks = read_row_group(path, row_group, fields, capacity(row_group.rows),
+                            arrow_file=arrow_file, tally=tally)
+    if tally is not None:
+        tally["pieces"] += 1
+    yield chunks, handed_on, row_group.rows
+
+
+def _streams(arrow_file, row_group: RowGroupMeta, held: Sequence[Field]) -> bool:
+    """Whether Arrow's reader takes every chunk of ``held``, so that the
+    row group can be read as a stream of its batches."""
+    if arrow_file is None:
+        return False
+    schema = arrow_file.schema_arrow
+    for f in held:
+        i = schema.get_field_index(f.name)
+        if (row_group.chunks[f.name].phys in (T_INT96, T_FLBA) or i < 0
+                or _arrow_layout(schema.field(i).type, f.dtype) is None):
+            return False
+    return True
+
+
+def _recut(batches, rows: int):
+    """Arrow's record batches as lists of slices of exactly ``rows`` rows
+    together, the last what is left: Arrow hands back the batch size it
+    is asked for, and where it does not, nothing downstream sees it."""
+    held, n = [], 0
+    for batch in batches:
+        while batch.num_rows:
+            part = batch.slice(0, rows - n)
+            held.append(part)
+            n += part.num_rows
+            batch = batch.slice(part.num_rows)
+            if n == rows:
+                yield held
+                held, n = [], 0
+    if held:
+        yield held
 
 
 _ARROW_FIXED = {  # requested kind -> the pyarrow.types test of the one Arrow type it is read from

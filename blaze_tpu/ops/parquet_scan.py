@@ -11,8 +11,10 @@ Spark-compatible schema adaption (scan/mod.rs:28-187).
 from __future__ import annotations
 
 import collections
+import contextlib
 import datetime
 import struct
+import time
 from typing import List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
@@ -178,113 +180,146 @@ class ParquetScanExec(ExecNode):
         return Column(dtype, np.zeros(cap, dtype.np_dtype), np.zeros(cap, np.bool_))
 
     def execute(self, partition: int, ctx: TaskContext) -> BatchStream:
-        files = self.file_groups[partition] if partition < len(self.file_groups) else []
-
-        def stream():
-            for entry in files:
-                path = entry_path(entry)
-                # one task's open of one file: its footer, which of its
-                # row groups are this entry's, and Arrow's reader over it
-                # where pyarrow imports (closed with the entry: no handle,
-                # footer or decoded array outlives its task)
-                with trace.span("scan_open"):
-                    try:
-                        row_groups = pq.read_metadata(path).row_groups
-                        arrow_file = pq.open_arrow_file(path, self._schema.fields, row_groups)
-                    except Exception:
-                        if bool(conf.IGNORE_CORRUPT_FILES.get()):
-                            self.metrics.add("skipped_corrupt_files", 1)
-                            continue
-                        raise
-                    mine = split_row_groups(entry, row_groups)
-                dispatch.record("scan_splits")
-                dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
-                try:
-                    yield from self._row_group_batches(path, mine, arrow_file)
-                finally:
-                    if arrow_file is not None:
-                        arrow_file.close(force=True)
-
+        """Three threads a task where the scan is pipelined
+        (``spark.blaze.pipeline.depth`` > 0), each at most ``depth``
+        hand-overs ahead of the next: the DECODE thread
+        (``blaze-parquet_decode``) opens each entry, chooses and prunes
+        its row groups and decodes them piece by piece (_pieces; every
+        use of an Arrow ``ParquetFile``, its close too, is this
+        thread's); the STAGING thread (``blaze-parquet_scan``) makes each
+        piece's host batches and stages them (_batches, _staged); the
+        task thread launches.  With depth 0 all of it runs on the
+        calling thread."""
         from ..runtime.pipeline import maybe_pipelined
 
-        # file decode overlaps downstream device compute (≙ rt.rs:100-133)
-        return maybe_pipelined(self._staged(stream()), ctx, "parquet_scan")
+        files = self.file_groups[partition] if partition < len(self.file_groups) else []
+        # the decode's own hand-over tallies as decode_wait / decode_full /
+        # decode_items; pipeline_* stay the task-facing hand-over's alone
+        pieces = maybe_pipelined(self._pieces(files), ctx, "parquet_decode", tally="decode")
+        # file decode overlaps staging, staging overlaps downstream
+        # device compute (≙ rt.rs:100-133)
+        return maybe_pipelined(self._staged(self._batches(pieces)), ctx, "parquet_scan")
 
-    def _row_group_batches(self, path: str, row_groups: Sequence[pq.RowGroupMeta], arrow_file):
-        """The batches of one entry's row groups, each decoded once."""
-        for rg in row_groups:
-            if rg.rows == 0:
+    def _pieces(self, files: Sequence[FileEntry]):
+        """The decoded pieces of one task's entries, file by file, row
+        group by row group: ``(chunks, lo, hi, cut)`` — rows ``[lo, hi)``
+        of ``chunks`` (pq.read_row_group_pieces) and whether the row
+        group is cut into more than one batch."""
+        for entry in files:
+            path = entry_path(entry)
+            # one task's open of one file: its footer, which of its
+            # row groups are this entry's, and Arrow's reader over it
+            # where pyarrow imports (closed with the entry, on this
+            # thread: no handle, footer or decoded array outlives its task)
+            with trace.span("scan_open"):
+                try:
+                    row_groups = pq.read_metadata(path).row_groups
+                    arrow_file = pq.open_arrow_file(path, self._schema.fields, row_groups)
+                except Exception:
+                    if bool(conf.IGNORE_CORRUPT_FILES.get()):
+                        self.metrics.add("skipped_corrupt_files", 1)
+                        continue
+                    raise
+                mine = split_row_groups(entry, row_groups)
+            dispatch.record("scan_splits")
+            dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
+            try:
+                for rg in mine:
+                    if rg.rows and not self._pruned(rg):
+                        yield from self._row_group_pieces(path, rg, arrow_file)
+            finally:
+                if arrow_file is not None:
+                    arrow_file.close(force=True)
+
+    def _pruned(self, rg: pq.RowGroupMeta) -> bool:
+        """Whether the chunk statistics rule the row group out, decided
+        before it is opened."""
+        for name, op, lit_v in self._conjuncts:
+            ch = rg.chunks.get(name)
+            if ch is None:
                 continue
-            pruned = False
-            for name, op, lit_v in self._conjuncts:
-                ch = rg.chunks.get(name)
-                if ch is None:
-                    continue
-                fld = next((f for f in self._schema.fields if f.name == name), None)
-                if fld is None:
-                    # predicate column pruned from the read
-                    # schema: stats pruning just skips it
-                    continue
-                if not _maybe_match(ch, fld.dtype, op, lit_v):
-                    pruned = True
-                    break
-            if pruned:
+            fld = next((f for f in self._schema.fields if f.name == name), None)
+            if fld is None:
+                # predicate column pruned from the read
+                # schema: stats pruning just skips it
+                continue
+            if not _maybe_match(ch, fld.dtype, op, lit_v):
                 self.metrics.add("pruned_row_groups", 1)
                 self.metrics.add("pruned_rows", rg.rows)
                 dispatch.record("scan_row_groups_pruned")
+                return True
+        return False
+
+    def _row_group_pieces(self, path: str, rg: pq.RowGroupMeta, arrow_file):
+        """One row group's fetch + decompress + decode, each row once,
+        on the decode thread where the scan is pipelined.  Where
+        ``arrow_file`` takes every chunk, a stream: Arrow's reader
+        decodes page by page, outside the GIL, as each piece of
+        ``batch_rows`` rows is pulled, and the piece is converted
+        straight into arrays of its own capacity — handed on while the
+        next is decoded.  Else ONE piece: the whole row group through
+        pq.read_row_group, chunk by chunk in this module's page decoder
+        where Arrow does not take it.  ``scan_decode`` is an annotation a
+        piece and ONE tally a row group: its pieces' nanoseconds summed,
+        as _staged does for a stream."""
+        decoded = collections.Counter()
+        cut = rg.rows > self.batch_rows
+        pieces = pq.read_row_group_pieces(path, rg, self._schema.fields, self.batch_rows,
+                                          bucket_capacity, arrow_file=arrow_file, tally=decoded)
+        ns = 0
+        try:
+            while True:
+                with trace.annotation("scan_decode"):
+                    t0 = time.perf_counter_ns()
+                    try:
+                        piece = next(pieces, None)
+                    finally:
+                        ns += time.perf_counter_ns() - t0
+                if piece is None:
+                    break
+                yield (*piece, cut)
+        finally:
+            dispatch.record_span("scan_decode", ns)
+            self.metrics.add("input_io_time", ns)
+        dispatch.record("scan_file_bytes", sum(
+            rg.chunks[f.name].total_comp for f in self._schema.fields if f.name in rg.chunks))
+        dispatch.record("scan_row_groups")
+        dispatch.record("scan_row_groups_streamed", decoded["streamed"])
+        dispatch.record("scan_pieces", decoded["pieces"])
+        dispatch.record("scan_chunks", decoded["chunks"])
+        dispatch.record("scan_chunks_native", decoded["chunks_native"])
+        # pages the page decoder walked: none where Arrow took every chunk
+        dispatch.record("scan_pages", decoded["pages"])
+        dispatch.record("scan_pages_python_codec", decoded["pages_python_codec"])
+
+    def _batches(self, pieces):
+        """Each piece as host batches of at most ``batch_rows`` rows, on
+        the staging thread where the scan is pipelined: a streamed
+        piece, or a row group no longer than a batch, is its arrays as
+        they are; a longer row group decoded whole is sliced.
+        ``scan_slice`` is around each batch of a row group that is cut
+        into several, closed before the yield."""
+        for chunks, lo, hi, cut in pieces:
+            for s in range(lo, hi, self.batch_rows):
+                e = min(s + self.batch_rows, hi)
+                with trace.span("scan_slice") if cut else contextlib.nullcontext():
+                    b = self._host_batch(chunks, s, e, whole=(s, e) == (0, hi))
+                self._record_batch(b)
+                yield b
+
+    def _host_batch(self, chunks, lo: int, hi: int, whole: bool) -> RecordBatch:
+        """Rows ``[lo, hi)`` of decoded ``chunks`` at their own capacity:
+        the arrays themselves where the rows are all they hold
+        (``whole``), a padded copy else; schema adaption: a missing
+        column is nulls."""
+        cap = bucket_capacity(hi - lo)
+        cols: List[Column] = []
+        for f, arrays in zip(self._schema.fields, chunks):
+            if arrays is None:
+                cols.append(self._null_column(f.dtype, cap))
                 continue
-            # one row group's fetch + decompress + decode, every
-            # column straight into arrays of the row group's capacity:
-            # through Arrow's reader in one call outside the GIL, then
-            # converted whole, where ``arrow_file`` is there and takes
-            # the chunk, page by page in this module's decoder else;
-            # once a row group, in the producer thread where the scan
-            # is pipelined
-            decoded = collections.Counter()
-            with self.metrics.timer("input_io_time", trace.span("scan_decode")):
-                cap = bucket_capacity(rg.rows)
-                fields = self._schema.fields
-                chunks = pq.read_row_group(path, rg, fields, cap,
-                                           arrow_file=arrow_file, tally=decoded)
-                # schema adaption: missing column -> null
-                cols: List[Column] = [
-                    self._null_column(f.dtype, cap) if arrays is None else Column(f.dtype, *arrays)
-                    for f, arrays in zip(fields, chunks)]
-            dispatch.record("scan_file_bytes", sum(
-                rg.chunks[f.name].total_comp for f in fields if f.name in rg.chunks))
-            dispatch.record("scan_row_groups")
-            dispatch.record("scan_chunks", decoded["chunks"])
-            dispatch.record("scan_chunks_native", decoded["chunks_native"])
-            # pages the page decoder walked: none where Arrow took every chunk
-            dispatch.record("scan_pages", decoded["pages"])
-            dispatch.record("scan_pages_python_codec", decoded["pages_python_codec"])
-            # emit in batch_rows slices to bound device batches
-            full = RecordBatch(self._schema, cols, rg.rows)
-            if rg.rows <= self.batch_rows:
-                self.metrics.add("output_rows", rg.rows)
-                yield full
-            else:
-                host = full
-                for s in range(0, rg.rows, self.batch_rows):
-                    # one sliced batch's construction, on the producer
-                    # thread where the scan is pipelined; closed before
-                    # the yield
-                    with trace.span("scan_slice"):
-                        e = min(s + self.batch_rows, rg.rows)
-                        scap = bucket_capacity(e - s)
-                        sl: List[Column] = []
-                        for c in host.columns:
-                            d = np.asarray(c.data)[s:e]
-                            sl.append(
-                                Column(
-                                    c.dtype,
-                                    _pad_1d(np.ascontiguousarray(d), scap),
-                                    _pad_1d(np.asarray(c.validity)[s:e], scap),
-                                    None
-                                    if c.lengths is None
-                                    else _pad_1d(np.asarray(c.lengths)[s:e], scap),
-                                )
-                            )
-                        b = RecordBatch(self._schema, sl, e - s)
-                    self._record_batch(b)
-                    yield b
+            if not whole:
+                arrays = [None if a is None else _pad_1d(np.ascontiguousarray(a[lo:hi]), cap)
+                          for a in arrays]
+            cols.append(Column(f.dtype, *arrays))
+        return RecordBatch(self._schema, cols, hi - lo)

@@ -25,7 +25,11 @@ tree said so.  Every jitted operator kernel (they all register through
                          ``inserter_drain`` (parallel/shuffle.py),
 - ``pipeline_items``/``pipeline_producer_ns`` — items a pipelined
                          stream handed over and its producer thread's
-                         life, one record a stream each;
+                         life, one record a stream each (a stream
+                         with a ``tally`` name of its own records
+                         under it: the Parquet scan's decode hand-over
+                         is ``decode_wait``/``decode_full``/
+                         ``decode_items``/``decode_producer_ns``);
                          ``inserter_items`` — batches a map task put to
                          its exchange stager, one record a task;
                          ``exchange_d2h_ns`` — the exchange writer's

@@ -764,3 +764,313 @@ print("rows", rows)
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     done = subprocess.run([sys.executable, "-c", script], env=env, capture_output=True, text=True, timeout=300)
     assert done.returncode == 0 and done.stdout.split()[-2:] == ["rows", "1200"], done.stderr[-2000:]
+
+
+# --------------------------------------------------------------------
+# The scan decodes as a stream (PR 39): a row group Arrow takes whole is
+# read piece by piece on a decode thread of its own, any other as one
+# piece through read_row_group — and either way the batches are the
+# whole-row-group read's, batch for batch and byte for byte.
+
+def _not_taken_file(tmp_path, how):
+    """Two row groups of 5,000 and 2,000 rows with one chunk Arrow's
+    reader is not given (INT96) or whose type is not the requested one
+    (int32 read as int64), beside two it does take."""
+    pa = pytest.importorskip("pyarrow")
+    import pyarrow.parquet as papq
+
+    n = 7_000
+    rng = np.random.RandomState(5)
+    k = pa.array(rng.randint(0, 1 << 40, n), pa.int64(), mask=rng.rand(n) < 0.1)
+    s = pa.array([None if i % 13 == 0 else "w%d" % (i % 40) for i in range(n)], pa.string())
+    if how == "int96_chunk":
+        c, dtype = pa.array(np.arange(n) * 1_000_003 + 1_600_000_000_000_000, pa.timestamp("us")), DataType.timestamp()
+        writer = dict(use_deprecated_int96_timestamps=True)
+    else:
+        c, dtype, writer = pa.array(rng.randint(-9, 9, n), pa.int32()), DataType.int64(), {}
+    path = str(tmp_path / f"{how}.parquet")
+    papq.write_table(pa.table({"k": k, "c": c, "s": s}), path, row_group_size=5_000, **writer)
+    return path, Schema([Field("k", DataType.int64()), Field("c", dtype), Field("s", DataType.string(8))])
+
+
+STREAMED = dict(CORPUS)  # every chunk of these is Arrow's: each row group is read as a stream
+WHOLE = {how: (lambda tmp, how=how: _not_taken_file(tmp, how)) for how in ("int96_chunk", "other_type_chunk")}
+
+
+def _whole_read_batches(path, schema, batch_rows):
+    """The reference: each row group decoded WHOLE by the page decoder
+    alone, then cut in ``batch_rows`` steps by hand — (rows, capacity,
+    every buffer's bytes) a batch."""
+    out = []
+    for rg in pq.read_metadata(path).row_groups:
+        chunks = pq.read_row_group(path, rg, schema.fields, bucket_capacity(rg.rows))
+        for s in range(0, rg.rows, batch_rows):
+            e = min(s + batch_rows, rg.rows)
+            cap = bucket_capacity(e - s)
+            buffers = []
+            for arrays in chunks:
+                for a in arrays:
+                    if a is None:
+                        buffers.append(None)
+                        continue
+                    padded = np.zeros((cap,) + a.shape[1:], a.dtype)
+                    padded[: e - s] = a[s:e]
+                    buffers.append((padded.dtype, padded.shape, padded.tobytes()))
+            out.append((e - s, cap, buffers))
+    return out
+
+
+def _scan_batches(scan, partition=0):
+    """What the consumer of one task's scan gets, in _whole_read_batches' form."""
+    out = []
+    for b in scan.execute(partition, TaskContext(partition, scan.num_partitions())):
+        buffers = []
+        for c in b.columns:
+            for a in (c.data, c.validity, c.lengths):
+                a = None if a is None else np.asarray(a)
+                buffers.append(None if a is None else (a.dtype, a.shape, a.tobytes()))
+        out.append((b.num_rows, b.capacity, buffers))
+    return out
+
+
+def _scan_threads_ended():
+    import threading
+
+    for t in threading.enumerate():
+        if t.name.startswith("blaze-"):
+            t.join(10)
+            assert not t.is_alive(), t.name
+
+
+# a tail piece in every row group; a row group no longer than a batch
+@pytest.mark.parametrize("batch_rows", [1024, 1 << 20])
+@pytest.mark.parametrize("case", sorted(STREAMED) + sorted(WHOLE))
+def test_the_streamed_scan_hands_on_the_whole_reads_batches(tmp_path, case, batch_rows):
+    from blaze_tpu.runtime import dispatch
+
+    path, schema = {**STREAMED, **WHOLE}[case](tmp_path)
+    row_groups = pq.read_metadata(path).row_groups
+    want = _whole_read_batches(path, schema, batch_rows)
+    with dispatch.capture() as c:
+        got = _scan_batches(ParquetScanExec([[path]], schema, batch_rows=batch_rows))
+    assert len(got) == len(want) == sum(-(-rg.rows // batch_rows) for rg in row_groups)
+    for k, (mine, theirs) in enumerate(zip(got, want)):
+        assert mine[:2] == theirs[:2], k
+        assert mine[2] == theirs[2], k
+    assert c["scan_decode_n"] == c["scan_row_groups"] == len(row_groups)
+    assert c["scan_row_groups_streamed"] == (len(row_groups) if case in STREAMED else 0)
+    assert c["scan_pieces"] == c["decode_items"] == (len(want) if case in STREAMED else len(row_groups))
+    assert c["pipeline_items"] == c["scan_stage_n"] == len(want)
+
+
+@pytest.mark.parametrize("how", ["streamed", "int96_chunk", "no_pyarrow"])
+def test_one_scans_counters_close_and_the_two_hand_overs_tally_apart(tmp_path, monkeypatch, how):
+    """scan_decode is ONE tally a row group however many pieces it came
+    in; pipeline_* count the task-facing hand-over alone, decode_* the
+    decode thread's; scan_slice stays one a batch of a row group that is
+    cut into several."""
+    from blaze_tpu.runtime import dispatch
+
+    if how == "int96_chunk":
+        path, schema = _not_taken_file(tmp_path, how)
+        rows, batch_rows = [5_000, 2_000], 2_048
+    else:
+        path, schema, _ = _mixed_file(tmp_path)
+        rows, batch_rows = [25_000, 15_000], 8_192
+    if how == "no_pyarrow":
+        monkeypatch.setattr(pq, "_arrow_reader", lambda: None)
+    batches = sum(-(-r // batch_rows) for r in rows)
+    with dispatch.capture() as c:
+        got = sum(b.num_rows for b in ParquetScanExec([[path]], schema, batch_rows=batch_rows).execute(
+            0, TaskContext(0, 1)))
+        _scan_threads_ended()  # a producer records its life as its thread ends
+    assert got == sum(rows)
+    assert c["scan_decode_n"] == c["scan_row_groups"] == 2 and c["scan_decode_ns"] > 0
+    assert c["pipeline_items"] == c["scan_stage_n"] == batches
+    # none for a row group no longer than a batch (the INT96 file's second)
+    assert c["scan_slice_n"] == sum(-(-r // batch_rows) for r in rows if r > batch_rows)
+    streamed = how == "streamed"
+    assert c["scan_row_groups_streamed"] == (2 if streamed else 0)
+    assert c["scan_pieces"] == c["decode_items"] == (batches if streamed else 2)
+    assert c["scan_chunks_native"] == {"streamed": 10, "int96_chunk": 4, "no_pyarrow": 0}[how]
+    assert c["decode_producer_ns"] > 0 and c["pipeline_producer_ns"] > 0
+    hand_over = {"_items", "_producer_ns", "_wait_ns", "_wait_n", "_full_ns", "_full_n"}
+    for tally in ("pipeline", "decode"):
+        assert {k[len(tally):] for k in c if k.startswith(tally + "_")} <= hand_over, tally
+    # the decode thread's life holds its decode and its waits: one thread, one clock
+    assert c["scan_decode_ns"] + c.get("decode_full_ns", 0) <= c["decode_producer_ns"]
+
+
+class _WatchedArrowFile:
+    """Arrow's ParquetFile with every use noted beside the thread that
+    made it, each pull of a stream's batch too; ``fail_after`` makes the
+    stream raise as Arrow would after that many batches, ``batch_size``
+    hands batches of another length than was asked."""
+
+    def __init__(self, arrow_file, uses, fail_after=None, batch_size=None):
+        self._f, self._uses = arrow_file, uses
+        self._fail_after, self._batch_size = fail_after, batch_size
+
+    def _note(self, what):
+        import threading
+
+        self._uses.append((what, threading.current_thread().name))
+
+    @property
+    def closed(self):
+        return self._f.closed
+
+    @property
+    def schema_arrow(self):
+        return self._f.schema_arrow
+
+    def read_row_group(self, *args, **kwargs):
+        self._note("read_row_group")
+        return self._f.read_row_group(*args, **kwargs)
+
+    def iter_batches(self, batch_size, **kwargs):
+        import pyarrow
+
+        self._note("iter_batches")
+        for k, batch in enumerate(self._f.iter_batches(batch_size=self._batch_size or batch_size, **kwargs)):
+            if k == self._fail_after:
+                raise pyarrow.ArrowInvalid("a page that does not decode")
+            self._note("next")
+            yield batch
+
+    def close(self, force=False):
+        self._note("close")
+        self._f.close(force=force)
+
+
+def _watch_opens(monkeypatch, **how):
+    """Every Arrow file the scan opens from here on is watched; (the
+    files, their uses, the threads that opened them)."""
+    import threading
+
+    files, uses, openers = [], [], []
+    open_arrow_file = pq.open_arrow_file
+
+    def opened(*args):
+        openers.append(threading.current_thread().name)
+        files.append(_WatchedArrowFile(open_arrow_file(*args), uses, **how))
+        return files[-1]
+
+    monkeypatch.setattr(pq, "open_arrow_file", opened)
+    return files, uses, openers
+
+
+def test_an_arrow_file_is_one_threads_from_open_to_close(tmp_path, monkeypatch):
+    path, schema, _ = _mixed_file(tmp_path)
+    files, uses, openers = _watch_opens(monkeypatch)
+    scan = ParquetScanExec([[path, path]], schema, batch_rows=8192)
+    assert sum(b.num_rows for b in scan.execute(0, TaskContext(0, 1))) == 80_000
+    _scan_threads_ended()
+    assert len(files) == 2 and all(f.closed for f in files)
+    assert [w for w, _ in uses].count("iter_batches") == 4 and ("read_row_group" not in dict(uses))
+    assert set(openers) | {t for _, t in uses} == {"blaze-parquet_decode"}
+
+
+def test_depth_zero_runs_the_whole_scan_on_the_calling_thread(tmp_path, monkeypatch):
+    import threading
+
+    from blaze_tpu.runtime import dispatch
+
+    path, schema, _ = _mixed_file(tmp_path)
+    files, uses, openers = _watch_opens(monkeypatch)
+    monkeypatch.setattr("blaze_tpu.conf.PIPELINE_DEPTH.get", lambda: 0)
+    started, start = [], threading.Thread.start
+    monkeypatch.setattr(threading.Thread, "start", lambda self: started.append(self.name) or start(self))
+    with dispatch.capture() as c:
+        got = _scan_batches(ParquetScanExec([[path]], schema, batch_rows=8192))
+    assert got == _whole_read_batches(path, schema, 8192)
+    assert not [name for name in started if name.startswith("blaze-")]
+    assert set(openers) | {t for _, t in uses} == {threading.current_thread().name}
+    assert files[0].closed and c["scan_row_groups_streamed"] == 2 and c["scan_pieces"] == 6
+    assert not [k for k in c if k.startswith(("pipeline_", "decode_"))]
+
+
+@pytest.mark.parametrize("fail_after", [0, 1, 3])
+def test_a_stream_arrow_fails_in_is_finished_by_the_whole_read(tmp_path, monkeypatch, fail_after):
+    """From the piece on at which Arrow's call fails, the rest of the row
+    group is ONE piece through read_row_group, each row handed on once."""
+    from blaze_tpu.runtime import dispatch
+
+    path, schema, _ = _mixed_file(tmp_path)
+    _watch_opens(monkeypatch, fail_after=fail_after)
+    with dispatch.capture() as c:
+        got = _scan_batches(ParquetScanExec([[path]], schema, batch_rows=8192))
+    assert got == _whole_read_batches(path, schema, 8192)
+    # 25,000 rows are 4 pieces and 15,000 are 2: only a row group with more pieces than that streams to its end
+    assert c["scan_row_groups_streamed"] == (1 if fail_after == 3 else 0)
+    assert c["scan_pieces"] == {0: 2, 1: 4, 3: 6}[fail_after]
+    assert c["scan_decode_n"] == c["scan_row_groups"] == 2 and c["pipeline_items"] == 6
+    assert c["scan_chunks"] == 10 and c["scan_chunks_native"] == 10  # the whole read is Arrow's too
+
+
+@pytest.mark.parametrize("arrow_hands", [1_000, 8_192, 20_000])
+def test_pieces_of_another_length_are_cut_again(tmp_path, monkeypatch, arrow_hands):
+    path, schema, _ = _mixed_file(tmp_path)
+    _watch_opens(monkeypatch, batch_size=arrow_hands)
+    got = _scan_batches(ParquetScanExec([[path]], schema, batch_rows=8192))
+    assert [rows for rows, _, _ in got] == [8192, 8192, 8192, 424, 8192, 6808]
+    assert got == _whole_read_batches(path, schema, 8192)
+
+
+def _broken_file(tmp_path, how):
+    """_mixed_file's file, its footer cut off (nothing opens it) or the
+    second row group's first pages overwritten (the first row group
+    decodes, the second does not)."""
+    path, schema, _ = _mixed_file(tmp_path)
+    with open(path, "rb") as f:
+        data = bytearray(f.read())
+    if how == "truncated":
+        data = data[: len(data) // 2]
+    else:
+        start = min(ch.offset for ch in pq.read_metadata(path).row_groups[1].chunks.values())
+        data[start + 40 : start + 4_000] = bytes(3_960)
+    with open(path, "wb") as f:
+        f.write(data)
+    return path, schema
+
+
+@pytest.mark.parametrize("how", ["truncated", "corrupt_second_row_group"])
+def test_a_decode_error_reaches_the_consumer_as_it_was_raised(tmp_path, monkeypatch, how):
+    """Through both hand-overs an error keeps the type and the message it
+    has with no thread between: the batches before it arrive first, and
+    neither thread nor file outlives it."""
+    path, schema = _broken_file(tmp_path, how)
+    files, _, _ = _watch_opens(monkeypatch)
+
+    def drive():
+        got = []
+        with pytest.raises(Exception) as caught:
+            for b in ParquetScanExec([[path]], schema, batch_rows=8192).execute(0, TaskContext(0, 1)):
+                got.append(b.num_rows)
+        _scan_threads_ended()
+        return got, caught.value
+
+    piped_rows, piped = drive()
+    with monkeypatch.context() as m:
+        m.setattr("blaze_tpu.conf.PIPELINE_DEPTH.get", lambda: 0)
+        sync_rows, sync = drive()
+    assert type(piped) is type(sync) and str(piped) == str(sync)
+    assert piped_rows == sync_rows == ([] if how == "truncated" else [8192, 8192, 8192, 424])
+    assert all(f.closed for f in files) and len(files) == (0 if how == "truncated" else 2)
+
+
+@pytest.mark.parametrize("how", ["consumer_closes", "task_cancelled"])
+def test_leaving_a_scan_mid_row_group_leaves_no_thread_and_no_open_file(tmp_path, monkeypatch, how):
+    path, schema, _ = _mixed_file(tmp_path)
+    files, uses, _ = _watch_opens(monkeypatch)
+    ctx = TaskContext(0, 1)
+    stream = ParquetScanExec([[path, path, path]], schema, batch_rows=1024).execute(0, ctx)
+    assert next(stream).num_rows == 1024  # 25 pieces a first row group: the decode thread is inside it
+    if how == "consumer_closes":
+        stream.close()
+    else:
+        ctx.cancel()
+        assert sum(b.num_rows for b in stream) < 3 * 40_000
+    _scan_threads_ended()
+    assert 1 <= len(files) < 3 and all(f.closed for f in files)
+    assert uses[-1] == ("close", "blaze-parquet_decode")

@@ -175,12 +175,12 @@ def test_scan_through_pipeline(tmp_path):
 # opened only where the hand-over was about to block, and the stream's
 # items and its producer's life whether or not anything blocked.
 
-def _join_producers(name):
+def _join_producers(*names):
     """The producer records its life as its thread ends: wait for it."""
     for t in threading.enumerate():
-        if t.name == f"blaze-{name}":
+        if t.name in {f"blaze-{name}" for name in names}:
             t.join(10)
-            assert not t.is_alive()
+            assert not t.is_alive(), t.name
 
 
 def _drive(gen, name, depth, consumer_sleep=0.0, take=None):
@@ -297,3 +297,122 @@ def test_the_producer_thread_lives_inside_an_annotation_named_for_its_stream(mon
     full = [o for o in opened if o[0] == "pipeline_full"]
     assert full and all(o[1:] == ({"stream": "some_scan"}, "blaze-some_scan") for o in full)
     assert opened.index(lives[0]) < opened.index(full[0])
+
+
+# --------------------------------------------------------------------
+# Hand-overs compose (PR 39): a pipelined stream consumes a pipelined
+# stream, each with a thread, a bound and a tally of its own, and the
+# error and teardown contract holds through both.
+
+def _stack(gen, ctx, depth=2):
+    """``gen`` behind two hand-overs, as the Parquet scan stacks them."""
+    inner = pipelined(gen, ctx, depth=depth, name="inner", tally="decode")
+    return pipelined((x for x in inner), ctx, depth=depth, name="outer")
+
+
+def test_a_named_tally_keeps_its_counters_out_of_pipeline_keys():
+    from blaze_tpu.runtime import dispatch
+
+    with dispatch.capture() as c:
+        it = pipelined(iter(range(9)), TaskContext(0, 1), depth=1, name="named", tally="decode")
+        got = []
+        for x in it:
+            got.append(x)
+            time.sleep(0.005)
+        _join_producers("named")
+    assert got == list(range(9))
+    assert c["decode_items"] == 9 and c["decode_producer_ns"] > 0
+    assert c.get("decode_full_n", 0) >= 1  # depth 1 and a slow consumer: the producer met its bound
+    assert not [k for k in c if k.startswith("pipeline_")]
+
+
+def test_two_stacked_streams_tally_apart_and_keep_the_order():
+    from blaze_tpu.runtime import dispatch
+
+    with dispatch.capture() as c:
+        got = list(_stack(iter(range(200)), TaskContext(0, 1)))
+        _join_producers("inner", "outer")
+    assert got == list(range(200))
+    assert c["decode_items"] == c["pipeline_items"] == 200
+    assert c["decode_producer_ns"] > 0 and c["pipeline_producer_ns"] > 0
+
+
+@pytest.mark.parametrize("error", [ValueError, OSError, AssertionError])
+def test_an_error_crosses_two_hand_overs_with_its_type(error):
+    def gen():
+        yield 1
+        raise error("boom two threads down")
+
+    it = _stack(gen(), TaskContext(0, 1))
+    assert next(it) == 1
+    with pytest.raises(error, match="boom two threads down") as caught:
+        next(it)
+    assert type(caught.value) is error
+    _join_producers("inner", "outer")
+
+
+@pytest.mark.parametrize("how", ["consumer_closes", "task_cancelled"])
+def test_leaving_two_stacked_streams_stops_both_threads(how):
+    ctx = TaskContext(0, 1)
+    produced = []
+
+    def gen():
+        for i in range(100_000):
+            produced.append(i)
+            yield i
+
+    it = _stack(gen(), ctx, depth=1)
+    assert next(it) == 0
+    if how == "consumer_closes":
+        it.close()
+    else:
+        ctx.cancel()
+        assert len(list(it)) < 100_000
+    _join_producers("inner", "outer")
+    assert len(produced) < 100_000
+
+
+def test_a_producer_closes_the_generator_it_drove_on_its_own_thread():
+    """A stream the consumer leaves early ends where it ran: its finally
+    blocks (a file's close) are the producer thread's, not those of
+    whichever thread drops the last reference."""
+    ended = []
+
+    def gen():
+        try:
+            yield from range(10_000)
+        finally:
+            ended.append(threading.current_thread().name)
+
+    it = pipelined(gen(), TaskContext(0, 1), depth=1, name="closer")
+    assert next(it) == 0
+    it.close()
+    _join_producers("closer")
+    assert ended == ["blaze-closer"]
+    # and through two hand-overs, innermost generator on the innermost thread
+    ended.clear()
+    it = _stack(gen(), TaskContext(0, 1), depth=1)
+    assert next(it) == 0
+    it.close()
+    _join_producers("inner", "outer")
+    assert ended == ["blaze-inner"]
+
+
+def test_depth_zero_stacks_nothing(monkeypatch):
+    """maybe_pipelined with depth 0, twice: the plain iterator, on the
+    calling thread, and no hand-over tallied."""
+    from blaze_tpu.runtime import dispatch
+
+    monkeypatch.setattr("blaze_tpu.conf.PIPELINE_DEPTH.get", lambda: 0)
+    seen = []
+
+    def gen():
+        for i in range(5):
+            seen.append(threading.current_thread().name)
+            yield i
+
+    with dispatch.capture() as c:
+        inner = maybe_pipelined(gen(), TaskContext(0, 1), "inner", tally="decode")
+        got = list(maybe_pipelined((x for x in inner), TaskContext(0, 1), "outer"))
+    assert got == list(range(5)) and set(seen) == {threading.current_thread().name}
+    assert not [k for k in c if k.startswith(("pipeline_", "decode_"))]
