@@ -907,26 +907,97 @@ def _arrow_reader():
     return _pyarrow_parquet
 
 
-def open_arrow_file(path: str, fields: Sequence[Field], row_groups: Sequence[RowGroupMeta]):
+#: a file no longer than this is fetched whole, in one read, and Arrow
+#: reads it from memory: Arrow's own reader asks for this much of a
+#: file's end to find the footer (``kDefaultFooterReadSize``), so such a
+#: file costs that one read either way — and then no second open, and no
+#: read that calls back into python
+WHOLE_FILE_BYTES = 64 * 1024
+
+
+def open_arrow_file(path: str, fields: Sequence[Field]):
     """One task's open of one file for read_row_group: Arrow's
-    ``ParquetFile`` over ``get_fs(path).open(path)``, the file's string
-    columns among ``fields`` left as indices + dictionary; None where
-    pyarrow does not import or the file holds no row group.  The caller
-    closes it (``close(force=True)`` closes the file under it too)."""
+    ``ParquetFile`` over ``get_fs(path).open(path)`` — over the file's
+    bytes where it is no longer than WHOLE_FILE_BYTES —, the file's
+    string columns among ``fields`` left as indices + dictionary; None
+    where pyarrow does not import or the file holds no row group.  The
+    footer is parsed once, here, by Arrow (arrow_row_groups hands it on
+    as this module's RowGroupMeta).  The caller closes it
+    (``close(force=True)`` closes the file under it too)."""
     lib = _arrow_reader()
-    if lib is None or not row_groups:
+    if lib is None:
         return None
+    import pyarrow
+
     from .fs import get_fs
 
-    chunks = row_groups[0].chunks
-    as_dictionary = [f.name for f in fields if f.dtype.is_string
-                     and f.name in chunks and chunks[f.name].phys == T_BYTE_ARRAY]
     f = get_fs(path).open(path)
     try:
-        return lib.ParquetFile(f, read_dictionary=as_dictionary)
+        if f.seek(0, os.SEEK_END) <= WHOLE_FILE_BYTES:
+            f.seek(0)
+            whole = f.read()
+            f.close()
+            f = pyarrow.BufferReader(whole)
+        arrow_file = lib.ParquetFile(f)
+        held = {c.path: c.physical_type for c in map(arrow_file.schema.column,
+                                                     range(arrow_file.metadata.num_columns))}
+        as_dictionary = [x.name for x in fields
+                         if x.dtype.is_string and held.get(x.name) == "BYTE_ARRAY"]
+        if as_dictionary:  # said when the reader is made; the parsed footer is handed over
+            arrow_file = lib.ParquetFile(f, metadata=arrow_file.metadata,
+                                         read_dictionary=as_dictionary)
+        if arrow_file.metadata.num_row_groups:
+            return arrow_file
     except BaseException:
         f.close()
         raise
+    f.close()
+    return None
+
+
+_ARROW_PHYSICAL = {"BOOLEAN": T_BOOLEAN, "INT32": T_INT32, "INT64": T_INT64, "INT96": T_INT96,
+                   "FLOAT": T_FLOAT, "DOUBLE": T_DOUBLE, "BYTE_ARRAY": T_BYTE_ARRAY,
+                   "FIXED_LEN_BYTE_ARRAY": T_FLBA}
+# pyarrow's names of the codecs; "LZ4" is its name for the format's LZ4_RAW and for
+# its LZ4 both, so a file of either is read_metadata's
+_ARROW_CODEC = {"UNCOMPRESSED": CODEC_UNCOMPRESSED, "SNAPPY": CODEC_SNAPPY, "GZIP": CODEC_GZIP,
+                "ZSTD": CODEC_ZSTD}
+
+
+def arrow_row_groups(arrow_file) -> Optional[List[RowGroupMeta]]:
+    """read_metadata's row groups, WITHOUT their statistics, from the
+    footer Arrow parsed when ``arrow_file`` (open_arrow_file's) was
+    opened — a small file's footer costs the thrift reader above more
+    than its pages cost Arrow.  None where the file has what this does
+    not spell (a nested column, a codec or type unknown here).
+    Statistics stay read_metadata's: Arrow withholds those whose sort
+    order the footer does not state, which this module's own writer
+    never did."""
+    md = arrow_file.metadata
+    columns = [md.schema.column(j) for j in range(md.num_columns)]
+    if any(c.max_repetition_level or c.max_definition_level > 1 or c.path != c.name
+           or c.physical_type not in _ARROW_PHYSICAL for c in columns):
+        return None
+    out: List[RowGroupMeta] = []
+    for g in range(md.num_row_groups):
+        rg = md.row_group(g)
+        chunks: Dict[str, ChunkMeta] = {}
+        for j, col in enumerate(columns):
+            c = rg.column(j)
+            if c.compression not in _ARROW_CODEC:
+                return None
+            phys = _ARROW_PHYSICAL[col.physical_type]
+            first = c.data_page_offset
+            if c.dictionary_page_offset:
+                first = min(first, c.dictionary_page_offset)
+            chunks[col.path] = ChunkMeta(
+                name=col.path, phys=phys, codec=_ARROW_CODEC[c.compression],
+                num_values=c.num_values, offset=first, total_comp=c.total_compressed_size,
+                max_def=col.max_definition_level,
+                type_length=col.length if phys == T_FLBA else 0)
+        out.append(RowGroupMeta(rows=rg.num_rows, chunks=chunks, index=g,
+                                total_comp=sum(c.total_comp for c in chunks.values())))
+    return out
 
 
 def read_row_group(path: str, row_group: RowGroupMeta, fields: Sequence[Field], capacity: int,
@@ -1001,10 +1072,13 @@ def read_row_group_pieces(path: str, row_group: RowGroupMeta, fields: Sequence[F
 
         # use_threads: the columns of a piece decode side by side on Arrow's pool;
         # on the chip machine every window read better with it, in both file
-        # cells, though a piece is a sixteenth of a row group (PERF.md §6, PR 39)
+        # cells, though a piece is a sixteenth of a row group (PERF.md §6, PR 39).
+        # A row group that is one piece has nothing to run ahead of: waking the
+        # pool for its few pages costs more than they do, the more so where the
+        # machine's cores are busy (PERF.md §6, PR 40)
         pieces = _recut(arrow_file.iter_batches(
             batch_size=piece_rows, row_groups=[row_group.index],
-            columns=[f.name for f in held], use_threads=True), piece_rows)
+            columns=[f.name for f in held], use_threads=row_group.rows > piece_rows), piece_rows)
         while True:
             try:
                 batches = next(pieces, None)

@@ -36,11 +36,21 @@ class FileSplit(NamedTuple):
     as Spark hands it to a task (a ``PartitionedFile``).  The pieces of
     a file tile it, and a row group belongs to the piece that holds its
     midpoint, so each is read once.  ≙ the ``range {start, end}`` the
-    reference's ``NativeParquetScanBase`` puts on each file."""
+    reference's ``NativeParquetScanBase`` puts on each file.
+
+    ``values``: for a file of a Hive-partitioned table, its directory's
+    value of each field of the scan's partition schema, in that order
+    (≙ ``PartitionedFile.partition_values``) — what the column's array
+    holds (an int for an integer, a date's days or a decimal's unscaled
+    digits, bytes for a string), None for
+    ``__HIVE_DEFAULT_PARTITION__``.  Typed, as Spark's
+    ``PartitionedFile.partitionValues`` are: the driver that listed the
+    directories parsed their names, and no executor parses one again."""
 
     path: str
     start: int
     length: int
+    values: tuple = ()
 
 
 #: an entry of a scan's file group: a path is the whole file
@@ -49,6 +59,10 @@ FileEntry = Union[str, FileSplit]
 
 def entry_path(entry: FileEntry) -> str:
     return entry.path if isinstance(entry, FileSplit) else entry
+
+
+def entry_values(entry: FileEntry) -> tuple:
+    return entry.values if isinstance(entry, FileSplit) else ()
 
 
 def split_row_groups(entry: FileEntry,
@@ -147,12 +161,22 @@ class ParquetScanExec(ExecNode):
         schema: Schema,
         predicate: Optional[Expr] = None,
         batch_rows: int = 0,
+        partition_schema: Optional[Schema] = None,
     ):
         super().__init__([])
         # one group a task; an entry is a path (the whole file) or a
         # FileSplit (the row groups whose midpoint lies in its range)
         self.file_groups = [list(g) for g in file_groups]
+        # what the files hold of the table; the columns that live in the
+        # paths follow it in the output, each file's value repeated
         self._schema = schema
+        self.partition_schema = partition_schema or Schema([])
+        n_values = len(self.partition_schema.fields)
+        for entry in (e for g in self.file_groups for e in g):
+            if len(entry_values(entry)) != n_values:
+                raise ValueError(f"{entry!r} carries no value for each of the partition "
+                                 f"columns {self.partition_schema.names}")
+        self._out_schema = Schema(list(schema.fields) + list(self.partition_schema.fields))
         self.predicate = predicate
         # what the plan states travels with it (serde); 0 = it states
         # none, and the executor's spark.blaze.batchSize decides
@@ -164,10 +188,24 @@ class ParquetScanExec(ExecNode):
 
     @property
     def schema(self) -> Schema:
-        return self._schema
+        return self._out_schema
 
     def num_partitions(self) -> int:
         return max(1, len(self.file_groups))
+
+    def narrowed(self, names: Sequence[str]) -> "ParquetScanExec":
+        """This scan reading ``names`` alone: fewer chunks of each file,
+        and of a partitioned table's files the values of the partition
+        columns among them."""
+        kept = [i for i, f in enumerate(self.partition_schema.fields) if f.name in names]
+        groups = self.file_groups
+        if len(kept) < len(self.partition_schema.fields):
+            groups = [[e._replace(values=tuple(e.values[i] for i in kept)) for e in g]
+                      for g in groups]
+        return ParquetScanExec(
+            groups, Schema([f for f in self._schema.fields if f.name in names]),
+            self.predicate, self.stated_batch_rows,
+            Schema([self.partition_schema.fields[i] for i in kept]))
 
     def _null_column(self, dtype: DataType, cap: int) -> Column:
         if dtype.is_string:
@@ -203,8 +241,9 @@ class ParquetScanExec(ExecNode):
     def _pieces(self, files: Sequence[FileEntry]):
         """The decoded pieces of one task's entries, file by file, row
         group by row group: ``(chunks, lo, hi, cut)`` — rows ``[lo, hi)``
-        of ``chunks`` (pq.read_row_group_pieces) and whether the row
-        group is cut into more than one batch."""
+        of ``chunks`` (pq.read_row_group_pieces' for the columns the
+        file holds, then the entry's partition values, each repeated)
+        and whether the row group is cut into more than one batch."""
         for entry in files:
             path = entry_path(entry)
             # one task's open of one file: its footer, which of its
@@ -212,24 +251,55 @@ class ParquetScanExec(ExecNode):
             # where pyarrow imports (closed with the entry, on this
             # thread: no handle, footer or decoded array outlives its task)
             with trace.span("scan_open"):
+                arrow_file = None
                 try:
-                    row_groups = pq.read_metadata(path).row_groups
-                    arrow_file = pq.open_arrow_file(path, self._schema.fields, row_groups)
+                    arrow_file = pq.open_arrow_file(path, self._schema.fields)
+                    # the footer Arrow parsed, where nothing is pruned by the
+                    # statistics it leaves out; else the thrift reader's
+                    row_groups = None
+                    if arrow_file is not None and not self._conjuncts:
+                        row_groups = pq.arrow_row_groups(arrow_file)
+                    if row_groups is None:
+                        row_groups = pq.read_metadata(path).row_groups
                 except Exception:
+                    if arrow_file is not None:
+                        arrow_file.close(force=True)
                     if bool(conf.IGNORE_CORRUPT_FILES.get()):
                         self.metrics.add("skipped_corrupt_files", 1)
                         continue
                     raise
                 mine = split_row_groups(entry, row_groups)
+            values = entry_values(entry)
             dispatch.record("scan_splits")
+            dispatch.record("scan_partition_files", bool(values))
             dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
             try:
                 for rg in mine:
                     if rg.rows and not self._pruned(rg):
-                        yield from self._row_group_pieces(path, rg, arrow_file)
+                        for chunks, lo, hi, cut in self._row_group_pieces(path, rg, arrow_file):
+                            yield chunks + self._partition_arrays(values, hi), lo, hi, cut
             finally:
                 if arrow_file is not None:
                     arrow_file.close(force=True)
+
+    def _partition_arrays(self, values: tuple, rows: int) -> list:
+        """A piece's partition columns as decoded chunks come —
+        ``(data, validity, lengths|None)`` at the capacity of ``rows``,
+        the piece's end in its arrays: each of the file's ``values``
+        repeated, a None all null."""
+        cap = bucket_capacity(rows)
+        out = []
+        for f, value in zip(self.partition_schema.fields, values):
+            col = self._null_column(f.dtype, cap)
+            if value is not None:
+                col.validity[:rows] = True
+                if f.dtype.is_string:
+                    col.data[:rows, :len(value)] = np.frombuffer(value, np.uint8)
+                    col.lengths[:rows] = len(value)
+                else:
+                    col.data[:rows] = value
+            out.append((col.data, col.validity, col.lengths))
+        return out
 
     def _pruned(self, rg: pq.RowGroupMeta) -> bool:
         """Whether the chunk statistics rule the row group out, decided
@@ -293,19 +363,71 @@ class ParquetScanExec(ExecNode):
         dispatch.record("scan_pages_python_codec", decoded["pages_python_codec"])
 
     def _batches(self, pieces):
-        """Each piece as host batches of at most ``batch_rows`` rows, on
-        the staging thread where the scan is pipelined: a streamed
-        piece, or a row group no longer than a batch, is its arrays as
-        they are; a longer row group decoded whole is sliced.
-        ``scan_slice`` is around each batch of a row group that is cut
-        into several, closed before the yield."""
-        for chunks, lo, hi, cut in pieces:
-            for s in range(lo, hi, self.batch_rows):
-                e = min(s + self.batch_rows, hi)
-                with trace.span("scan_slice") if cut else contextlib.nullcontext():
-                    b = self._host_batch(chunks, s, e, whole=(s, e) == (0, hi))
-                self._record_batch(b)
-                yield b
+        """The pieces as host batches of at most ``batch_rows`` rows, on
+        the staging thread where the scan is pipelined.  ONE rule packs
+        them: whole pieces join the open batch while it stays within
+        ``batch_rows`` rows — across row groups and across the files of
+        the task — and the piece that would take it past that closes it;
+        a piece is never split to fill one.  So a piece of ``batch_rows``
+        rows passes through as its arrays are, a task of many small
+        files stages a batch or two and not one a file, and what is held
+        open is under ``batch_rows`` rows.  A row group decoded whole and
+        longer than a batch is sliced.  ``scan_slice`` is around each
+        batch of a row group that is cut into several, ``scan_coalesce``
+        around the assembly of a batch of several pieces, both closed
+        before the yield."""
+        held, held_rows = [], 0
+        tally = collections.Counter()
+        try:
+            for chunks, lo, hi, cut in pieces:
+                if held and held_rows + hi - lo > self.batch_rows:
+                    yield self._packed(held, tally)
+                    held, held_rows = [], 0
+                if hi - lo < self.batch_rows:
+                    held.append((chunks, lo, hi, cut, lo == 0))
+                    held_rows += hi - lo
+                    continue
+                for s in range(lo, hi, self.batch_rows):
+                    e = min(s + self.batch_rows, hi)
+                    yield self._packed([(chunks, s, e, cut, (s, e) == (0, hi))], tally)
+            if held:
+                yield self._packed(held, tally)
+        finally:
+            # what the staged batches held and what they could have: a
+            # reader of the counters sees no configuration
+            dispatch.record("scan_rows", tally["rows"])
+            dispatch.record("scan_rows_budget", tally["batches"] * self.batch_rows)
+            dispatch.record("scan_pieces_packed", tally["pieces_packed"])
+
+    def _packed(self, held, tally) -> RecordBatch:
+        """One host batch of ``held`` — rows ``[lo, hi)`` of ``chunks``
+        each, in order, as ``(chunks, lo, hi, cut, whole)``, ``whole``
+        where those rows are all the arrays hold: a single one is its
+        rows as _host_batch hands them on; several are copied side by
+        side into arrays of their rows' capacity."""
+        if len(held) == 1:
+            chunks, lo, hi, cut, whole = held[0]
+            with trace.span("scan_slice") if cut else contextlib.nullcontext():
+                b = self._host_batch(chunks, lo, hi, whole)
+        else:
+            with trace.span("scan_coalesce"):
+                rows = sum(hi - lo for _, lo, hi, _, _ in held)
+                cols = [self._null_column(f.dtype, bucket_capacity(rows))
+                        for f in self._out_schema.fields]
+                at = 0
+                for chunks, lo, hi, _, _ in held:
+                    for col, arrays in zip(cols, chunks):
+                        if arrays is not None:  # a column the file lacks stays null
+                            for to, a in zip((col.data, col.validity, col.lengths), arrays):
+                                if a is not None:
+                                    to[at:at + hi - lo] = a[lo:hi]
+                    at += hi - lo
+                b = RecordBatch(self._out_schema, cols, rows)
+            tally["pieces_packed"] += len(held)
+        tally["rows"] += b.num_rows
+        tally["batches"] += 1
+        self._record_batch(b)
+        return b
 
     def _host_batch(self, chunks, lo: int, hi: int, whole: bool) -> RecordBatch:
         """Rows ``[lo, hi)`` of decoded ``chunks`` at their own capacity:
@@ -314,7 +436,7 @@ class ParquetScanExec(ExecNode):
         column is nulls."""
         cap = bucket_capacity(hi - lo)
         cols: List[Column] = []
-        for f, arrays in zip(self._schema.fields, chunks):
+        for f, arrays in zip(self._out_schema.fields, chunks):
             if arrays is None:
                 cols.append(self._null_column(f.dtype, cap))
                 continue
@@ -322,4 +444,4 @@ class ParquetScanExec(ExecNode):
                 arrays = [None if a is None else _pad_1d(np.ascontiguousarray(a[lo:hi]), cap)
                           for a in arrays]
             cols.append(Column(f.dtype, *arrays))
-        return RecordBatch(self._schema, cols, hi - lo)
+        return RecordBatch(self._out_schema, cols, hi - lo)

@@ -104,9 +104,11 @@ def _narrow(child, needed: Set[str]):
         names = [child.schema.names[0]]
     if len(names) == len(child.schema.names):
         return child
-    if isinstance(child, (ParquetScanExec, OrcScanExec)):
+    if isinstance(child, ParquetScanExec):
+        return child.narrowed(names)
+    if isinstance(child, OrcScanExec):
         narrowed = Schema([child.schema.field(n) for n in names])
-        return type(child)(
+        return OrcScanExec(
             child.file_groups, narrowed, child.predicate, child.stated_batch_rows
         )
     return ProjectExec(child, [Col(n) for n in names], names)

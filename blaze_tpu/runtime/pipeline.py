@@ -30,8 +30,9 @@ hand-over's alone.  Who runs where in the Parquet scan
 - ``blaze-parquet_decode``: open, choice of row groups, decode and
   conversion, piece by piece; every use of an Arrow file, its close too.
   Waits in ``decode_full`` for
-- ``blaze-parquet_scan``: each piece's host batches (``scan_slice``) and
-  their staging (``scan_stage``).  Waits in ``decode_wait`` for the
+- ``blaze-parquet_scan``: the pieces' host batches (``scan_slice``; the
+  packing of short pieces, ``scan_coalesce``) and their staging
+  (``scan_stage``).  Waits in ``decode_wait`` for the
   thread above, in ``pipeline_full`` for
 - the task thread: launches, reads, exchange.  Waits in
   ``pipeline_wait`` for the thread above.

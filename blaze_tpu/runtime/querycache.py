@@ -105,7 +105,8 @@ def _node_part(node, slots: list, sources: list, exact: list):
 
         from ..ops.parquet_scan import entry_path
 
-        # an entry is a path or a FileSplit: a range is part of the key
+        # an entry is a path or a FileSplit: a range and a partitioned
+        # file's values are part of the key
         paths = tuple(tuple(g) for g in node.file_groups)
         for g in node.file_groups:
             for p in map(entry_path, g):
@@ -115,7 +116,7 @@ def _node_part(node, slots: list, sources: list, exact: list):
                     raise _Uncacheable(p)
                 sources.append(("file", p, st.st_mtime_ns, st.st_size))
         pred = getattr(node, "predicate", None)
-        return (name, paths, schema_key(node._schema),
+        return (name, paths, schema_key(node.schema),
                 None if pred is None else expr_key(pred), node.batch_rows)
 
     if isinstance(node, FilterExec):

@@ -66,6 +66,11 @@ def _lit_from_proto(l: pb.LiteralValue) -> Lit:
     return Lit(l.int_value, t)
 
 
+def _partition_value_from_proto(l: pb.LiteralValue):
+    """to_proto._partition_value_to_proto's value back."""
+    return None if l.is_null else getattr(l, l.WhichOneof("value"))
+
+
 def expr_from_proto(n: pb.ExprNode) -> Expr:
     kind = n.WhichOneof("expr")
     if kind == "column":
@@ -191,7 +196,13 @@ def plan_from_proto(n: pb.PhysicalPlanNode):
                 groups = [[path if length < 0 else FileSplit(path, start, length)
                            for path, start, length in zip(g, r.start, r.length, strict=True)]
                           for g, r in zip(groups, s.file_ranges, strict=True)]
-            return ParquetScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows)
+            if s.partition_values:  # none: the table is not partitioned
+                # a file with values is a FileSplit, so file_ranges held its range
+                groups = [[e._replace(values=tuple(map(_partition_value_from_proto, f.values)))
+                           for e, f in zip(g, p.files, strict=True)]
+                          for g, p in zip(groups, s.partition_values, strict=True)]
+            return ParquetScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows,
+                                   schema_from_proto(s.partition_schema))
         from ..ops.orc_scan import OrcScanExec
 
         return OrcScanExec(groups, schema_from_proto(s.schema), pred, s.batch_rows)

@@ -9,6 +9,7 @@ plans to worker processes.
 
 from __future__ import annotations
 
+import contextlib
 import datetime
 import pickle
 from typing import Optional
@@ -36,6 +37,36 @@ _memscan_rids = itertools.count()
 STAGED_RIDS: contextvars.ContextVar = contextvars.ContextVar(
     "blaze_staged_rids", default=None
 )
+
+# The partition a task's plan is being serialised for (task_definition
+# sets it), None for a whole plan.  A file scan reached from the root
+# through operators that run every child at their own partition then
+# carries that partition's file group alone, the other groups empty in
+# their places (≙ NativeParquetScanBase: a task's native plan names its
+# own FilePartition's files).  What a task decodes, fingerprints and
+# estimates is then its own files, not the table's: a date-partitioned
+# table lists hundreds of files a query, and every task paid two stats
+# a file of all of them (PERF.md section 6, PR 40).
+TASK_PARTITION: contextvars.ContextVar = contextvars.ContextVar(
+    "blaze_task_partition", default=None
+)
+#: operators whose execute(p) runs each child at p and no other
+#: partition; under any other, a scan keeps every group
+_CHILDREN_AT_OWN_PARTITION = frozenset({
+    "ParquetScanExec", "OrcScanExec", "ProjectExec", "FilterExec", "AggExec", "SortExec",
+    "LimitExec", "RenameColumnsExec", "DebugExec", "CoalesceBatchesExec", "ExpandExec",
+    "GenerateExec", "WindowExec", "HashJoinExec", "SortMergeJoinExec", "ShuffleWriterExec",
+    "IpcWriterExec", "BroadcastJoinExec",  # its probe side; its build side is read whole
+})
+
+
+@contextlib.contextmanager
+def _for_partition(partition: Optional[int]):
+    token = TASK_PARTITION.set(partition)
+    try:
+        yield
+    finally:
+        TASK_PARTITION.reset(token)
 
 
 def dtype_to_proto(t: DataType) -> pb.DataTypeProto:
@@ -88,6 +119,23 @@ def _lit_to_proto(e: Lit) -> pb.LiteralValue:
         if isinstance(v, datetime.date):
             v = (v - datetime.date(1970, 1, 1)).days
         out.int_value = int(v)
+    else:
+        out.int_value = int(v)
+    return out
+
+
+def _partition_value_to_proto(v, t: DataType) -> pb.LiteralValue:
+    """A file's value of a partition column (``FileSplit.values``: what
+    the column's array holds, None for a null) as a typed literal."""
+    out = pb.LiteralValue(dtype=dtype_to_proto(t))
+    if v is None:
+        out.is_null = True
+    elif t.kind == TypeKind.BOOL:
+        out.bool_value = bool(v)
+    elif t.is_string:
+        out.bytes_value = bytes(v)
+    elif t.is_float:
+        out.float_value = float(v)
     else:
         out.int_value = int(v)
     return out
@@ -198,6 +246,13 @@ def _partitioning_to_proto(p) -> pb.PartitioningProto:
 
 
 def plan_to_proto(node) -> pb.PhysicalPlanNode:
+    if TASK_PARTITION.get() is None or type(node).__name__ in _CHILDREN_AT_OWN_PARTITION:
+        return _node_to_proto(node)
+    with _for_partition(None):
+        return _node_to_proto(node)
+
+
+def _node_to_proto(node) -> pb.PhysicalPlanNode:
     from ..ops import (
         AggExec, CoalesceBatchesExec, DebugExec, EmptyPartitionsExec, ExpandExec,
         FilterExec, GenerateExec, LimitExec, MemoryScanExec, OrcScanExec,
@@ -240,17 +295,29 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
         out.memory_scan.num_partitions = node.num_partitions()
     elif isinstance(node, (ParquetScanExec, OrcScanExec)):
         sub = out.parquet_scan if isinstance(node, ParquetScanExec) else out.orc_scan
-        sub.schema.CopyFrom(schema_to_proto(node.schema))
-        for g in node.file_groups:
+        # what the files hold: a partitioned table's path columns travel apart
+        sub.schema.CopyFrom(schema_to_proto(node._schema))
+        groups, own = node.file_groups, TASK_PARTITION.get()
+        if own is not None:
+            groups = [g if p == own else [] for p, g in enumerate(groups)]
+        for g in groups:
             sub.file_groups.append(";".join(entry_path(e) for e in g))
         if any(isinstance(e, FileSplit) for g in node.file_groups for e in g):
             # OrcScanExec admits no split, so this is a Parquet scan's
-            for g in node.file_groups:
+            for g in groups:
                 ranges = sub.file_ranges.add()
                 for e in g:
                     ranged = isinstance(e, FileSplit)
                     ranges.start.append(e.start if ranged else 0)
                     ranges.length.append(e.length if ranged else -1)
+        if isinstance(node, ParquetScanExec) and node.partition_schema.fields:
+            sub.partition_schema.CopyFrom(schema_to_proto(node.partition_schema))
+            for g in groups:
+                files = sub.partition_values.add().files
+                for e in g:
+                    files.add().values.extend(
+                        _partition_value_to_proto(v, f.dtype)
+                        for v, f in zip(e.values, node.partition_schema.fields, strict=True))
         if node.predicate is not None:
             sub.predicate.add().CopyFrom(expr_to_proto(node.predicate))
         sub.batch_rows = node.stated_batch_rows
@@ -325,7 +392,9 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
         out.ipc_writer.ipc_consumer_resource_id = node.resource_id
     elif isinstance(node, (BroadcastJoinExec, HashJoinExec)):
         dst = out.broadcast_join if isinstance(node, BroadcastJoinExec) else out.hash_join
-        dst.build.CopyFrom(plan_to_proto(node.children[0]))
+        # a broadcast join collects every partition of its build side
+        with _for_partition(None) if isinstance(node, BroadcastJoinExec) else contextlib.nullcontext():
+            dst.build.CopyFrom(plan_to_proto(node.children[0]))
         dst.probe.CopyFrom(plan_to_proto(node.children[1]))
         for e in node.build_keys:
             dst.build_keys.add().CopyFrom(expr_to_proto(e))
@@ -426,8 +495,9 @@ def plan_to_proto(node) -> pb.PhysicalPlanNode:
 
 
 def task_definition(plan, task_id: str, stage_id: int, partition: int) -> bytes:
-    td = pb.TaskDefinition(
-        task_id=task_id, stage_id=stage_id, partition=partition,
-        plan=plan_to_proto(plan),
-    )
+    with _for_partition(partition):
+        td = pb.TaskDefinition(
+            task_id=task_id, stage_id=stage_id, partition=partition,
+            plan=plan_to_proto(plan),
+        )
     return td.SerializeToString()

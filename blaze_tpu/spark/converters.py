@@ -305,6 +305,22 @@ def convert_exec(node: SparkNode, ctx: ConversionContext) -> ExecNode:
     return fn(node, ctx)
 
 
+def _filter_columns(filters: List[SparkNode]) -> List[str]:
+    """The columns a scan's partition filters name.  The inside of a
+    ``DynamicPruningExpression`` is Spark's — the pruning key and the
+    broadcast subquery that fills it at run time — and is not read: the
+    rule that plants one (``PartitionPruning``) does so on a partition
+    column only."""
+    names, todo = [], list(filters)
+    while todo:
+        e = todo.pop()
+        if e.name == "AttributeReference":
+            names.append(_attr_user_name(e))
+        elif e.name != "DynamicPruningExpression":
+            todo.extend(e.children)
+    return names
+
+
 def _convert_scan(node: SparkNode, ctx: ConversionContext) -> ExecNode:
     """FileSourceScanExec: resolve the relation through the catalog
     (≙ NativeParquetScanBase building FileGroups from the relation),
@@ -317,15 +333,27 @@ def _convert_scan(node: SparkNode, ctx: ConversionContext) -> ExecNode:
         table = ident.split(".")[-1]
     if table is None or table not in ctx.catalog:
         raise UnsupportedSparkExec(f"scan relation {ident!r} not in catalog")
-    # partition filters are enforced at the scan in Spark (FilterExec
-    # above the scan re-applies only the data filters) — dropping them
-    # silently returns rows from pruned partitions, so fall back
-    pf = node.fields.get("partitionFilters")
-    if isinstance(pf, list) and pf:
-        raise UnsupportedSparkExec(
-            f"FileSourceScanExec with {len(pf)} partitionFilters"
-        )
     scan = ctx.catalog[table]
+    # partition filters are enforced at the scan in Spark (FilterExec
+    # above the scan re-applies only the data filters), and where Spark
+    # enforces them is the LISTING: FileSourceScanExec's
+    # dynamicallySelectedPartitions keeps the directories that pass, so
+    # a relation registered with a partition schema was handed the
+    # selected files (as NativeParquetScanBase is) and the filters are
+    # spent.  One that names a data column, or a relation whose files
+    # were not listed under partition columns, cannot have been applied
+    # that way — dropping it would return rows it rules out: fall back
+    partition_columns = getattr(scan, "partition_schema", Schema([])).names
+    filters = node.expr_list("partitionFilters")
+    if filters and not partition_columns:
+        raise UnsupportedSparkExec(
+            f"FileSourceScanExec of {table!r} with {len(filters)} partitionFilters, and the "
+            f"relation is registered with no partition schema")
+    for column in _filter_columns(filters):
+        if column not in partition_columns:
+            raise UnsupportedSparkExec(
+                f"FileSourceScanExec of {table!r}: partition filter on {column!r}, which is "
+                f"no partition column of the registered relation {partition_columns}")
     attrs = node.expr_list("output")
     exprs, names = [], []
     for a in attrs:

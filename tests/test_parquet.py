@@ -480,9 +480,9 @@ def test_scan_counts_pages_and_the_codec_that_ran(tmp_path, monkeypatch, decoder
 
 @pytest.mark.parametrize("batch_rows,sliced", [(8192, True), (1 << 20, False)])
 def test_scan_slices_under_a_span_and_hands_every_batch_over(tmp_path, batch_rows, sliced):
-    """One scan_slice span a batch cut from a row group, none where a row
-    group is yielded whole; the pipelined scan hands over, and stages,
-    exactly the batches it made."""
+    """One scan_slice span a batch cut from a row group, none where row
+    groups shorter than a batch are packed into one (PR 40); the
+    pipelined scan hands over, and stages, exactly the batches it made."""
     from blaze_tpu.runtime import dispatch
 
     path, schema, _ = _mixed_file(tmp_path)
@@ -490,13 +490,17 @@ def test_scan_slices_under_a_span_and_hands_every_batch_over(tmp_path, batch_row
     scan = ParquetScanExec([[path]], schema, batch_rows=batch_rows)
     with dispatch.capture() as c:
         batches = list(scan.execute(0, TaskContext(0, 1)))
-    want = sum(-(-r // batch_rows) for r in rows) if sliced else len(rows)
+    want = sum(-(-r // batch_rows) for r in rows) if sliced else 1
     assert len(batches) == want == c["pipeline_items"] == c["scan_stage_n"]
     assert c.get("scan_slice_n", 0) == (want if sliced else 0)
+    assert c["scan_rows"] == sum(rows) and c["scan_rows_budget"] == want * batch_rows
     if sliced:
         assert want > len(rows) and c["scan_slice_ns"] > 0
+        # each row group's tail is a piece of its own: two never fit a batch here
+        assert (c.get("scan_coalesce_n", 0), c["scan_pieces_packed"]) == (0, 0)
     else:
         assert "scan_slice_ns" not in c
+        assert (c["scan_coalesce_n"], c["scan_pieces_packed"]) == (1, len(rows))
 
 
 def test_scan_batches_equal_the_decoded_row_group(tmp_path):
@@ -520,7 +524,7 @@ def decoders_agree(path, schema, capacity=None):
     page decoder alone, then with Arrow's reader — equal in dtype, shape
     and every byte, padding included.  Returns the second run's tally."""
     meta = pq.read_metadata(path)
-    arrow_file = pq.open_arrow_file(path, schema.fields, meta.row_groups)
+    arrow_file = pq.open_arrow_file(path, schema.fields)
     assert arrow_file is not None
     tally = collections.Counter()
     try:
@@ -644,7 +648,7 @@ def test_strings_longer_than_the_width_are_cut_the_same(tmp_path, width, use_dic
     tally = decoders_agree(path, schema, capacity=8_192)  # the row group holds 5,000
     assert tally["chunks_native"] == tally["chunks"] == 1
     rg = pq.read_metadata(path).row_groups[0]
-    arrow_file = pq.open_arrow_file(path, schema.fields, [rg])
+    arrow_file = pq.open_arrow_file(path, schema.fields)
     try:
         ((data, validity, lengths),) = pq.read_row_group(path, rg, schema.fields, 8_192, arrow_file=arrow_file)
     finally:
@@ -799,24 +803,36 @@ WHOLE = {how: (lambda tmp, how=how: _not_taken_file(tmp, how)) for how in ("int9
 
 def _whole_read_batches(path, schema, batch_rows):
     """The reference: each row group decoded WHOLE by the page decoder
-    alone, then cut in ``batch_rows`` steps by hand — (rows, capacity,
-    every buffer's bytes) a batch."""
-    out = []
+    alone, cut in ``batch_rows`` steps by hand, and (PR 40) neighbouring
+    cuts shorter than ``batch_rows`` joined while they fit one batch —
+    (rows, capacity, every buffer's bytes) a batch."""
+    cuts = []  # each: every buffer's own rows, unpadded
     for rg in pq.read_metadata(path).row_groups:
         chunks = pq.read_row_group(path, rg, schema.fields, bucket_capacity(rg.rows))
         for s in range(0, rg.rows, batch_rows):
             e = min(s + batch_rows, rg.rows)
-            cap = bucket_capacity(e - s)
-            buffers = []
-            for arrays in chunks:
-                for a in arrays:
-                    if a is None:
-                        buffers.append(None)
-                        continue
-                    padded = np.zeros((cap,) + a.shape[1:], a.dtype)
-                    padded[: e - s] = a[s:e]
-                    buffers.append((padded.dtype, padded.shape, padded.tobytes()))
-            out.append((e - s, cap, buffers))
+            cuts.append([None if a is None else a[s:e] for arrays in chunks for a in arrays])
+    joined = []
+    for cut in cuts:
+        rows = len(cut[1])  # a validity
+        if joined and len(joined[-1][1]) + rows <= batch_rows and rows < batch_rows \
+                and len(joined[-1][1]) < batch_rows:
+            joined[-1] = [None if a is None else np.concatenate([a, b]) for a, b in zip(joined[-1], cut)]
+        else:
+            joined.append(cut)
+    out = []
+    for cut in joined:
+        rows = len(cut[1])
+        cap = bucket_capacity(rows)
+        buffers = []
+        for a in cut:
+            if a is None:
+                buffers.append(None)
+                continue
+            padded = np.zeros((cap,) + a.shape[1:], a.dtype)
+            padded[:rows] = a
+            buffers.append((padded.dtype, padded.shape, padded.tobytes()))
+        out.append((rows, cap, buffers))
     return out
 
 
@@ -853,13 +869,18 @@ def test_the_streamed_scan_hands_on_the_whole_reads_batches(tmp_path, case, batc
     want = _whole_read_batches(path, schema, batch_rows)
     with dispatch.capture() as c:
         got = _scan_batches(ParquetScanExec([[path]], schema, batch_rows=batch_rows))
-    assert len(got) == len(want) == sum(-(-rg.rows // batch_rows) for rg in row_groups)
+    unpacked = sum(-(-rg.rows // batch_rows) for rg in row_groups)
+    # row groups under a batch each join: the whole file is one batch of 2^20 rows
+    assert len(got) == len(want) <= unpacked and (batch_rows == 1024 or len(want) == 1)
+    assert c["scan_rows"] == sum(rg.rows for rg in row_groups)
+    assert c["scan_rows_budget"] == len(want) * batch_rows
     for k, (mine, theirs) in enumerate(zip(got, want)):
         assert mine[:2] == theirs[:2], k
         assert mine[2] == theirs[2], k
     assert c["scan_decode_n"] == c["scan_row_groups"] == len(row_groups)
     assert c["scan_row_groups_streamed"] == (len(row_groups) if case in STREAMED else 0)
-    assert c["scan_pieces"] == c["decode_items"] == (len(want) if case in STREAMED else len(row_groups))
+    assert c["scan_pieces"] == c["decode_items"] == (unpacked if case in STREAMED else len(row_groups))
+    assert c["scan_pieces_packed"] == (0 if len(want) == unpacked else c["scan_pieces"] - len(want) + c["scan_coalesce_n"])
     assert c["pipeline_items"] == c["scan_stage_n"] == len(want)
 
 
@@ -923,6 +944,10 @@ class _WatchedArrowFile:
     @property
     def schema_arrow(self):
         return self._f.schema_arrow
+
+    @property
+    def metadata(self):
+        return self._f.metadata
 
     def read_row_group(self, *args, **kwargs):
         self._note("read_row_group")
@@ -1055,7 +1080,8 @@ def test_a_decode_error_reaches_the_consumer_as_it_was_raised(tmp_path, monkeypa
         m.setattr("blaze_tpu.conf.PIPELINE_DEPTH.get", lambda: 0)
         sync_rows, sync = drive()
     assert type(piped) is type(sync) and str(piped) == str(sync)
-    assert piped_rows == sync_rows == ([] if how == "truncated" else [8192, 8192, 8192, 424])
+    # the first row group's tail of 424 rows was held open for the piece that failed
+    assert piped_rows == sync_rows == ([] if how == "truncated" else [8192, 8192, 8192])
     assert all(f.closed for f in files) and len(files) == (0 if how == "truncated" else 2)
 
 
