@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +56,12 @@ class JoinMap:
     form carries the sorted table, its run lengths, its bucket offsets
     AND the data batch, so a probe-side executor rebuilds it with buffer
     copies only — no re-sort, no key re-hash, no run-length or bucket
-    pass."""
+    pass.
+
+    Beside the pytree, on the host: the largest candidate total the map
+    has shown for each probe-batch capacity, which picks the probe's
+    output bucket before that batch's own total is read.  Neither a
+    leaf nor serialized: a rebuilt or deserialized map starts empty."""
 
     sorted_keys: jnp.ndarray     # uint64 (cap,) sorted
     sorted_rows: jnp.ndarray     # int32 (cap,) original row per key
@@ -65,6 +70,24 @@ class JoinMap:
     max_bucket: jnp.ndarray      # int32 () live keys in the largest bucket
     num_rows: int                # live build rows (static)
     batch: RecordBatch           # build-side data
+
+    #: guarded-by declaration (analysis/guarded.py): the tasks that
+    #: share a broadcast map raise its peaks without a lock
+    LOCK_FREE = {"_candidate_peaks": "max-only update: a lost race keeps "
+                                     "a lower peak, which costs one probe "
+                                     "re-launch, never a row"}
+
+    def __post_init__(self):
+        self._candidate_peaks: Dict[int, int] = {}
+
+    def candidate_peak(self, probe_capacity: int) -> Optional[int]:
+        """The largest candidate total a probe batch of this capacity
+        has shown against this map; None before the first."""
+        return self._candidate_peaks.get(probe_capacity)
+
+    def raise_candidate_peak(self, probe_capacity: int, total: int) -> None:
+        if total > self._candidate_peaks.get(probe_capacity, -1):
+            self._candidate_peaks[probe_capacity] = total
 
     def tree_flatten(self):
         return ((self.sorted_keys, self.sorted_rows, self.run_lens,
@@ -323,10 +346,12 @@ class Joiner:
     """Build/probe driver for one join exec instance.  Kernels compile
     once per (schema, capacity) via instance-owned jitted closures.  A
     probe batch searches the key table once, in the candidate program,
-    which hands ``lo``/``counts`` to the probe program on the device;
-    the host syncs the candidate total (it picks the output bucket),
-    then the count of rows it emits, and the unmatched count where the
-    probe side is preserved."""
+    which hands ``lo``/``counts`` to the probe program on the device.
+    The probe program's output bucket comes from the largest candidate
+    total the map has shown at the batch's capacity, and the host reads
+    this batch's total with the count of rows it emits; the first batch
+    of a capacity reads its total first, to pick the bucket.  The
+    unmatched count follows where the probe side is preserved."""
 
     def __init__(
         self,
@@ -460,43 +485,53 @@ class Joiner:
         head, lo, counts = self._candidate_kernel(
             tuple(batch.columns), jmap.sorted_keys, jmap.run_lens,
             jmap.bucket_offsets, jmap.max_bucket, batch.num_rows)
-        with trace.span("device_read"):  # the round trip that picks out_cap
-            cand, steps = np.asarray(head).tolist()
+        peak = jmap.candidate_peak(batch.capacity)
+        if peak is None:
+            with trace.span("device_read"):  # the round trip that picks out_cap
+                cand, steps = np.asarray(head).tolist()
+            out_cols, count, vcounts, matched = self._launch_probe(
+                jmap, batch, lo, counts, bucket_capacity(max(1, cand)))
+            n = None if count is None else trace.read_scalar(count)
+        else:
+            # the bucket this map needed at this capacity before: the
+            # probe program queues behind the candidate program, and the
+            # candidate total rides the read of what the probe emits
+            dispatch.record("join_outcap_predicted")
+            out_cap = bucket_capacity(max(1, peak))
+            out_cols, count, vcounts, matched = self._launch_probe(
+                jmap, batch, lo, counts, out_cap)
+            with trace.span("device_read"):
+                got_head, got = jax.device_get((head, count))
+            cand, steps = got_head.tolist()
+            n = None if got is None else int(got)
+            if cand > out_cap:
+                # the pairs were cut at out_cap: nothing of that launch
+                # is used; the search's lo/counts serve the re-launch
+                dispatch.record("join_outcap_redo")
+                out_cols, count, vcounts, matched = self._launch_probe(
+                    jmap, batch, lo, counts, bucket_capacity(cand))
+                n = None if count is None else trace.read_scalar(count)
         dispatch.record("join_search_steps", steps)
-        out_cap = bucket_capacity(max(1, cand))
-        pair_cols, pair_count, vcounts, matched = self._probe_kernel(
-            tuple(batch.columns), jmap, lo, counts, out_cap
-        )
+        jmap.raise_candidate_peak(batch.capacity, cand)
         if self._need_matched:
             state.matched_build = (
                 matched if state.matched_build is None else (state.matched_build | matched)
             )
 
-        semi_like = jt in (
-            JoinType.LEFT_SEMI, JoinType.LEFT_ANTI, JoinType.RIGHT_SEMI,
-            JoinType.RIGHT_ANTI, JoinType.EXISTENCE,
-        )
-        if semi_like:
+        if jt == JoinType.EXISTENCE:
             has = vcounts > 0
-            live = jnp.arange(batch.capacity) < batch.num_rows
-            if jt == JoinType.EXISTENCE:
-                cols = list(batch.columns) + [
-                    Column(DataType.bool_(), has, jnp.ones_like(has))
-                ]
-                return RecordBatch(self.out_schema, cols, batch.num_rows)
-            if jt in (JoinType.RIGHT_SEMI, JoinType.RIGHT_ANTI):
-                return None  # emitted from build side at finish
-            want = has if jt == JoinType.LEFT_SEMI else ~has
-            out_cols, count = self._compact_kernel(tuple(batch.columns), want & live)
-            n = trace.read_scalar(count)
+            cols = list(batch.columns) + [Column(DataType.bool_(), has, jnp.ones_like(has))]
+            return RecordBatch(self.out_schema, cols, batch.num_rows)
+        if jt in (JoinType.RIGHT_SEMI, JoinType.RIGHT_ANTI):
+            return None  # emitted from build side at finish
+        if jt in (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI):
             return RecordBatch(self.out_schema, list(out_cols), n) if n else None
 
-        n = trace.read_scalar(pair_count)
         parts: List[RecordBatch] = []
         if n:
             np_ = len(batch.columns)
-            probe_side = list(pair_cols[:np_])
-            build_side = list(pair_cols[np_:])
+            probe_side = list(out_cols[:np_])
+            build_side = list(out_cols[np_:])
             cols = probe_side + build_side if self.probe_is_left else build_side + probe_side
             parts.append(RecordBatch(self.out_schema, cols, n))
         if self._probe_outer:
@@ -510,6 +545,23 @@ class Joiner:
         if not parts:
             return None
         return parts[0] if len(parts) == 1 else concat_batches(parts)
+
+    def _launch_probe(self, jmap: JoinMap, batch: RecordBatch, lo, counts, out_cap: int):
+        """The probe program at ``out_cap`` and, for a left semi/anti
+        join, the compaction it feeds: (the columns emitted, the device
+        count of their rows — None where the join reads none —, the
+        per-probe-row match counts, the build rows matched)."""
+        jt = self.join_type
+        out_cols, count, vcounts, matched = self._probe_kernel(
+            tuple(batch.columns), jmap, lo, counts, out_cap)
+        if jt in (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI):
+            has = vcounts > 0
+            live = jnp.arange(batch.capacity) < batch.num_rows
+            want = has if jt == JoinType.LEFT_SEMI else ~has
+            out_cols, count = self._compact_kernel(tuple(batch.columns), want & live)
+        elif jt in (JoinType.EXISTENCE, JoinType.RIGHT_SEMI, JoinType.RIGHT_ANTI):
+            count = None
+        return out_cols, count, vcounts, matched
 
     def finish(self, jmap: JoinMap, state: JoinerState) -> Optional[RecordBatch]:
         """Emit build-side rows for right/full outer and build-side

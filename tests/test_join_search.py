@@ -2,7 +2,9 @@
 key table per probe batch, in the candidate program alone, begun inside
 the bucket of the probe key's hash prefix; the bucket offsets and a
 range's length (the key's run length) are what the build kernel kept in
-the JoinMap.
+the JoinMap.  The probe's output bucket comes from the largest candidate
+total the map has shown at the batch's capacity, checked in the read of
+what the probe emits.
 """
 
 import jax
@@ -10,12 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from blaze_tpu.batch import batch_from_pydict
+from blaze_tpu.batch import batch_from_pydict, batch_to_pydict
 from blaze_tpu.exprs import col
 from blaze_tpu.ops.joins.core import (
     _SENTINEL,
-    JoinType,
     Joiner,
+    JoinerState,
+    JoinMap,
+    JoinType,
     bucket_offsets,
     build_join_map,
     expand_pairs,
@@ -23,6 +27,7 @@ from blaze_tpu.ops.joins.core import (
     probe_counts,
     run_lengths,
 )
+from blaze_tpu.runtime import dispatch
 from blaze_tpu.schema import DataType, Field, Schema
 
 SENT = np.uint64(_SENTINEL)
@@ -307,3 +312,124 @@ def test_the_key_table_is_searched_once_a_probe_batch():
         lambda c, m, a, b: j._probe_kernel.__wrapped__(c, m, a, b, out_cap=1024)
     )(cols, jmap, lo, counts)
     assert _loops(probe_jaxpr.jaxpr) == 1
+
+
+# ------------------------------------------ the output bucket from the map
+
+PROBE_P = Schema([Field("pk", DataType.int64()), Field("p", DataType.int32())])
+BUILD_B = Schema([Field("bk", DataType.int64()), Field("b", DataType.int32())])
+
+#: join type (the probe side is the left) -> (reads of a map's first
+#: batch at a capacity, reads of a later one): the candidate total, then
+#: what the probe emits, then the unmatched rows where the probe side is
+#: kept; a later batch reads the total with what the probe emits.
+#: EXISTENCE reads the total alone either way, before or after the probe
+PREDICTED_READS = {
+    "inner": (JoinType.INNER, 2, 1),
+    "left_probe_preserved": (JoinType.LEFT, 3, 2),
+    "right_build_preserved": (JoinType.RIGHT, 2, 1),
+    "full": (JoinType.FULL, 3, 2),
+    "left_semi": (JoinType.LEFT_SEMI, 2, 1),
+    "left_anti": (JoinType.LEFT_ANTI, 2, 1),
+    "existence": (JoinType.EXISTENCE, 1, 1),
+}
+
+
+def _run_map_build():
+    """Key 7 a run of 600 rows, keys 10..109 once, NULL keys beside."""
+    keys = [7] * 600 + list(range(10, 110)) + [None] * 9
+    return batch_from_pydict({"bk": keys, "b": list(range(len(keys)))}, BUILD_B)
+
+
+def _probe_batch(keys):
+    return batch_from_pydict({"pk": keys, "p": list(range(len(keys)))}, PROBE_P)
+
+
+def _rows(out):
+    if out is None:
+        return []
+    d = batch_to_pydict(out)
+    return list(zip(*d.values()))
+
+
+def _probe_counted(j, jmap, batch, state):
+    with dispatch.capture() as c:
+        out = j.probe_batch(jmap, batch, state)
+    return _rows(out), c
+
+
+def _two_read_rows(j, build, batches):
+    """Each batch probed on a fresh map (the two-read path), one state
+    across them, then what ``finish`` emits."""
+    state, rows = JoinerState(), []
+    for b in batches:
+        out, c = _probe_counted(j, j.build_map(build), b, state)
+        assert c.get("join_outcap_predicted", 0) == 0
+        rows.append(out)
+    return rows, _rows(j.finish(j.build_map(build), state))
+
+
+@pytest.mark.parametrize("case", sorted(PREDICTED_READS))
+def test_a_later_batch_reads_the_candidate_total_with_the_probes_count(case):
+    jt, first_reads, later_reads = PREDICTED_READS[case]
+    j = Joiner(PROBE_P, BUILD_B, [col("pk")], [col("bk")], jt, True)
+    build = _run_map_build()
+    # 603 and 602 candidates: both inside the first batch's bucket
+    batches = [_probe_batch([7, 11, 12, None, 60]), _probe_batch([7, 13, None, 99, 300])]
+    jmap, state = j.build_map(build), JoinerState()
+    (rows1, c1), (rows2, c2) = (_probe_counted(j, jmap, b, state) for b in batches)
+    assert c1["device_read_n"] == first_reads and c1.get("join_outcap_predicted", 0) == 0
+    assert c2["device_read_n"] == later_reads
+    assert (c2["join_outcap_predicted"], c2.get("join_outcap_redo", 0)) == (1, 0)
+    assert c2["join_search_steps"] == c1["join_search_steps"] > 0
+    assert jmap.candidate_peak(batches[0].capacity) == 603
+    want, want_tail = _two_read_rows(j, build, batches)
+    assert [rows1, rows2] == want
+    assert _rows(j.finish(jmap, state)) == want_tail
+    if case == "inner":
+        assert len(rows2) == 602  # 600 pairs of key 7, keys 13 and 99 once
+
+
+@pytest.mark.parametrize("case", sorted(PREDICTED_READS))
+def test_a_batch_that_outgrows_the_bucket_is_probed_again(case):
+    """The second batch's 1,801 candidates outgrow the first batch's
+    1,024-row bucket: the cut launch is dropped, the probe re-launched
+    at 2,048 from the same search, and the rows are the two-read path's
+    — NULL probe keys and a run of 600 build rows among them."""
+    jt, first_reads, _ = PREDICTED_READS[case]
+    j = Joiner(PROBE_P, BUILD_B, [col("pk")], [col("bk")], jt, True)
+    build = _run_map_build()
+    batches = [_probe_batch([7, 11, 12, None, 60]), _probe_batch([7, 7, None, 13, 7, 200])]
+    jmap, state = j.build_map(build), JoinerState()
+    _probe_counted(j, jmap, batches[0], state)
+    rows2, c2 = _probe_counted(j, jmap, batches[1], state)
+    assert (c2["join_outcap_predicted"], c2["join_outcap_redo"]) == (1, 1)
+    # the total rides the first read, the re-launch's count is read alone
+    assert c2["device_read_n"] == first_reads
+    assert c2["join_search_steps"] > 0
+    assert jmap.candidate_peak(batches[1].capacity) == 1801
+    want, want_tail = _two_read_rows(j, build, batches)
+    assert rows2 == want[1]
+    assert _rows(j.finish(jmap, state)) == want_tail
+    if case == "inner":
+        assert len(rows2) == 1801
+    # the peak only rises: a smaller batch after it takes the wider bucket
+    _, c3 = _probe_counted(j, jmap, batches[0], JoinerState())
+    assert (c3["join_outcap_predicted"], c3.get("join_outcap_redo", 0)) == (1, 0)
+    assert jmap.candidate_peak(batches[0].capacity) == 1801
+
+
+def test_the_candidate_peaks_are_not_serialized():
+    j = Joiner(PROBE_P, BUILD_B, [col("pk")], [col("bk")], JoinType.INNER, True)
+    jmap = j.build_map(_run_map_build())
+    batch = _probe_batch([7, 11, 12, None, 60])
+    _probe_counted(j, jmap, batch, JoinerState())
+    assert jmap.candidate_peak(batch.capacity) == 603
+    back = JoinMap.deserialize(jmap.serialize(), BUILD_B)
+    assert back.candidate_peak(batch.capacity) is None
+    # nor a pytree leaf: a map rebuilt from its leaves starts empty too
+    leaves, tree = jax.tree_util.tree_flatten(jmap)
+    assert jax.tree_util.tree_unflatten(tree, leaves).candidate_peak(batch.capacity) is None
+    rows, c = _probe_counted(j, back, batch, JoinerState())
+    assert c.get("join_outcap_predicted", 0) == 0 and c["device_read_n"] == 2
+    assert rows == _probe_counted(j, jmap, batch, JoinerState())[0]

@@ -779,9 +779,20 @@ def test_joiner_kernels_are_under_the_dispatch_counters():
     assert {"join_candidate", "join_probe", "join_compact"} <= set(kc)
     assert c["xla_dispatches"] >= 3 and c["launch_n"] == c["xla_dispatches"]
     assert c.get("xla_compiles", 0) == 0
-    assert c["device_read_n"] == 3  # candidate total, pair count, unmatched count
+    # a second probe of the map: the pair count and the candidate
+    # total in one read (the bucket is the first probe's), then the
+    # unmatched count
+    assert c["device_read_n"] == 2
+    assert (c["join_outcap_predicted"], c.get("join_outcap_redo", 0)) == (1, 0)
     # the search's steps ride the candidate total's read: build key 4
     # twice is the largest bucket, two steps
+    assert (c["join_probe_n"], c["join_search_steps"]) == (1, 2)
+    # a map's first probe: candidate total, pair count, unmatched count
+    jmap = j.build_map(build)
+    with dispatch.capture() as c:
+        assert probe_once() == 5
+    assert c.get("xla_compiles", 0) == 0
+    assert c["device_read_n"] == 3 and "join_outcap_predicted" not in c
     assert (c["join_probe_n"], c["join_search_steps"]) == (1, 2)
 
 
