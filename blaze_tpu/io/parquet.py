@@ -487,19 +487,29 @@ def _stat_bytes(dtype: DataType, v) -> bytes:
     return bytes(v)  # byte array: raw bytes
 
 
-def _stat_value(dtype: DataType, b: bytes):
-    phys = _physical(dtype)
-    if phys == T_INT32:
-        return struct.unpack("<i", b)[0]
-    if phys == T_INT64:
-        return struct.unpack("<q", b)[0]
-    if phys == T_FLOAT:
-        return struct.unpack("<f", b)[0]
-    if phys == T_DOUBLE:
-        return struct.unpack("<d", b)[0]
-    if phys == T_BOOLEAN:
-        return b[0] != 0
-    return bytes(b)
+_STAT_INT = {T_INT32: struct.Struct("<i"), T_INT64: struct.Struct("<q")}
+
+
+def chunk_bounds(chunk: "ChunkMeta") -> Optional[Tuple]:
+    """A chunk's ``(min, max)`` as the values its physical type orders:
+    ints for INT32 and INT64, an int for a FIXED_LEN_BYTE_ARRAY (a
+    decimal's big-endian two's complement), bytes for a BYTE_ARRAY
+    (ordered byte by byte, unsigned).  None where the chunk states none,
+    or its type is one whose statistics prune nothing here: a float's
+    leave its NaNs out, a boolean and an INT96 are not compared."""
+    lo, hi = chunk.min_value, chunk.max_value
+    if lo is None or hi is None:
+        return None
+    if chunk.phys in _STAT_INT:
+        s = _STAT_INT[chunk.phys]
+        if len(lo) != s.size or len(hi) != s.size:
+            return None
+        return s.unpack(lo)[0], s.unpack(hi)[0]
+    if chunk.phys == T_FLBA:
+        return int.from_bytes(lo, "big", signed=True), int.from_bytes(hi, "big", signed=True)
+    if chunk.phys == T_BYTE_ARRAY:
+        return bytes(lo), bytes(hi)
+    return None
 
 
 # ------------------------------------------------------------------ writer
@@ -650,6 +660,15 @@ def write_parquet(
         w.write_i64(3, rg["rows"])
         w.list_elem_struct_end()
     _w_string(w, 6, "blaze-tpu parquet 0.1")
+    # column_orders: every column's statistics in its type's own order
+    # (TypeDefinedOrder), without which a reader leaves min_value and
+    # max_value unread
+    w.begin_list(7, CT_STRUCT, len(schema.fields))
+    for _ in schema.fields:
+        w.list_elem_struct_begin()
+        w.begin_struct(1)
+        w.end_struct()
+        w.list_elem_struct_end()
     w.buf.append(0)  # FileMetaData stop
 
     meta = w.getvalue()
@@ -742,12 +761,15 @@ def read_metadata(path: str) -> ParquetFileMeta:
             elif not isinstance(nc, int):
                 nc = None
             # min/max: prefer modern min_value/max_value (5/6), fall
-            # back to deprecated max/min (1/2)
-            mx = stats.get(5, stats.get(1))
-            mn = stats.get(6, stats.get(2))
+            # back to deprecated max/min (1/2), which writers ordered as
+            # signed values: right for the integer types alone
+            phys = md.get(1, 0)
+            mx, mn = stats.get(5), stats.get(6)
+            if mx is None and mn is None and phys in _STAT_INT:
+                mx, mn = stats.get(1), stats.get(2)
             chunks[name] = ChunkMeta(
                 name=name,
-                phys=md.get(1, 0),
+                phys=phys,
                 codec=md.get(4, 0),
                 num_values=md.get(5, 0),
                 offset=first,
@@ -964,15 +986,22 @@ _ARROW_CODEC = {"UNCOMPRESSED": CODEC_UNCOMPRESSED, "SNAPPY": CODEC_SNAPPY, "GZI
                 "ZSTD": CODEC_ZSTD}
 
 
-def arrow_row_groups(arrow_file) -> Optional[List[RowGroupMeta]]:
-    """read_metadata's row groups, WITHOUT their statistics, from the
-    footer Arrow parsed when ``arrow_file`` (open_arrow_file's) was
-    opened — a small file's footer costs the thrift reader above more
-    than its pages cost Arrow.  None where the file has what this does
-    not spell (a nested column, a codec or type unknown here).
-    Statistics stay read_metadata's: Arrow withholds those whose sort
-    order the footer does not state, which this module's own writer
-    never did."""
+#: a min or max as Arrow hands it over (``Statistics.min_raw``) -> the
+#: footer's bytes of it, as read_metadata holds them
+_ARROW_STAT_BYTES = {T_INT32: _STAT_INT[T_INT32].pack, T_INT64: _STAT_INT[T_INT64].pack,
+                     T_BYTE_ARRAY: bytes, T_FLBA: bytes}
+
+
+def arrow_row_groups(arrow_file, statistics: Sequence[str] = ()) -> Optional[List[RowGroupMeta]]:
+    """read_metadata's row groups from the footer Arrow parsed when
+    ``arrow_file`` (open_arrow_file's) was opened — a small file's
+    footer costs the thrift reader above more than its pages cost
+    Arrow.  The chunks of the columns named in ``statistics`` carry
+    their min, max and null count as read_metadata would; Arrow
+    withholds a min and max whose sort order the footer does not state
+    (an old writer's strings), and such a chunk has none.  None where
+    the file has what this does not spell (a nested column, a codec or
+    type unknown here)."""
     md = arrow_file.metadata
     columns = [md.schema.column(j) for j in range(md.num_columns)]
     if any(c.max_repetition_level or c.max_definition_level > 1 or c.path != c.name
@@ -990,14 +1019,28 @@ def arrow_row_groups(arrow_file) -> Optional[List[RowGroupMeta]]:
             first = c.data_page_offset
             if c.dictionary_page_offset:
                 first = min(first, c.dictionary_page_offset)
-            chunks[col.path] = ChunkMeta(
+            chunk = chunks[col.path] = ChunkMeta(
                 name=col.path, phys=phys, codec=_ARROW_CODEC[c.compression],
                 num_values=c.num_values, offset=first, total_comp=c.total_compressed_size,
                 max_def=col.max_definition_level,
                 type_length=col.length if phys == T_FLBA else 0)
+            if col.path in statistics:
+                _arrow_statistics(c.statistics, chunk)
         out.append(RowGroupMeta(rows=rg.num_rows, chunks=chunks, index=g,
                                 total_comp=sum(c.total_comp for c in chunks.values())))
     return out
+
+
+def _arrow_statistics(stats, chunk: ChunkMeta) -> None:
+    """Arrow's statistics of one chunk into ``chunk``, in the footer's
+    encoding."""
+    if stats is None:
+        return
+    if stats.has_null_count:
+        chunk.null_count = stats.null_count
+    encode = _ARROW_STAT_BYTES.get(chunk.phys)
+    if encode is not None and stats.has_min_max:
+        chunk.min_value, chunk.max_value = encode(stats.min_raw), encode(stats.max_raw)
 
 
 def read_row_group(path: str, row_group: RowGroupMeta, fields: Sequence[Field], capacity: int,

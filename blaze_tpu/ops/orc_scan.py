@@ -73,7 +73,7 @@ class OrcScanExec(ExecNode):
         self.predicate = predicate
         self.stated_batch_rows = int(batch_rows)  # as ParquetScanExec's
         self.batch_rows = self.stated_batch_rows or int(conf.BATCH_SIZE.get())
-        self._conjuncts = _prune_conjuncts(predicate)
+        self._conjuncts = _prune_conjuncts(predicate, schema)
 
     @property
     def schema(self) -> Schema:

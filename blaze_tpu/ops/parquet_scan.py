@@ -13,16 +13,16 @@ from __future__ import annotations
 import collections
 import contextlib
 import datetime
-import struct
 import time
-from typing import List, NamedTuple, Optional, Sequence, Union
+from fractions import Fraction
+from typing import Any, List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
 
 from .. import conf
 from ..batch import Column, RecordBatch, _pad_1d, bucket_capacity
-from ..exprs.compile import infer_lit_dtype
-from ..exprs.ir import BinOp, Col, Expr, Lit
+from ..exprs.compile import decimal_unscaled, infer_lit_dtype
+from ..exprs.ir import BinOp, Col, Expr, InList, IsNotNull, Lit
 from ..io import parquet as pq
 from ..runtime import dispatch, trace
 from ..runtime.context import TaskContext
@@ -76,82 +76,145 @@ def split_row_groups(entry: FileEntry,
     return [rg for rg in row_groups if entry.start <= rg.midpoint < end]
 
 
-def _lit_physical(value, dtype: DataType):
-    """Literal -> comparable physical value (matching chunk stats)."""
-    if dtype.is_decimal:
-        if isinstance(value, float):
-            return int(round(value * 10**dtype.scale))
-        if isinstance(value, str):
-            from decimal import Decimal
+#: a comparison of a column with a literal, read from the column's side
+_FLIP = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "==": "==", "!=": "!="}
+_EPOCH = datetime.date(1970, 1, 1)
 
-            return int(Decimal(value).scaleb(dtype.scale).to_integral_value())
-        return int(value) * 10**dtype.scale
+
+class Conjunct(NamedTuple):
+    """One conjunct of a scan's predicate that a chunk's statistics can
+    rule out: ``name <op> value`` for ``op`` in ``_FLIP``, ``value`` in
+    the column's units (_lit_physical); ``op`` "in" with ``value`` the
+    tuple of such values; ``op`` "notnull" with none."""
+
+    name: str
+    op: str
+    value: Any
+
+
+def _lit_physical(lit: Lit, dtype: DataType):
+    """``lit`` in the units its column's statistics are ordered in, as
+    the engine compares it: a decimal column's unscaled digits, exactly
+    — a Fraction where the literal holds more digits than the column's
+    scale — an integer as itself, a date's days since the epoch, a
+    string's UTF-8 bytes.  None where that cannot be said exactly: a
+    float, whose comparison with an exact column rounds; a literal of
+    another kind than its column; a string as long as the column's
+    width, since the column holds its values cut there; a column of any
+    other kind."""
+    if isinstance(lit.value, bool):
+        return None
+    try:
+        return _in_units(lit.value, infer_lit_dtype(lit.value, lit.dtype), dtype)
+    except (TypeError, ValueError, ArithmeticError) as e:
+        reraise_control(e)
+        return None
+
+
+def _in_units(value, t: DataType, dtype: DataType):
+    if dtype.is_decimal or dtype.is_integer:
+        if t.is_decimal:
+            exact = Fraction(decimal_unscaled(value, t.scale), 10**t.scale)
+        elif t.is_integer and isinstance(value, int):
+            exact = Fraction(value)
+        else:
+            return None
+        if dtype.is_decimal:
+            exact *= 10**dtype.scale
+        return exact.numerator if exact.denominator == 1 else exact
     if dtype.kind == TypeKind.DATE32:
+        if t.kind != TypeKind.DATE32 or isinstance(value, datetime.datetime):
+            return None
         if isinstance(value, str):
             value = datetime.date.fromisoformat(value)
         if isinstance(value, datetime.date):
-            return (value - datetime.date(1970, 1, 1)).days
-        return int(value)
-    if dtype.is_string:
-        return value.encode("utf-8") if isinstance(value, str) else bytes(value)
-    return value
+            return (value - _EPOCH).days
+        return value if isinstance(value, int) else None
+    if dtype.is_string and isinstance(value, (str, bytes)):
+        b = value.encode("utf-8") if isinstance(value, str) else bytes(value)
+        # shorter than the width, it orders against a cut value as against the whole
+        return b if len(b) < dtype.string_width else None
+    return None
 
 
-def _prune_conjuncts(predicate: Optional[Expr]) -> List:
-    """Extract (col, op, physical literal) conjuncts usable against
-    row-group min/max stats."""
-    out = []
+def _conjunct(e: Expr, dtypes: dict) -> Optional[Conjunct]:
+    if isinstance(e, IsNotNull) and isinstance(e.child, Col) and e.child.name in dtypes:
+        return Conjunct(e.child.name, "notnull", None)
+    if (isinstance(e, InList) and not e.negated and isinstance(e.child, Col)
+            and e.child.name in dtypes and all(isinstance(v, Lit) for v in e.values)):
+        # a NULL of the list passes no row
+        values = [_lit_physical(v, dtypes[e.child.name]) for v in e.values if v.value is not None]
+        if any(v is None for v in values):
+            return None
+        return Conjunct(e.child.name, "in", tuple(values))
+    if isinstance(e, BinOp) and e.op in _FLIP:
+        column, lit, op = e.left, e.right, e.op
+        if isinstance(column, Lit) and isinstance(lit, Col):
+            column, lit, op = lit, column, _FLIP[op]
+        if (isinstance(column, Col) and isinstance(lit, Lit) and lit.value is not None
+                and column.name in dtypes):
+            value = _lit_physical(lit, dtypes[column.name])
+            if value is not None:
+                return Conjunct(column.name, op, value)
+    return None
 
-    def walk(e: Optional[Expr]):
-        if e is None:
-            return
-        if isinstance(e, BinOp):
-            if e.op == "and":
-                walk(e.left)
-                walk(e.right)
-                return
-            if e.op in ("<", "<=", ">", ">=", "=="):
-                l, r = e.left, e.right
-                if isinstance(l, Col) and isinstance(r, Lit) and r.value is not None:
-                    t = infer_lit_dtype(r.value, r.dtype)
-                    out.append((l.name, e.op, _lit_physical(r.value, t)))
-                elif isinstance(r, Col) and isinstance(l, Lit) and l.value is not None:
-                    flip = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "==": "=="}
-                    t = infer_lit_dtype(l.value, l.dtype)
-                    out.append((r.name, flip[e.op], _lit_physical(l.value, t)))
 
-    walk(predicate)
+def _prune_conjuncts(predicate: Optional[Expr], schema: Schema) -> List[Conjunct]:
+    """The conjuncts of ``predicate`` — through AND, and nothing else —
+    over columns of ``schema`` that statistics can rule out: a column
+    compared with a literal on either side, a column IN literals,
+    IsNotNull of a column.  Anything else (OR, NOT, a cast of the
+    column, a function) and a literal _lit_physical cannot state
+    exactly is left out: it rules out no row group, and the filter
+    above the scan applies it still."""
+    dtypes = {f.name: f.dtype for f in schema.fields}
+    out, todo = [], [] if predicate is None else [predicate]
+    while todo:
+        e = todo.pop()
+        if isinstance(e, BinOp) and e.op == "and":
+            todo += [e.right, e.left]
+        elif (c := _conjunct(e, dtypes)) is not None:
+            out.append(c)
     return out
 
 
-def _maybe_match(chunk: pq.ChunkMeta, dtype: DataType, op: str, lit_v) -> bool:
-    if chunk.min_value is None or chunk.max_value is None:
+def _ordered_by(dtype: DataType) -> tuple:
+    """The physical types whose statistics order a column of ``dtype``
+    in _lit_physical's units."""
+    if dtype.is_decimal:
+        return pq.T_INT32, pq.T_INT64, pq.T_FLBA
+    if dtype.is_integer:
+        return pq.T_INT32, pq.T_INT64
+    if dtype.kind == TypeKind.DATE32:
+        return (pq.T_INT32,)
+    return (pq.T_BYTE_ARRAY,) if dtype.is_string else ()
+
+
+def _rules_out(c: Conjunct, chunk: pq.ChunkMeta, rows: int, dtype: DataType) -> bool:
+    """Whether ``chunk``'s statistics prove that none of the ``rows`` of
+    its row group passes ``c``.  A NULL passes none of the conjuncts, so
+    a chunk of NULLs alone is ruled out by each; past that, its min and
+    max, where its physical type orders them as ``dtype``'s values."""
+    if chunk.null_count is not None and chunk.null_count >= rows:
         return True
-    try:
-        if chunk.phys == pq.T_FLBA:
-            # FLBA stats (decimal): big-endian signed
-            lo = int.from_bytes(chunk.min_value, "big", signed=True)
-            hi = int.from_bytes(chunk.max_value, "big", signed=True)
-        else:
-            lo = pq._stat_value(dtype, chunk.min_value)
-            hi = pq._stat_value(dtype, chunk.max_value)
-    except (struct.error, ValueError) as e:
-        reraise_control(e)
-        return True
-    try:
-        if op == "<":
-            return lo < lit_v
-        if op == "<=":
-            return lo <= lit_v
-        if op == ">":
-            return hi > lit_v
-        if op == ">=":
-            return hi >= lit_v
-        if op == "==":
-            return lo <= lit_v <= hi
-    except TypeError:
-        return True
-    return True
+    bounds = pq.chunk_bounds(chunk)
+    if c.op == "notnull" or bounds is None or chunk.phys not in _ordered_by(dtype):
+        return False
+    lo, hi = bounds
+    v = c.value
+    if c.op == "in":
+        return all(x < lo or x > hi for x in v)
+    if c.op == "<":
+        return lo >= v
+    if c.op == "<=":
+        return lo > v
+    if c.op == ">":
+        return hi <= v
+    if c.op == ">=":
+        return hi < v
+    if c.op == "==":
+        return v < lo or v > hi
+    return lo == hi == v  # !=
 
 
 class ParquetScanExec(ExecNode):
@@ -182,9 +245,11 @@ class ParquetScanExec(ExecNode):
         # none, and the executor's spark.blaze.batchSize decides
         self.stated_batch_rows = int(batch_rows)
         self.batch_rows = self.stated_batch_rows or int(conf.BATCH_SIZE.get())
-        self._conjuncts = _prune_conjuncts(predicate) if bool(
+        # over the files' columns: a partition column has no statistics
+        self._conjuncts = _prune_conjuncts(predicate, schema) if bool(
             conf.PARQUET_FILTER_PUSHDOWN.get()
         ) else []
+        self._stat_columns = frozenset(c.name for c in self._conjuncts)
 
     @property
     def schema(self) -> Schema:
@@ -192,6 +257,15 @@ class ParquetScanExec(ExecNode):
 
     def num_partitions(self) -> int:
         return max(1, len(self.file_groups))
+
+    def with_predicate(self, predicate: Expr) -> "ParquetScanExec":
+        """A copy of this scan with ``predicate`` ANDed to its own: the
+        row groups whose statistics rule it out are never fetched.  This
+        scan is left as it is."""
+        if self.predicate is not None:
+            predicate = BinOp("and", self.predicate, predicate)
+        return ParquetScanExec(self.file_groups, self._schema, predicate, self.stated_batch_rows,
+                               self.partition_schema)
 
     def narrowed(self, names: Sequence[str]) -> "ParquetScanExec":
         """This scan reading ``names`` alone: fewer chunks of each file,
@@ -254,11 +328,12 @@ class ParquetScanExec(ExecNode):
                 arrow_file = None
                 try:
                     arrow_file = pq.open_arrow_file(path, self._schema.fields)
-                    # the footer Arrow parsed, where nothing is pruned by the
-                    # statistics it leaves out; else the thrift reader's
+                    # the footer Arrow parsed, with the statistics the
+                    # predicate reads; the thrift reader's where Arrow
+                    # does not take the file
                     row_groups = None
-                    if arrow_file is not None and not self._conjuncts:
-                        row_groups = pq.arrow_row_groups(arrow_file)
+                    if arrow_file is not None:
+                        row_groups = pq.arrow_row_groups(arrow_file, self._stat_columns)
                     if row_groups is None:
                         row_groups = pq.read_metadata(path).row_groups
                 except Exception:
@@ -274,10 +349,18 @@ class ParquetScanExec(ExecNode):
             dispatch.record("scan_partition_files", bool(values))
             dispatch.record("scan_row_groups_other_split", len(row_groups) - len(mine))
             try:
-                for rg in mine:
-                    if rg.rows and not self._pruned(rg):
-                        for chunks, lo, hi, cut in self._row_group_pieces(path, rg, arrow_file):
-                            yield chunks + self._partition_arrays(values, hi), lo, hi, cut
+                kept = [rg for rg in mine if rg.rows]
+                if self._conjuncts:
+                    # the statistics' verdict on the entry's row groups,
+                    # before one of them is fetched
+                    with trace.span("scan_prune"):
+                        kept = [rg for rg in kept if not self._pruned(rg)]
+                chosen = sum(rg.rows for rg in mine)
+                dispatch.record("scan_rows_chosen", chosen)
+                dispatch.record("scan_rows_pruned", chosen - sum(rg.rows for rg in kept))
+                for rg in kept:
+                    for chunks, lo, hi, cut in self._row_group_pieces(path, rg, arrow_file):
+                        yield chunks + self._partition_arrays(values, hi), lo, hi, cut
             finally:
                 if arrow_file is not None:
                     arrow_file.close(force=True)
@@ -304,16 +387,9 @@ class ParquetScanExec(ExecNode):
     def _pruned(self, rg: pq.RowGroupMeta) -> bool:
         """Whether the chunk statistics rule the row group out, decided
         before it is opened."""
-        for name, op, lit_v in self._conjuncts:
-            ch = rg.chunks.get(name)
-            if ch is None:
-                continue
-            fld = next((f for f in self._schema.fields if f.name == name), None)
-            if fld is None:
-                # predicate column pruned from the read
-                # schema: stats pruning just skips it
-                continue
-            if not _maybe_match(ch, fld.dtype, op, lit_v):
+        for c in self._conjuncts:
+            ch = rg.chunks.get(c.name)  # none: a column the file lacks, with no statistics
+            if ch is not None and _rules_out(c, ch, rg.rows, self._schema.field(c.name).dtype):
                 self.metrics.add("pruned_row_groups", 1)
                 self.metrics.add("pruned_rows", rg.rows)
                 dispatch.record("scan_row_groups_pruned")

@@ -18,11 +18,11 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .. import conf
-from ..exprs.ir import Alias, Col, Expr
+from ..exprs.ir import Alias, Col, Expr, and_
 from ..ops import (
     AggExec, AggFunction, AggMode, ExecNode, ExpandExec, FilterExec,
-    GenerateExec, GroupingExpr, LimitExec, MemoryScanExec, ProjectExec,
-    RenameColumnsExec, SortExec, SortField, UnionExec, WindowExec,
+    GenerateExec, GroupingExpr, LimitExec, MemoryScanExec, ParquetScanExec,
+    ProjectExec, RenameColumnsExec, SortExec, SortField, UnionExec, WindowExec,
     WindowFunction,
 )
 from ..ops.generate import NativeGenerator, json_tuple_generator
@@ -35,6 +35,7 @@ from ..schema import DataType, Field, Schema
 from .expr_converter import (
     UnsupportedSparkExpr, convert_expr, convert_expr_with_fallback,
 )
+from ..runtime import dispatch
 from ..runtime.errors import reraise_control
 from .plan_json import SparkNode, expr_id
 
@@ -363,7 +364,50 @@ def _convert_scan(node: SparkNode, ctx: ConversionContext) -> ExecNode:
             raise UnsupportedSparkExec(f"column {user!r} not in table {table!r}")
         exprs.append(Col(user))
         names.append(f"#{eid}" if eid is not None else user)
+    if isinstance(scan, ParquetScanExec):
+        scan = _pushed_down(scan, node, attrs)
     return ProjectExec(scan, exprs, names)
+
+
+def _pushed_down(scan: ParquetScanExec, node: SparkNode,
+                 attrs: List[SparkNode]) -> ParquetScanExec:
+    """A copy of ``scan`` whose predicate is the scan node's
+    ``dataFilters``, ANDed (≙ ``NativeParquetScanBase`` handing them to
+    the native scan as its pruning predicate, as parquet-mr is handed
+    them under ``spark.sql.parquet.filterPushdown``): the row groups
+    whose statistics rule them out are never read.  A filter that does
+    not lower is left out, never a reason to fall back: the FilterExec
+    above keeps every one, as Spark's does."""
+    try:
+        filters = node.expr_list("dataFilters")
+    except Exception as e:  # noqa: BLE001 — a field that does not parse prunes nothing
+        reraise_control(e)
+        filters = []
+    by_id = {expr_id(a.fields.get("exprId")): _attr_user_name(a) for a in attrs}
+    pushed = []
+    for f in filters:
+        try:
+            pushed.append(convert_expr(_by_column_name(f, by_id)))
+        except Exception as e:  # noqa: BLE001 — what does not lower prunes nothing
+            reraise_control(e)
+            dispatch.record("scan_conjuncts_dropped")
+            continue
+        dispatch.record("scan_conjuncts_pushed")
+    return scan.with_predicate(and_(*pushed)) if pushed else scan
+
+
+def _by_column_name(node: SparkNode, by_id: Dict[Optional[int], str]) -> SparkNode:
+    """``node`` with each attribute named as the scan's column it is,
+    not by its exprId.  A subquery is not run a second time to prune."""
+    if "Subquery" in node.name or node.name == "DynamicPruningExpression":
+        raise UnsupportedSparkExpr(f"{node.name} in a data filter")
+    if node.name == "AttributeReference":
+        eid = expr_id(node.fields.get("exprId"))
+        name = node.fields.get("name") if eid is None else by_id.get(eid)
+        if name not in by_id.values():
+            raise UnsupportedSparkExpr(f"attribute {name!r} #{eid} is no column of the scan")
+        return SparkNode(node.cls, {"name": name})
+    return SparkNode(node.cls, node.fields, [_by_column_name(c, by_id) for c in node.children])
 
 
 def _convert_project(node: SparkNode, ctx: ConversionContext) -> ExecNode:
