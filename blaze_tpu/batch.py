@@ -21,8 +21,9 @@ stays static.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +130,43 @@ class Column:
             g(self.lengths),
             None if self.children is None else tuple(c.take(idx) for c in self.children),
         )
+
+
+#: The device row mask of a FULL batch, all True, one a capacity for
+#: the process: ``RecordBatch.to_device_counted`` gives it to every
+#: column whose validity equals the row mask.  One array serves as the
+#: validity of many columns and batches because nothing in this package
+#: donates a buffer to a program or deletes an array: keep it so.  The
+#: capacity buckets bound the dict; scan threads stage side by side.
+_FULL_MASKS: Dict[int, jnp.ndarray] = {}
+_FULL_MASKS_LOCK = threading.Lock()
+
+
+def _full_mask(cap: int) -> jnp.ndarray:
+    m = _FULL_MASKS.get(cap)
+    if m is None:
+        with _FULL_MASKS_LOCK:
+            m = _FULL_MASKS.get(cap)
+            if m is None:
+                # uncommitted on the default device, as every staged
+                # buffer is: programs see the signature they always saw
+                m = _FULL_MASKS[cap] = jnp.asarray(np.ones(cap, np.bool_))
+    return m
+
+
+def _is_row_mask(v, n: int) -> bool:
+    """True when host validity ``v`` is ``arange(cap) < n``."""
+    return (type(v) is np.ndarray and v.ndim == 1 and v.dtype == np.bool_
+            and bool(v[:n].all()) and not v[n:].any())
+
+
+def _host_arrays(c: Column) -> int:
+    """Host buffers ``c.to_device()`` transfers, children's included."""
+    if c.dtype.kind == TypeKind.OPAQUE:
+        return 0
+    k = sum(a is not None and not isinstance(a, jnp.ndarray)
+            for a in (c.data, c.validity, c.lengths))
+    return k + sum(_host_arrays(ch) for ch in c.children or ())
 
 
 def _pad_1d(a: np.ndarray, cap: int) -> np.ndarray:
@@ -410,7 +448,38 @@ class RecordBatch:
         return jnp.arange(cap) < self.num_rows
 
     def to_device(self) -> "RecordBatch":
-        return RecordBatch(self.schema, [c.to_device() for c in self.columns], self.num_rows)
+        return self.to_device_counted()[0]
+
+    def to_device_counted(self) -> Tuple["RecordBatch", int, int]:
+        """``to_device()`` with what it cost: (device batch, arrays
+        transferred, validity arrays that took a shared row mask).
+
+        A top-level column that is neither nested nor opaque, whose host
+        validity equals the batch's row mask ``arange(cap) < num_rows``
+        (every real row valid, every padding row not), transfers no
+        validity of its own: in a full batch it takes the one device
+        mask of its capacity (:func:`_full_mask`), in a partial batch
+        the batch's such columns share one mask transferred once.  Data,
+        lengths and every other column transfer as ``Column.to_device``
+        does."""
+        cap, n = self.capacity, self.num_rows
+        mask = None
+        arrays = shared = 0
+        cols = []
+        for c in self.columns:
+            if (c.children is None and c.dtype.kind != TypeKind.OPAQUE
+                    and _is_row_mask(c.validity, n)):
+                if mask is None:
+                    if n == cap:
+                        mask = _full_mask(cap)
+                    else:
+                        mask = jnp.asarray(np.arange(cap) < n)
+                        arrays += 1
+                shared += 1
+                c = replace(c, validity=mask)
+            arrays += _host_arrays(c)
+            cols.append(c.to_device())
+        return RecordBatch(self.schema, cols, n), arrays, shared
 
     def host_nbytes(self) -> int:
         """Bytes ``to_device()`` would stage: the ``nbytes`` of every

@@ -157,21 +157,27 @@ class ExecNode:
         under a ``scan_stage`` annotation — host time in the enqueue,
         not the transfer.  The one per-batch site: it sums locally and
         reaches the tally once, when the stream ends (``scan_stage_ns``,
-        ``scan_stage_n`` batches, ``h2d_bytes`` from shapes), with the
-        same nanoseconds as this node's ``input_io_time``."""
-        ns = n = nbytes = 0
+        ``scan_stage_n`` batches, ``h2d_bytes`` from shapes — every host
+        array handed over, a validity left untransferred included —,
+        ``h2d_arrays`` transferred, ``h2d_masks_shared`` validities that
+        took a shared row mask), with the same nanoseconds as this
+        node's ``input_io_time``."""
+        ns = n = nbytes = arrays = shared = 0
         try:
             for b in host_batches:
                 with trace.annotation("scan_stage"):
                     t0 = time.perf_counter_ns()
-                    out = b.to_device()
+                    out, k, s = b.to_device_counted()
                     ns += time.perf_counter_ns() - t0
                 n += 1
                 nbytes += b.host_nbytes()
+                arrays += k
+                shared += s
                 yield out
         finally:
             if n:
-                dispatch.record_span("scan_stage", ns, n, h2d_bytes=nbytes)
+                dispatch.record_span("scan_stage", ns, n, h2d_bytes=nbytes,
+                                     h2d_arrays=arrays, h2d_masks_shared=shared)
                 self.metrics.add("input_io_time", ns)
 
     def _count_output(self, stream: BatchStream) -> BatchStream:

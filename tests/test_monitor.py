@@ -877,9 +877,10 @@ def _source_metric_literals():
     (+ counter= kwargs), plus the histogram/timer observation sites
     (observe_hist / record_timer) that carry full family names, plus
     what a host span tallies: ``trace.span("x")`` / ``record_span("x")``
-    give ``x_ns`` and ``x_n``, ``record_span("x", ns, y=v)`` ``y``, and
-    ``tally="x"`` (a hand-over of ``pipelined``) ``x_wait_ns`` / ``_n``,
-    ``x_full_ns`` / ``_n``, ``x_items`` and ``x_producer_ns``."""
+    give ``x_ns`` and ``x_n``, ``record_span("x", ns, y=v, z=w)`` ``y``
+    and ``z``, and ``tally="x"`` (a hand-over of ``pipelined``)
+    ``x_wait_ns`` / ``_n``, ``x_full_ns`` / ``_n``, ``x_items`` and
+    ``x_producer_ns``."""
     names = set()
     hist_re = re.compile(
         r'(?:observe_hist|record_timer)\(\s*"([a-z][a-z_0-9]*)"')
@@ -911,9 +912,8 @@ def _source_metric_literals():
             for m in re.finditer(
                     r'\b(?:span|record_span)\(\s*"([a-z][a-z_0-9]*)"', src):
                 names.update((m.group(1) + "_ns", m.group(1) + "_n"))
-            for m in re.finditer(
-                    r'\brecord_span\("[a-z_]+",[^)=]*\b([a-z][a-z_0-9]*)=', src):
-                names.add(m.group(1))
+            for m in re.finditer(r'\brecord_span\("[a-z_]+",([^)]*)\)', src):
+                names.update(re.findall(r'\b([a-z][a-z_0-9]*)=(?!=)', m.group(1)))
             # a hand-over of runtime/pipeline.pipelined tallies under its
             # ``tally`` name: two wait spans and two counters
             for m in re.finditer(r'\btally(?:: str)? ?= ?"([a-z][a-z_0-9]*)"', src):

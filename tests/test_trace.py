@@ -746,6 +746,68 @@ def test_staged_scan_sums_locally_and_records_once(data):
     assert c["scan_stage_ns"] == scan.metrics.get("input_io_time") > 0
 
 
+def _q6_memory_scans(data, n_parts):
+    """lineitem as q6's column-pruned scan hands it over: its four
+    columns, none of them NULL; 16,384-row batches, so every partition
+    ends in a partial one."""
+    from blaze_tpu.schema import Schema
+
+    cols = ("l_quantity", "l_extendedprice", "l_discount", "l_shipdate")
+    schema = Schema([f for f in TPCH_SCHEMAS["lineitem"].fields if f.name in cols])
+    assert len(schema.fields) == len(cols)
+    return {"lineitem": MemoryScanExec(
+        table_to_batches(data["lineitem"], schema, n_parts, batch_rows=16384), schema)}
+
+
+def test_staged_q6_shares_its_row_masks_and_counts_them(data):
+    """A q6 run from memory: each of the four columns' validity takes a
+    shared row mask, full batch or partial; what was transferred plus
+    what was shared is what to_device() was handed, and a partial
+    batch's one mask; h2d_bytes still counts every host array."""
+    import numpy as np
+
+    n_parts = 2
+    scans = _q6_memory_scans(data, n_parts)
+    host = [b for p in scans["lineitem"]._partitions for b in p]
+    partial = sum(b.num_rows < b.capacity for b in host)
+    assert 0 < partial < len(host)
+    handed = sum(isinstance(a, np.ndarray) for b in host for c in b.columns
+                 for a in (c.data, c.validity, c.lengths))
+    stages, manager = split_stages(build_query("q6", scans, n_parts))
+    with dispatch.capture() as c:
+        assert sum(b.num_rows for b in run_stages(stages, manager, max_task_attempts=1)) > 0
+    assert c["scan_stage_n"] == len(host)
+    assert c["h2d_masks_shared"] == 4 * len(host)
+    assert c["h2d_arrays"] + c["h2d_masks_shared"] == handed + partial
+    assert c["h2d_arrays"] == 4 * len(host) + partial
+    assert c["h2d_bytes"] == sum(_host_nbytes(b) for b in host)
+
+
+def test_shared_row_masks_compile_nothing_new(data, monkeypatch):
+    """Warmed by batches staged column by column — every validity its
+    own transfer — a run over shared row masks launches the same
+    programs with the same argument signatures, and so compiles nothing;
+    nor does a second run over shared masks."""
+    from blaze_tpu.batch import RecordBatch
+
+    def each_column(b):
+        return RecordBatch(b.schema, [c.to_device() for c in b.columns], b.num_rows), 0, 0
+
+    def run():
+        stages, manager = split_stages(build_query("q6", _q6_memory_scans(data, 2), 2))
+        with dispatch.capture() as c:
+            assert sum(b.num_rows for b in run_stages(stages, manager, max_task_attempts=1)) > 0
+        return c
+
+    with monkeypatch.context() as m:
+        m.setattr(RecordBatch, "to_device_counted", each_column)
+        assert run()["h2d_masks_shared"] == 0
+    for _ in range(2):
+        c = run()
+        assert c["h2d_masks_shared"] > 0
+        assert c.get("xla_compiles", 0) == 0
+
+
 def test_span_closes_and_tallies_when_its_body_raises():
     with dispatch.capture() as c:
         with pytest.raises(KeyError):
