@@ -58,7 +58,7 @@ from ..schema import (
     decimal_sum_agg_type,
 )
 from .base import BatchStream, ExecNode
-from .filter import compact_columns
+from .filter import compact_columns, kept_indices
 
 
 class AggMode(enum.Enum):
@@ -1205,7 +1205,7 @@ class AggExec(ExecNode):
             if use_segscan:
                 b_idx = seg.starts
             else:
-                b_idx = jnp.nonzero(boundary, size=cap, fill_value=0)[0]
+                b_idx, _ = kept_indices(boundary)
             out_live = jnp.arange(cap) < n_out
             group_out: List[Column] = []
             for skc in sorted_keys:

@@ -23,13 +23,29 @@ from ..schema import DataType, Field, Schema
 from .base import BatchStream, ExecNode
 
 
+def kept_indices(keep):
+    """Ascending indices of the True rows of ``keep``, padded to its
+    capacity with 0; returns (idx int32[cap], count int32).
+
+    One single-operand int32 sort of ``where(keep, row, cap)``: the
+    kept rows' indices are distinct, so no stability is needed and their
+    order holds.  Not ``jnp.nonzero(size=cap)``: its bincount is a
+    scatter-add of every row whatever ``keep`` holds — int64 under x64 —
+    which the TPU runs nearly serially (as ``build_sorted_segs`` in
+    ops/agg.py already avoids)."""
+    cap = keep.shape[0]
+    count = jnp.sum(keep, dtype=jnp.int32)
+    pos = jax.lax.sort(jnp.where(keep, jax.lax.iota(jnp.int32, cap), jnp.int32(cap)))
+    idx = jnp.where(pos < cap, pos, jnp.int32(0))
+    return idx, count
+
+
 def compact_columns(cols, keep):
     """Move rows where ``keep`` to the front; invalidate the rest.
     Returns (new_cols, count)."""
     cap = keep.shape[0]
-    count = jnp.sum(keep.astype(jnp.int32))
-    idx = jnp.nonzero(keep, size=cap, fill_value=0)[0]
-    live = jnp.arange(cap) < count
+    idx, count = kept_indices(keep)
+    live = jax.lax.iota(jnp.int32, cap) < count
     out = []
     for c in cols:
         taken = c.take(idx)

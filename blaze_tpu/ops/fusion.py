@@ -34,7 +34,7 @@ before fusion (VERDICT r5).  Four tiers, all gated on
    a traceable chain (or nothing) feeds a ``ShuffleWriterExec`` with
    hash or round-robin partitioning, the chain's transform, the
    partition-id computation, the pid sort, and the per-partition
-   bincount compose into ONE program per batch
+   counts compose into ONE program per batch
    (``ShuffleWriterExec.absorb_traceable_chain``) — a shuffle map
    stage costs ~1 dispatch/batch instead of chain+hash+sort, mirroring
    the reference's native shuffle writer where map-side compute and

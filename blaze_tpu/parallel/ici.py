@@ -36,14 +36,15 @@ from .mesh import DATA_AXIS
 
 def _bucketize(cols: Tuple[Column, ...], pids, live, n_dev: int):
     """Route local rows into n_dev fixed-capacity buckets."""
+    from ..ops.filter import kept_indices
+
     cap = pids.shape[0]
     out_data = []
     counts = []
     for d in range(n_dev):
         keep = live & (pids == d)
-        cnt = jnp.sum(keep.astype(jnp.int32))
-        idx = jnp.nonzero(keep, size=cap, fill_value=0)[0]
-        bucket_live = jnp.arange(cap) < cnt
+        idx, cnt = kept_indices(keep)
+        bucket_live = jax.lax.iota(jnp.int32, cap) < cnt
         bcols = []
         for c in cols:
             t = c.take(idx)

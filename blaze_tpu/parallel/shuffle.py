@@ -111,11 +111,17 @@ def _sort_by_pid_body(cols, pids, n_out, num_rows):
     row_idx = jnp.arange(cap, dtype=jnp.int32)
     skey, sidx = jax.lax.sort((key, row_idx), num_keys=1, is_stable=True)
     sorted_cols = tuple(c.take(sidx) for c in cols)
-    counts = jax.ops.segment_sum(
-        live.astype(jnp.int64), jnp.clip(pids, 0, n_out - 1).astype(jnp.int32),
-        num_segments=n_out,
-    )
-    return sorted_cols, counts, sidx
+    return sorted_cols, _sorted_counts(skey, n_out), sidx
+
+
+def _sorted_counts(skey, n_out):
+    """Rows a partition (int64[n_out]) of pid-sorted keys, dead rows
+    carrying ``n_out`` after every live one: where each partition's run
+    starts, differenced.  Not a ``segment_sum`` of the live rows: that is
+    a scatter-add of every row, which the TPU runs nearly serially."""
+    starts = jnp.searchsorted(skey, jnp.arange(n_out + 1, dtype=skey.dtype),
+                              side="left", method="scan_unrolled")
+    return jnp.diff(starts).astype(jnp.int64)
 
 
 def _build_pid_sort_kernel():
@@ -563,7 +569,7 @@ def _build_fused_write_kernel(out_schema, fns, pid_mode, exprs, n_out,
                               slot_counts=()):
     """ONE program per map-stage batch (fusion tier 5): the traceable
     map chain, the partition-id computation, the pid sort, and the
-    per-partition bincount, all in a single XLA executable.  The
+    per-partition counts, all in a single XLA executable.  The
     unfused path pays chain + hash + sort dispatches per batch; over a
     remote chip each is ~70-80 ms of turnaround.  ``fns`` are the
     chain's trace transforms bottom->top (may be empty: a bare writer

@@ -230,6 +230,10 @@ def programs():
     return out
 
 
+#: programs whose row compaction and partition counts lower to no scatter
+ROW_BOOKKEEPING = {"filter", "fused_stage", "shuffle_pid_sort", "fused_shuffle_write"}
+
+
 @pytest.mark.parametrize("query,labels", [
     ("q6", {"agg", "agg_update"}),
     ("q1", {"agg", "agg_dense_update", "sort"}),
@@ -242,14 +246,19 @@ def test_query_programs_compile_for_the_chip(one_chip, programs, as_chip,
     """The fused q06 stage, the q01 dense agg update (4 groups: the
     sort-free program at batch capacity 65,536) and q03's
     filter/join-build/shuffle-write/sort programs, each at the shapes
-    the scheduler path really launched — x64 and all.  (The q01 sort
+    the scheduler path really launched — x64 and all; the filter and
+    shuffle-write programs among them with no scatter.  (The q01 sort
     update that merged at accumulator + batch capacity crashed this
     compiler; interpret mode and the CPU never noticed.)"""
     found = programs[query]
     assert labels <= {p[0] for p in found}, sorted({p[0] for p in found})
     widest = 0
     for label, fn, args, kwargs in found:
-        _compile(fn, one_chip, *args, **kwargs)
+        compiled = _compile(fn, one_chip, *args, **kwargs)
+        if label in ROW_BOOKKEEPING:
+            # kept rows by a sort, partition counts by a search: the
+            # TPU runs a scatter nearly serially, whatever the mask holds
+            assert "scatter(" not in compiled.as_text(), label
         widest = max([widest] + [
             x.shape[0] for x in jax.tree_util.tree_leaves((args, kwargs))
             if isinstance(x, jax.ShapeDtypeStruct) and x.shape])
